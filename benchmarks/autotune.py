@@ -53,6 +53,7 @@ from repro.core.networks import (
     sparse_conv_layers,
     vgg16_conv_layers,
 )
+from repro.runtime.compile_cache import use_compilation_cache
 
 NET_LAYERS = {
     "resnet50": resnet50_conv_layers,
@@ -224,6 +225,7 @@ def main() -> None:
     ap.add_argument("--verbose", action="store_true",
                     help="print every candidate timing, not just winners")
     args = ap.parse_args()
+    use_compilation_cache()
 
     net = "smoke" if args.smoke else args.net
     reps = 1 if args.smoke else args.reps

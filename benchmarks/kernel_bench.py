@@ -1,9 +1,11 @@
-"""Kernel micro-bench: Pallas (interpret) vs jnp oracle, correctness + time.
+"""Kernel micro-bench: Pallas kernels vs jnp oracle, correctness + time.
 
-On this CPU container the wall times characterize the *oracle* (XLA-CPU) and
-the interpreter overhead only — TPU projections come from the roofline
-harness, not from these timings.  The value here is the sweep: every kernel
-x shape x dtype cell must stay within tolerance of its oracle.
+Every kernel is called through ``kernels.ops`` with ``impl="pallas"``, so
+``ops`` decides from the platform whether Mosaic compiles it or the
+interpreter runs it.  Off a TPU the wall times characterize XLA-CPU and the
+interpreter only; they are liveness readings, not kernel speed.  The value
+here is the sweep: every kernel x shape cell must stay within tolerance of
+its oracle.
 """
 from __future__ import annotations
 
@@ -12,13 +14,8 @@ import time
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import (
-    conv1d_causal,
-    conv2d,
-    matmul_act_stationary,
-    matmul_weight_stationary,
-    ref,
-)
+from repro.core.modes import Stationarity
+from repro.kernels import ops, ref
 
 
 def _time(fn, *args, reps=3):
@@ -30,52 +27,43 @@ def _time(fn, *args, reps=3):
     return (time.perf_counter() - t0) / reps * 1e6  # us
 
 
+def _row(name, pallas, oracle):
+    err = float(jnp.max(jnp.abs(pallas() - oracle())))
+    return [name, f"{_time(pallas):.0f}", f"{_time(oracle):.0f}", f"{err:.1e}"]
+
+
 def kernel_table():
     key = jax.random.PRNGKey(0)
     rows = []
 
     cases = [
-        ("conv2d 3x3 s1", lambda: (
-            jax.random.normal(key, (1, 28, 28, 32)),
-            jax.random.normal(key, (3, 3, 32, 64)), dict(padding=1))),
-        ("conv2d 7x7 s2", lambda: (
-            jax.random.normal(key, (1, 56, 56, 3)),
-            jax.random.normal(key, (7, 7, 3, 32)),
-            dict(stride=2, padding=3))),
+        ("conv2d 3x3 s1", (1, 28, 28, 32), (3, 3, 32, 64), dict(padding=1)),
+        ("conv2d 7x7 s2 (im2col gemm)", (1, 56, 56, 3), (7, 7, 3, 32),
+         dict(stride=2, padding=3)),
     ]
-    for name, mk in cases:
-        x, w, kw = mk()
-        t_pal = _time(lambda: conv2d(x, w, interpret=True, **kw))
-        t_ref = _time(lambda: ref.conv2d_ref(x, w, **{k: v for k, v in
-                                                      kw.items()}))
-        err = float(jnp.max(jnp.abs(conv2d(x, w, interpret=True, **kw)
-                                    - ref.conv2d_ref(x, w, **kw))))
-        rows.append([name, f"{t_pal:.0f}", f"{t_ref:.0f}", f"{err:.1e}"])
+    for name, xs, ws, kw in cases:
+        x, w = jax.random.normal(key, xs), jax.random.normal(key, ws)
+        rows.append(_row(name,
+                         lambda: ops.conv2d(x, w, impl="pallas", **kw),
+                         lambda: ref.conv2d_ref(x, w, **kw)))
 
-    x = jax.random.normal(key, (1024, 1024))
-    w = jax.random.normal(key, (1024, 1024))
-    err = float(jnp.max(jnp.abs(matmul_act_stationary(x, w) -
-                                ref.matmul_ref(x, w))))
-    rows.append(["matmul act-stationary 1k^3",
-                 f"{_time(lambda: matmul_act_stationary(x, w)):.0f}",
-                 f"{_time(lambda: ref.matmul_ref(x, w)):.0f}", f"{err:.1e}"])
-
-    x2 = jax.random.normal(key, (4, 2048))
-    w2 = jax.random.normal(key, (2048, 1024))
-    err = float(jnp.max(jnp.abs(matmul_weight_stationary(x2, w2) -
-                                ref.matmul_ref(x2, w2))))
-    rows.append(["matmul weight-stationary (decode)",
-                 f"{_time(lambda: matmul_weight_stationary(x2, w2)):.0f}",
-                 f"{_time(lambda: ref.matmul_ref(x2, w2)):.0f}", f"{err:.1e}"])
+    for name, (m, c, k), st in [
+            ("gemm act-stationary 1k^3", (1024, 1024, 1024),
+             Stationarity.ACTIVATION_STATIONARY),
+            ("gemm weight-stationary (decode)", (4, 2048, 1024),
+             Stationarity.WEIGHT_STATIONARY)]:
+        x = jax.random.normal(key, (m, c))
+        w = jax.random.normal(key, (c, k))
+        rows.append(_row(name,
+                         lambda: ops.gemm(x, w, impl="pallas", stationarity=st),
+                         lambda: ref.matmul_ref(x, w)))
 
     x3 = jax.random.normal(key, (2, 256, 512))
     w3 = jax.random.normal(key, (4, 512))
-    err = float(jnp.max(jnp.abs(conv1d_causal(x3, w3, interpret=True) -
-                                ref.conv1d_causal_ref(x3, w3))))
-    rows.append(["conv1d causal d_conv=4",
-                 f"{_time(lambda: conv1d_causal(x3, w3, interpret=True)):.0f}",
-                 f"{_time(lambda: ref.conv1d_causal_ref(x3, w3)):.0f}",
-                 f"{err:.1e}"])
+    rows.append(_row("conv1d causal d_conv=4",
+                     lambda: ops.conv1d_causal(x3, w3, impl="pallas"),
+                     lambda: ref.conv1d_causal_ref(x3, w3)))
 
-    return ("Kernel micro-bench (Pallas interpret vs jnp oracle)",
+    return (f"Kernel micro-bench (Pallas vs jnp oracle, "
+            f"{jax.default_backend()})",
             ["kernel", "pallas us", "oracle us", "max err"], rows)

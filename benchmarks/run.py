@@ -25,6 +25,8 @@ import argparse
 import json
 import sys
 
+from repro.runtime.compile_cache import use_compilation_cache
+
 
 def _print_table(title, headers, rows, max_rows=60):
     print(f"\n=== {title} ===")
@@ -59,6 +61,7 @@ def main() -> None:
                          "for --bench-json and embed the dense-vs-sparse "
                          "per-layer deltas (sparse_delta)")
     args = ap.parse_args()
+    use_compilation_cache()
 
     from . import paper_figures
 

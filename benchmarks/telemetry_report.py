@@ -65,6 +65,7 @@ from repro.core.networks import (
     vgg16_conv_layers,
 )
 from repro.observability import format_table, reconcile, totals, trace
+from repro.runtime.compile_cache import use_compilation_cache
 
 NET_LAYERS = {
     "resnet50": resnet50_conv_layers,
@@ -464,6 +465,7 @@ def main() -> None:
                     help="enable the tuning cache for the run (tile%%/tiles "
                          "columns show what ran)")
     args = ap.parse_args()
+    use_compilation_cache()
 
     if args.tuned:
         autotune.enable()

@@ -32,7 +32,7 @@ def _conv1d_kernel(x_ref, w_ref, o_ref, acc_ref, *, fl: int):
 
 
 def conv1d_causal(x: jnp.ndarray, w: jnp.ndarray, *, bc: int = BC,
-                  interpret: bool = True) -> jnp.ndarray:
+                  interpret: bool) -> jnp.ndarray:
     b, t, c = x.shape
     fl, c2 = w.shape
     assert c == c2, (x.shape, w.shape)

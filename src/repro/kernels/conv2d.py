@@ -11,6 +11,9 @@ The paper's §III.A dataflow, transplanted to the TPU memory hierarchy:
   rows into <=3-tap pieces (§III.D, 21 pieces for 7x7) because a CU has 3
   cascaded PEs; the MXU has no such register-width limit, so each row is one
   loop level and the 7x7 decomposition lives only in the analytic model.
+* **Unit stride only**: every tap window is a plain shifted slice.  Mosaic
+  refuses a strided in-kernel slice, so ``kernels.ops`` runs strided convs
+  (ResNet-50's 7x7/2 stem) as an im2col GEMM on the matmul kernels instead.
 * **Feedback-path reuse**: the input spatial block is fetched to VMEM *once*
   per (batch, channel-block) and re-read for every tap — the halo rows are
   never re-fetched from HBM, which is exactly the economics of the paper's
@@ -30,8 +33,8 @@ The paper's §III.A dataflow, transplanted to the TPU memory hierarchy:
 
 Zero padding is applied by index arithmetic in the wrapper (pad once in HBM);
 the paper's MUX-based zero-pad insertion is register-level micro-architecture
-with no TPU analogue (see DESIGN.md §2) — the *goal* (no wasted work on pads)
-holds here by construction.
+with no TPU analogue — the *goal* (no wasted work on pads) holds here by
+construction.
 
 Layout: NHWC activations, HWIO weights, fp32 accumulation (MXU native).
 """
@@ -41,9 +44,10 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .matmul import mxu_dot
 
 # Default channel tiles — the fallback operating point.  The empirical
 # autotuner (``core.autotune``) selects per-layer-shape ``bk/bc`` by
@@ -54,7 +58,7 @@ BK = 128   # output-channel tile
 BC = 128   # input-channel tile
 
 
-def _conv2d_kernel(*refs, fh: int, fw: int, stride: int, n_c: int,
+def _conv2d_kernel(*refs, fh: int, fw: int, n_c: int,
                    has_sb: bool, has_res: bool, relu: bool):
     """grid = (B, K/bk, C/bc); c innermost (reduction axis).
 
@@ -78,20 +82,12 @@ def _conv2d_kernel(*refs, fh: int, fw: int, stride: int, n_c: int,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     oh, ow, bk = acc_ref.shape
-    x = x_ref[0]                      # (HP, WP, bc) — one fetch, all taps reuse
-    w = w_ref[...]
-    acc = acc_ref[...]
     # Serial accumulation: filter rows outer (the CU chain), columns inner.
     for r in range(fh):
         for s in range(fw):
-            window = lax.slice(
-                x, (r, s, 0),
-                (r + stride * (oh - 1) + 1, s + stride * (ow - 1) + 1, x.shape[2]),
-                (stride, stride, 1))                       # (OH, OW, bc)
-            acc += jnp.dot(window.reshape(oh * ow, -1), w[r, s],
-                           preferred_element_type=jnp.float32
-                           ).reshape(oh, ow, bk)
-    acc_ref[...] = acc
+            window = x_ref[0, r:r + oh, s:s + ow, :]          # (OH, OW, bc)
+            acc_ref[...] += mxu_dot(window.reshape(oh * ow, -1),
+                                    w_ref[r, s]).reshape(oh, ow, bk)
 
     @pl.when(c == n_c - 1)
     def _flush():
@@ -114,12 +110,12 @@ def _pack_scale_bias(scale, bias, k: int, kpad: int):
     return jnp.pad(sb, ((0, 0), (0, kpad)))
 
 
-def conv2d(x: jnp.ndarray, w: jnp.ndarray, *, stride: int = 1,
-           padding: int = 0, bk: int = BK, bc: int = BC,
+def conv2d(x: jnp.ndarray, w: jnp.ndarray, *, padding: int = 0,
+           bk: int = BK, bc: int = BC,
            scale: jnp.ndarray | None = None, bias: jnp.ndarray | None = None,
            relu: bool = False, residual: jnp.ndarray | None = None,
-           interpret: bool = True) -> jnp.ndarray:
-    """x: (B, H, W, C), w: (FH, FW, C, K) -> (B, OH, OW, K).
+           interpret: bool) -> jnp.ndarray:
+    """Unit-stride conv.  x: (B, H, W, C), w: (FH, FW, C, K) -> (B, OH, OW, K).
 
     scale/bias ((K,)), residual ((B, OH, OW, K)) and relu are fused into the
     flush step — see the module docstring's fused-flush design note.
@@ -127,8 +123,8 @@ def conv2d(x: jnp.ndarray, w: jnp.ndarray, *, stride: int = 1,
     b, h, wd, cin = x.shape
     fh, fw, cin2, k = w.shape
     assert cin == cin2, (x.shape, w.shape)
-    oh = (h - fh + 2 * padding) // stride + 1
-    ow = (wd - fw + 2 * padding) // stride + 1
+    oh = h - fh + 2 * padding + 1
+    ow = wd - fw + 2 * padding + 1
 
     bc = min(bc, cin)
     bk = min(bk, k)
@@ -160,7 +156,7 @@ def conv2d(x: jnp.ndarray, w: jnp.ndarray, *, stride: int = 1,
         in_specs.append(pl.BlockSpec((1, oh, ow, bk), lambda i, j, l: (i, 0, 0, j)))
 
     out = pl.pallas_call(
-        functools.partial(_conv2d_kernel, fh=fh, fw=fw, stride=stride, n_c=n_c,
+        functools.partial(_conv2d_kernel, fh=fh, fw=fw, n_c=n_c,
                           has_sb=has_sb, has_res=has_res, relu=relu),
         grid=(b, n_k, n_c),
         in_specs=in_specs,

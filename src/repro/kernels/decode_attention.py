@@ -67,7 +67,7 @@ def _decode_attn_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref,
 
 def decode_attention(q: jnp.ndarray, cache_k: jnp.ndarray,
                      cache_v: jnp.ndarray, pos: jnp.ndarray, *,
-                     bs: int = BS, interpret: bool = True) -> jnp.ndarray:
+                     bs: int = BS, interpret: bool) -> jnp.ndarray:
     """q: (B, H, dh); cache: (B, S, Kh, dh); pos: (B,) -> (B, H, dh)."""
     b, h, dh = q.shape
     _, s, kh, _ = cache_k.shape

@@ -73,7 +73,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 
 def flash_attention_fused(q, k, v, *, window: int = 0, softcap: float = 0.0,
                           bq: int = BQ, bk: int = BK,
-                          interpret: bool = True):
+                          interpret: bool):
     """Fused causal GQA attention.  q: (B,T,H,dh); k/v: (B,S,Kh,dh)."""
     b, t, h, dh = q.shape
     s, kh = k.shape[1], k.shape[2]

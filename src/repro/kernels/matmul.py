@@ -21,9 +21,10 @@ scale/bias (folded BN), a residual operand, and ReLU, applied on the fp32
 accumulator in the flush step so the output crosses HBM exactly once (the
 1x1 convs of a bottleneck block route here via ``ops.conv1x1``).
 
-``matmul`` picks the variant via ``core.modes.select_stationarity`` — the
-software twin of CARLA's controller.  Grid pipelining double-buffers the
-streamed operand, the TPU analogue of the paper's paired wide/narrow SRAMs.
+``kernels.ops`` picks the variant via ``core.modes.select_stationarity`` — the
+software twin of CARLA's controller — and decides ``interpret`` from the
+platform.  Grid pipelining double-buffers the streamed operand, the TPU
+analogue of the paper's paired wide/narrow SRAMs.
 """
 from __future__ import annotations
 
@@ -31,10 +32,9 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.core.modes import Stationarity, select_stationarity
 
 # MXU-aligned default tiles.  These are the *fallback* operating point: the
 # empirical autotuner (``core.autotune`` + ``benchmarks/autotune.py``) selects
@@ -58,6 +58,15 @@ def _pack_scale_bias(scale, bias, k: int, bk: int) -> jnp.ndarray:
     sc = jnp.ones((k,), jnp.float32) if scale is None else scale.astype(jnp.float32)
     bi = jnp.zeros((k,), jnp.float32) if bias is None else bias.astype(jnp.float32)
     return _pad_to(jnp.stack([sc, bi]), 1, bk)
+
+
+def mxu_dot(a, b):
+    """a @ b with an fp32 accumulator.  fp32 operands contract at full fp32
+    precision: Mosaic's default rounds them to bf16, which on a v5e put
+    ResNet-50's logits ~3e-3 (relative) off the fp32 reference."""
+    precision = lax.Precision.HIGHEST if a.dtype == jnp.float32 else None
+    return jnp.dot(a, b, precision=precision,
+                   preferred_element_type=jnp.float32)
 
 
 def _epilogue(y, sb_ref, res_ref, relu: bool):
@@ -88,8 +97,10 @@ def _mm_act_stationary_kernel(*refs, n_c: int, bc: int,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     # Slice the resident activation block; stream the weight tile past it.
-    acc_ref[...] += jnp.dot(x_ref[:, pl.ds(c * bc, bc)], w_ref[...],
-                            preferred_element_type=jnp.float32)
+    # One channel block is read whole: a slice narrower than 128 lanes at a
+    # traced offset is refused by Mosaic (C=64/32 1x1s).
+    xs = x_ref[...] if n_c == 1 else x_ref[:, pl.ds(c * bc, bc)]
+    acc_ref[...] += mxu_dot(xs, w_ref[...])
 
     @pl.when(c == n_c - 1)
     def _flush():
@@ -103,7 +114,7 @@ def matmul_act_stationary(x: jnp.ndarray, w: jnp.ndarray, *,
                           bias: jnp.ndarray | None = None,
                           relu: bool = False,
                           residual: jnp.ndarray | None = None,
-                          interpret: bool = True) -> jnp.ndarray:
+                          interpret: bool) -> jnp.ndarray:
     """(M, C) @ (C, K); activation row-block VMEM-resident, weights stream."""
     m, c = x.shape
     c2, k = w.shape
@@ -154,7 +165,7 @@ def _mm_weight_stationary_kernel(*refs, has_sb: bool, has_res: bool,
     sb_ref = next(it) if has_sb else None
     res_ref = next(it) if has_res else None
     o_ref = next(it)
-    y = jnp.dot(x_ref[...], w_ref[...], preferred_element_type=jnp.float32)
+    y = mxu_dot(x_ref[...], w_ref[...])
     o_ref[...] = _epilogue(y, sb_ref, res_ref, relu).astype(o_ref.dtype)
 
 
@@ -164,7 +175,7 @@ def matmul_weight_stationary(x: jnp.ndarray, w: jnp.ndarray, *,
                              bias: jnp.ndarray | None = None,
                              relu: bool = False,
                              residual: jnp.ndarray | None = None,
-                             interpret: bool = True) -> jnp.ndarray:
+                             interpret: bool) -> jnp.ndarray:
     """(M, C) @ (C, K) with small M: the decode GEMV-like shape."""
     m, c = x.shape
     c2, k = w.shape
@@ -198,22 +209,3 @@ def matmul_weight_stationary(x: jnp.ndarray, w: jnp.ndarray, *,
         interpret=interpret,
     )(*operands)
     return out[:, :k]
-
-
-def matmul(x: jnp.ndarray, w: jnp.ndarray, *, interpret: bool = True,
-           stationarity: Stationarity | None = None,
-           bm: int = BM, bk: int = BK, bc: int = BC,
-           **epilogue) -> jnp.ndarray:
-    """CARLA-style reconfigurable GEMM: pick residency from the M extent.
-
-    ``bm/bk/bc`` override the default tiles (the autotuner's knobs); the
-    weight-stationary variant only tiles K, so ``bm``/``bc`` apply to the
-    activation-stationary path alone.
-    """
-    if stationarity is None:
-        stationarity = select_stationarity(x.shape[0])
-    if stationarity == Stationarity.WEIGHT_STATIONARY:
-        return matmul_weight_stationary(x, w, bk=bk, interpret=interpret,
-                                        **epilogue)
-    return matmul_act_stationary(x, w, bm=bm, bk=bk, bc=bc,
-                                 interpret=interpret, **epilogue)

@@ -1,7 +1,9 @@
 """jit'd wrappers + reconfigurable dispatch over the Pallas kernels.
 
 ``impl`` selects the execution engine:
-  * ``"pallas"`` — the Pallas TPU kernels (run under interpret=True on CPU);
+  * ``"pallas"`` — the Pallas TPU kernels, compiled by Mosaic on a TPU and
+                   interpreted elsewhere (this module is the one place that
+                   decides ``interpret``, from the platform);
   * ``"ref"``    — the pure-jnp oracles (XLA-compiled; fast on CPU, and what
                    the LM models use so that 512-device dry-runs lower to
                    plain HLO convolutions/GEMMs);
@@ -104,29 +106,56 @@ def _tuning_attrs(sp, entry, tiles: TileConfig | None) -> None:
     sp.attrs["tuning_source"] = entry.source if entry is not None else "default"
 
 
+def _im2col(x, fh: int, fw: int, stride: int, padding: int):
+    """(B, H, W, C) -> (B, OH, OW, FH*FW*C) patches in HWIO tap order."""
+    _, h, wd, _ = x.shape
+    oh = (h - fh + 2 * padding) // stride + 1
+    ow = (wd - fw + 2 * padding) // stride + 1
+    xp = jnp.pad(x, ((0, 0), (padding, padding), (padding, padding), (0, 0)))
+    return jnp.concatenate(
+        [xp[:, r:r + stride * (oh - 1) + 1:stride,
+            s:s + stride * (ow - 1) + 1:stride, :]
+         for r in range(fh) for s in range(fw)], axis=-1)
+
+
 @functools.partial(
     jax.jit, static_argnames=("stride", "padding", "impl", "relu", "tiles"))
 def _conv2d_jit(x, w, scale=None, bias=None, residual=None, *,
                 relu: bool = False, stride: int = 1, padding: int = 0,
                 impl: str = "auto", tiles: TileConfig | None = None):
-    if _resolve(impl) == "pallas":
-        kw = {}
-        if tiles is not None:
-            if tiles.bk:
-                kw["bk"] = tiles.bk
-            if tiles.bc:
-                kw["bc"] = tiles.bc
-        return _conv2d_pallas(x, w, stride=stride, padding=padding,
-                              scale=scale, bias=bias, relu=relu,
-                              residual=residual, interpret=not _on_tpu(), **kw)
-    return _ref.conv2d_ref(x, w, stride=stride, padding=padding, scale=scale,
-                           bias=bias, relu=relu,
-                           residual=residual).astype(x.dtype)
+    if _resolve(impl) != "pallas":
+        return _ref.conv2d_ref(x, w, stride=stride, padding=padding,
+                               scale=scale, bias=bias, relu=relu,
+                               residual=residual).astype(x.dtype)
+    if stride > 1:
+        # Strided convs (ResNet-50's 7x7/2 stem) run as one GEMM over im2col
+        # patches: Mosaic refuses a strided slice inside the conv2d kernel,
+        # and K = FH*FW*C fills lanes that a 3-channel input block would not.
+        fh, fw, _, k = w.shape
+        p = _im2col(x, fh, fw, stride, padding)
+        b, oh, ow, kk = p.shape
+        rf = residual.reshape(b * oh * ow, k) if residual is not None else None
+        out = _tiled_matmul(p.reshape(b * oh * ow, kk), w.reshape(kk, k),
+                            scale, bias, relu, rf, tiles)
+        return out.reshape(b, oh, ow, k)
+    kw = {}
+    if tiles is not None:
+        if tiles.bk:
+            kw["bk"] = tiles.bk
+        if tiles.bc:
+            kw["bc"] = tiles.bc
+    return _conv2d_pallas(x, w, padding=padding, scale=scale, bias=bias,
+                          relu=relu, residual=residual,
+                          interpret=not _on_tpu(), **kw)
 
 
 def conv2d(x, w, *, stride: int = 1, padding: int = 0, impl: str = "auto",
            epilogue: Epilogue | None = None):
-    """General NHWC conv; CARLA 3x3/7x7 serial-accumulation dataflow."""
+    """General NHWC conv; CARLA 3x3/7x7 serial-accumulation dataflow.
+
+    On the pallas path a strided conv runs as an im2col GEMM; the span's
+    ``kernel`` attribute records which kernel ran.
+    """
     ep = epilogue or _NO_EPILOGUE
     impl = _resolve(impl)
     entry = _lookup("conv2d",
@@ -145,6 +174,8 @@ def conv2d(x, w, *, stride: int = 1, padding: int = 0, impl: str = "auto",
                           stride=stride, padding=padding, impl=impl,
                           tiles=tiles)
         jax.block_until_ready(out)
+        if impl == "pallas":
+            sp.attrs["kernel"] = "im2col_gemm" if stride > 1 else "conv2d"
         b, oh, ow, _ = out.shape
         sp.attrs["flops"] = 2 * b * oh * ow * k * fh * fw * x.shape[-1]
         sp.attrs["bytes_touched"] = _nbytes(x, w, out, ep.scale, ep.bias,

@@ -52,7 +52,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
     from repro.configs import get_config, get_shape
     from repro.launch import steps as steps_mod
     from repro.launch.hlo_analysis import analyze
-    from repro.launch.mesh import make_production_mesh, mesh_num_devices, set_mesh
+    from repro.launch.mesh import make_production_mesh, mesh_num_devices
 
     cfg = get_config(arch)
     shape = get_shape(shape_name)
@@ -64,7 +64,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
         optimizer = "adafactor" if cfg.param_count() > 1e11 else "adamw"
 
     t0 = time.time()
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             mk = steps_mod.make_train_step(cfg, mesh, optimizer_name=optimizer)
             batch_struct = steps_mod.input_specs(cfg, shape)

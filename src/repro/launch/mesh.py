@@ -5,31 +5,26 @@ platform before first jax init; everything else sees 1 CPU device).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
-def set_mesh(mesh):
-    """Context manager activating ``mesh`` for the block.
-
-    ``jax.set_mesh`` (ambient mesh, jax >= 0.5) when available; on older jax
-    the Mesh object itself is the context manager that makes it the default
-    for sharded computations.
-    """
-    set_fn = getattr(jax, "set_mesh", None)
-    if set_fn is not None:
-        return set_fn(mesh)
-    return mesh
+def auto_mesh(shape: tuple, axes: tuple):
+    """A mesh whose axes are all ``Auto``: GSPMD propagates shardings and
+    ``with_sharding_constraint`` stays a hint (``make_mesh`` defaults to
+    ``Explicit`` axes, where a constraint is an assertion)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; 2x16x16 = 512 chips across two pods."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_smoke_mesh():
     """Degenerate 1x1 mesh: lets the sharded step functions run on 1 CPU."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return auto_mesh((1, 1), ("data", "model"))
 
 
 def batch_axes(mesh) -> tuple:

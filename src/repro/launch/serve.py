@@ -18,9 +18,10 @@ import jax.numpy as jnp
 
 from repro.configs import get_config
 from repro.launch import steps as steps_mod
-from repro.launch.mesh import make_production_mesh, make_smoke_mesh, set_mesh
+from repro.launch.mesh import make_production_mesh, make_smoke_mesh
 from repro.models import lm
 from repro.observability import MetricsExporter, MetricsRegistry, events
+from repro.runtime.compile_cache import use_compilation_cache
 
 
 def main():
@@ -41,6 +42,7 @@ def main():
                     help="append structured JSONL events to this path "
                          "(env REPRO_EVENT_LOG)")
     args = ap.parse_args()
+    use_compilation_cache()
     if args.event_log:
         events.install(args.event_log)
 
@@ -50,7 +52,7 @@ def main():
     max_seq = args.prompt_len + args.gen
 
     key = jax.random.PRNGKey(0)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = lm.init_params(cfg, key)
         if cfg.input_mode == "embeds":
             batch = {"embeds": jax.random.normal(

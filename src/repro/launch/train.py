@@ -19,7 +19,7 @@ import jax.numpy as jnp
 from repro.configs import get_config
 from repro.data import PrefetchIterator, SyntheticTokenDataset
 from repro.launch import steps as steps_mod
-from repro.launch.mesh import make_production_mesh, make_smoke_mesh, set_mesh
+from repro.launch.mesh import make_production_mesh, make_smoke_mesh
 from repro.observability import (
     MetricsExporter,
     MetricsRegistry,
@@ -28,6 +28,7 @@ from repro.observability import (
     trace,
 )
 from repro.runtime import TrainSupervisor
+from repro.runtime.compile_cache import use_compilation_cache
 
 
 def main():
@@ -57,6 +58,7 @@ def main():
                     help="append structured JSONL events to this path "
                          "(env REPRO_EVENT_LOG)")
     args = ap.parse_args()
+    use_compilation_cache()
     if args.trace_out or args.trace_chrome:
         trace.enable()
     if args.event_log:
@@ -70,7 +72,7 @@ def main():
                                input_mode=cfg.input_mode,
                                d_model=cfg.d_model)
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         mk = steps_mod.make_train_step(cfg, mesh, args.optimizer, args.lr)
         batch0 = ds.batch(0)
         batch_struct = {k: jax.ShapeDtypeStruct(v.shape, v.dtype)

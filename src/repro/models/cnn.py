@@ -73,6 +73,14 @@ def _conv_bn(x, w, bn, *, fused: bool, relu: bool = False,
     return jax.nn.relu(y) if relu else y
 
 
+def _classifier(params, x):
+    """Global average pool + fc.  The fc runs at full fp32 precision: on a
+    TPU the default would round its operands to bf16, and the model is fp32."""
+    x = jnp.mean(x, axis=(1, 2))
+    return jnp.dot(x, params["fc"]["w"].astype(x.dtype),
+                   precision=jax.lax.Precision.HIGHEST)
+
+
 # ------------------------------- ResNet-50 -----------------------------------
 RESNET50_BLOCKS = {"conv2": 3, "conv3": 4, "conv4": 6, "conv5": 3}
 
@@ -215,8 +223,7 @@ def resnet50_apply(params, x, *, impl: str = "auto", fused: bool = True,
             x = _conv_bn(h, blk["c3"], blk["bn3"], fused=fused, relu=True,
                          residual=sc, impl=impl, name=f"{bname}_1x1b",
                          sparsity=tag(bname, "c3", blk["c3"]))
-    x = jnp.mean(x, axis=(1, 2))
-    return x @ params["fc"]["w"].astype(x.dtype)
+    return _classifier(params, x)
 
 
 # -------------------------------- VGG-16 -------------------------------------
@@ -244,8 +251,7 @@ def vgg16_apply(params, x, *, impl: str = "auto", fused: bool = True):
                          relu=True, padding=1, impl=impl)
         x = jax.lax.reduce_window(x, -jnp.inf, jax.lax.max, (1, 2, 2, 1),
                                   (1, 2, 2, 1), "VALID")
-    x = jnp.mean(x, axis=(1, 2))
-    return x @ params["fc"]["w"].astype(x.dtype)
+    return _classifier(params, x)
 
 
 def network_plan(layers) -> list:

@@ -17,7 +17,7 @@ import jax.numpy as jnp
 
 from repro import perf
 
-from .sharding_hints import BATCH, ambient_mesh, constrain
+from .sharding_hints import BATCH, constrain
 
 
 def moe_init(key, n_experts: int, d: int, d_ff: int):
@@ -34,8 +34,7 @@ def moe_init(key, n_experts: int, d: int, d_ff: int):
 def _group_for_shards(x, t: int):
     """B3 (§Perf): split T into per-'model'-shard blocks so routing capacity
     and the dispatch/combine contractions are shard-local."""
-    mesh = ambient_mesh()
-    ms = mesh.shape.get("model", 1) if (mesh and mesh.axis_names) else 1
+    ms = jax.sharding.get_abstract_mesh().shape.get("model", 1)
     if perf.get().grouped_moe_dispatch and ms > 1 and t % ms == 0 \
             and t >= 2 * ms:
         return ms
@@ -91,8 +90,7 @@ def _moe_grouped(params, x, *, top_k: int, capacity_factor: float):
 
     # EP when experts divide 'data' (tokens travel to expert owners via one
     # all-to-all); otherwise expert compute stays token-sharded.
-    mesh = ambient_mesh()
-    data_sz = mesh.shape.get("data", 1) if (mesh and mesh.axis_names) else 1
+    data_sz = jax.sharding.get_abstract_mesh().shape.get("data", 1)
     ep_ok = data_sz > 1 and e % data_sz == 0
     ep = (None, "model", "data", None, None) if ep_ok else \
         (BATCH, "model", None, None, None)
@@ -153,8 +151,7 @@ def _moe_flat(params, x, *, top_k: int, capacity_factor: float):
     # the capacity axis shards over 'data' (expert-data parallelism); d_ff
     # over 'model' (TP).  The dispatch einsum reshards token-sharded -> EP
     # (GSPMD lowers it to the MoE all-to-all).
-    mesh = ambient_mesh()
-    data_sz = mesh.shape.get("data", 1) if (mesh and mesh.axis_names) else 1
+    data_sz = jax.sharding.get_abstract_mesh().shape.get("data", 1)
     ep = (None, "data", None, None) if (data_sz > 1 and e % data_sz == 0) \
         else (None, None, BATCH, None)
     # dispatch -> expert buffers: (B, E, C, d)

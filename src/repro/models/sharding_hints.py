@@ -17,21 +17,9 @@ import jax
 from jax.sharding import PartitionSpec as P
 
 
-def ambient_mesh():
-    """The ambient abstract mesh, or None.
-
-    jax < 0.5 has no ambient abstract-mesh API; callers treat None the same
-    as running without a mesh (the CPU smoke path documented above).
-    """
-    get_mesh = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_mesh is None:
-        return None
-    return get_mesh()
-
-
 def _mesh_axes():
-    mesh = ambient_mesh()
-    if mesh is None or not mesh.axis_names:
+    mesh = jax.sharding.get_abstract_mesh()
+    if not mesh.axis_names:
         return None
     return mesh
 

@@ -13,8 +13,7 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 
-import jax
-
+from repro.launch.mesh import auto_mesh
 from repro.observability import events
 
 
@@ -57,4 +56,4 @@ def plan_remesh(old_shape: tuple, axis_names: tuple,
 
 
 def build_mesh(plan: ElasticPlan):
-    return jax.make_mesh(plan.new_shape, plan.axis_names)
+    return auto_mesh(plan.new_shape, plan.axis_names)

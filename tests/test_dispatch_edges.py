@@ -88,3 +88,25 @@ def test_carla_conv_3x3_matches_reference():
         x, w, (1, 1), [(1, 1), (1, 1)],
         dimension_numbers=("NHWC", "HWIO", "NHWC"))
     assert float(jnp.max(jnp.abs(got - want))) < 1e-4
+
+
+@pytest.mark.parametrize("stride,padding,kernel", [(2, 3, "im2col_gemm"),
+                                                   (1, 3, "conv2d")])
+def test_pallas_conv2d_span_records_the_kernel_that_ran(stride, padding,
+                                                        kernel):
+    """A strided conv (the 7x7/2 stem) runs as an im2col GEMM on the pallas
+    path; the plan keeps the analytic 7x7 dataflow and the kernel span says
+    which kernel ran."""
+    from repro.kernels.ref import conv2d_ref
+    from repro.observability import trace
+    key = jax.random.PRNGKey(11)
+    x = jax.random.normal(key, (2, 20, 20, 3))
+    w = jax.random.normal(jax.random.fold_in(key, 1), (7, 7, 3, 16))
+    with trace.capture() as tr:
+        got = carla_conv(x, w, stride=stride, padding=padding, impl="pallas")
+    (sp,) = tr.spans
+    assert sp.attrs["dataflow"] == Dataflow.CONV7X7_ROW_DECOMPOSED.value
+    (kernel_sp,) = sp.children
+    assert kernel_sp.attrs["kernel"] == kernel
+    want = conv2d_ref(x, w, stride=stride, padding=padding)
+    assert float(jnp.max(jnp.abs(got - want))) < 1e-4

@@ -13,7 +13,7 @@ from jax.sharding import PartitionSpec as P
 from repro.configs import ARCHS, get_config
 from repro.launch import steps as steps_mod
 from repro.launch.hlo_analysis import analyze
-from repro.launch.mesh import make_smoke_mesh, set_mesh
+from repro.launch.mesh import make_smoke_mesh
 from repro.launch.sharding import make_param_pspecs
 
 MESH_SHAPES = {"single": {"data": 16, "model": 16},
@@ -51,7 +51,7 @@ def test_param_specs_divide_dims(arch, mesh_name):
 def test_train_step_runs_on_smoke_mesh():
     cfg = get_config("smollm-135m", smoke=True)
     mesh = make_smoke_mesh()
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         mk = steps_mod.make_train_step(cfg, mesh, optimizer_name="adamw",
                                        lr=1e-3)
         state = mk["make_init"](jax.random.PRNGKey(0))()
@@ -67,7 +67,7 @@ def test_train_step_runs_on_smoke_mesh():
 def test_decode_step_runs_on_smoke_mesh():
     cfg = get_config("granite-3-2b", smoke=True)
     mesh = make_smoke_mesh()
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         mk = steps_mod.make_decode_step(cfg, mesh, max_seq=64, batch_size=2)
         params = jax.tree.map(
             lambda s: jnp.zeros(s.shape, s.dtype), steps_mod.param_specs(cfg))

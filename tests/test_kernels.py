@@ -1,7 +1,8 @@
 """Per-kernel validation: shape/dtype sweeps + hypothesis, vs ref.py oracles.
 
-All Pallas kernels run under interpret=True (CPU container; TPU is the
-lowering target).  Tolerances: fp32 1e-4 relative-ish; bf16 inputs 2e-2.
+Kernels called directly run under interpret=True; conv2d runs through
+``kernels.ops`` (which interprets off a TPU), so its strided cases cover the
+im2col GEMM route.  Tolerances: fp32 1e-4 relative-ish; bf16 inputs 2e-2.
 
 ``hypothesis`` is optional: the randomized any-(m,c,k) matmul property has a
 deterministic pinned-shape twin that always runs.
@@ -20,9 +21,9 @@ except ImportError:
 from repro.core.modes import Stationarity
 from repro.kernels import (
     conv1d_causal,
-    conv2d,
     matmul_act_stationary,
     matmul_weight_stationary,
+    ops,
     ref,
 )
 
@@ -59,7 +60,7 @@ def test_conv2d_sweep(b, h, w, c, k, fl, s, p, dtype):
     key = jax.random.PRNGKey(b * 100 + h + c + fl)
     x = _rand(key, (b, h, w, c), dtype)
     wgt = _rand(jax.random.fold_in(key, 1), (fl, fl, c, k), dtype)
-    got = conv2d(x, wgt, stride=s, padding=p, interpret=True)
+    got = ops.conv2d(x, wgt, stride=s, padding=p, impl="pallas")
     want = ref.conv2d_ref(x, wgt, stride=s, padding=p)
     assert got.shape == want.shape
     assert _err(got, want) < _tol(dtype, scale=fl * fl * c ** 0.5)
@@ -76,7 +77,7 @@ def test_matmul_act_stationary_sweep(m, c, k, dtype):
     key = jax.random.PRNGKey(m + c + k)
     x = _rand(key, (m, c), dtype)
     w = _rand(jax.random.fold_in(key, 1), (c, k), dtype)
-    got = matmul_act_stationary(x, w)
+    got = matmul_act_stationary(x, w, interpret=True)
     want = ref.matmul_ref(x, w).astype(dtype)
     assert got.shape == (m, k)
     assert _err(got, want) < _tol(dtype, scale=c ** 0.5)
@@ -88,7 +89,7 @@ def test_matmul_weight_stationary_sweep(m, c, k, dtype):
     key = jax.random.PRNGKey(m * 7 + c + k)
     x = _rand(key, (m, c), dtype)
     w = _rand(jax.random.fold_in(key, 1), (c, k), dtype)
-    got = matmul_weight_stationary(x, w)
+    got = matmul_weight_stationary(x, w, interpret=True)
     want = ref.matmul_ref(x, w).astype(dtype)
     assert _err(got, want) < _tol(dtype, scale=c ** 0.5)
 
@@ -99,8 +100,10 @@ def _check_matmul_property(m, c, k):
     x = _rand(key, (m, c), jnp.float32)
     w = _rand(jax.random.fold_in(key, 1), (c, k), jnp.float32)
     want = ref.matmul_ref(x, w)
-    assert _err(matmul_act_stationary(x, w), want) < 1e-3 * c ** 0.5
-    assert _err(matmul_weight_stationary(x, w), want) < 1e-3 * c ** 0.5
+    assert _err(matmul_act_stationary(x, w, interpret=True),
+                want) < 1e-3 * c ** 0.5
+    assert _err(matmul_weight_stationary(x, w, interpret=True),
+                want) < 1e-3 * c ** 0.5
 
 
 # Deterministic twin of the hypothesis property: primes, 1s, tile edges
@@ -167,7 +170,7 @@ def test_decode_attention_sweep(b, s, h, kh, dh, bs):
     ck = _rand(jax.random.fold_in(key, 1), (b, s, kh, dh), jnp.float32)
     cv = _rand(jax.random.fold_in(key, 2), (b, s, kh, dh), jnp.float32)
     pos = jnp.arange(b, dtype=jnp.int32) * (s // 2) + s // 3
-    got = decode_attention(q, ck, cv, pos, bs=bs)
+    got = decode_attention(q, ck, cv, pos, bs=bs, interpret=True)
     assert _err(got, _decode_ref(q, ck, cv, pos)) < 1e-4
 
 
@@ -189,7 +192,7 @@ def test_flash_fused_sweep(b, t, h, kh, dh, win, cap):
     k = _rand(jax.random.fold_in(key, 1), (b, t, kh, dh), jnp.float32)
     v = _rand(jax.random.fold_in(key, 2), (b, t, kh, dh), jnp.float32)
     got = flash_attention_fused(q, k, v, window=win, softcap=cap,
-                                bq=128, bk=128)
+                                bq=128, bk=128, interpret=True)
     with perf.baseline():
         sc = _gqa_scores(q, k)
         if cap:

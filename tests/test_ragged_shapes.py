@@ -14,8 +14,12 @@ import pytest
 
 from repro.core import Epilogue, autotune, carla_conv
 from repro.core.autotune import TileConfig, conv2d_key, gemm_key
-from repro.kernels import matmul_act_stationary, matmul_weight_stationary, ref
-from repro.kernels.conv2d import conv2d as conv2d_kernel
+from repro.kernels import (
+    matmul_act_stationary,
+    matmul_weight_stationary,
+    ops,
+    ref,
+)
 
 try:
     import hypothesis.strategies as st
@@ -75,7 +79,12 @@ def test_conv2d_kernel_ragged_tiles(h, c, k, fl, s, p, bk, bc, fused):
         ep = _epilogue(k, (1, oh, oh, k), jax.random.fold_in(key, 2))
         kw = dict(scale=ep.scale, bias=ep.bias, relu=True,
                   residual=ep.residual)
-    got = conv2d_kernel(x, w, stride=s, padding=p, bk=bk, bc=bc, **kw)
+    # through the pallas dispatch with explicit tiles: unit stride runs the
+    # conv2d kernel, stride 2 the im2col GEMM (bc tiles its reduction)
+    got = ops._conv2d_jit(x, w, kw.get("scale"), kw.get("bias"),
+                          kw.get("residual"), relu=bool(kw.get("relu")),
+                          stride=s, padding=p, impl="pallas",
+                          tiles=TileConfig(bk=bk, bc=bc))
     want = ref.conv2d_ref(x, w, stride=s, padding=p, **kw)
     assert got.shape == want.shape
     assert _err(got, want) < 1e-3, (h, c, k, fl, s, bk, bc, fused)
@@ -101,8 +110,9 @@ def test_matmul_ragged_tiles_both_stationarities(m, c, k, bm, bk, bc, fused):
         kw = dict(scale=ep.scale, bias=ep.bias, relu=True,
                   residual=ep.residual)
     want = ref.matmul_ref(x, w, **kw)
-    got_as = matmul_act_stationary(x, w, bm=bm, bk=bk, bc=min(bc, c), **kw)
-    got_ws = matmul_weight_stationary(x, w, bk=bk, **kw)
+    got_as = matmul_act_stationary(x, w, bm=bm, bk=bk, bc=min(bc, c),
+                                   interpret=True, **kw)
+    got_ws = matmul_weight_stationary(x, w, bk=bk, interpret=True, **kw)
     assert _err(got_as, want) < 1e-3, ("as", m, c, k, bm, bk, bc, fused)
     assert _err(got_ws, want) < 1e-3, ("ws", m, c, k, bk, fused)
 
@@ -167,7 +177,8 @@ if HAVE_HYPOTHESIS:
         x = jax.random.normal(key, (m, c))
         w = jax.random.normal(jax.random.fold_in(key, 1), (c, k))
         want = ref.matmul_ref(x, w)
-        got = matmul_act_stationary(x, w, bm=bm, bk=bk, bc=bc)
+        got = matmul_act_stationary(x, w, bm=bm, bk=bk, bc=bc,
+                                    interpret=True)
         assert _err(got, want) < 1e-3
 else:
     def test_matmul_any_ragged_tiles():
@@ -178,5 +189,6 @@ else:
             key = jax.random.PRNGKey(m)
             x = jax.random.normal(key, (m, c))
             w = jax.random.normal(jax.random.fold_in(key, 1), (c, k))
-            got = matmul_act_stationary(x, w, bm=bm, bk=bk, bc=bc)
+            got = matmul_act_stationary(x, w, bm=bm, bk=bk, bc=bc,
+                                        interpret=True)
             assert _err(got, ref.matmul_ref(x, w)) < 1e-3
