@@ -9,7 +9,7 @@ every unique (layer shape, dtype, epilogue, backend) key of a network it
      ``bk/bc`` channel tiles for the serial-accumulation conv kernel,
      ``bm/bk/bc`` tiles x both stationarities for the dual-residency GEMM
      (1x1 layers flatten to their GEMM shape, so ``conv1x1``/``gemm`` share
-     entries);
+     entries, and strided convs key by the shape of their im2col GEMM);
   2. times each candidate through the jitted kernel wrappers
      (best-of-``reps`` wall time, compile excluded), *including the hardcoded
      defaults* — the PR 8 operating point;
@@ -68,13 +68,29 @@ def _gemm_rows(layer, batch: int) -> int:
     return batch * per_axis * per_axis
 
 
-def _layer_key(layer, batch: int, dtype="float32") -> str:
+def _conv_shapes(layer, batch: int):
+    return ((batch, layer.IL, layer.IL, layer.IC),
+            (layer.FL, layer.FL, layer.IC, layer.K))
+
+
+def _gemm_shape(layer, batch: int):
+    """(M, C, K) of the GEMM a layer runs as: a 1x1 flattens to its strided
+    view's rows, a strided conv to its im2col patches.  None for a layer that
+    runs the conv2d kernel."""
     if layer.FL == 1:
-        return autotune.gemm_key(_gemm_rows(layer, batch), layer.IC, layer.K,
-                                 dtype)
-    x_shape = (batch, layer.IL, layer.IL, layer.IC)
-    w_shape = (layer.FL, layer.FL, layer.IC, layer.K)
-    return autotune.conv2d_key(x_shape, w_shape, layer.S, layer.Z, dtype)
+        return _gemm_rows(layer, batch), layer.IC, layer.K
+    if layer.S > 1:
+        return autotune.conv2d_gemm_shape(*_conv_shapes(layer, batch),
+                                          layer.S, layer.Z)
+    return None
+
+
+def _layer_key(layer, batch: int, dtype="float32") -> str:
+    gemm = _gemm_shape(layer, batch)
+    if gemm is not None:
+        return autotune.gemm_key(*gemm, dtype)
+    return autotune.conv2d_key(*_conv_shapes(layer, batch), layer.S, layer.Z,
+                               dtype)
 
 
 def _best_of(fn, reps: int) -> float:
@@ -102,10 +118,9 @@ def _timer_for(layer, batch: int, key, reps: int):
                                                   tiles=tiles), reps)
         return time_ms
     kx, kw = jax.random.split(key)
-    x = jax.random.normal(kx, (batch, layer.IL, layer.IL, layer.IC),
-                          jnp.float32)
-    w = jax.random.normal(kw, (layer.FL, layer.FL, layer.IC, layer.K),
-                          jnp.float32)
+    x_shape, w_shape = _conv_shapes(layer, batch)
+    x = jax.random.normal(kx, x_shape, jnp.float32)
+    w = jax.random.normal(kw, w_shape, jnp.float32)
 
     def time_ms(tiles: TileConfig | None) -> float:
         return _best_of(lambda: ops._conv2d_jit(x, w, stride=layer.S,
@@ -116,13 +131,11 @@ def _timer_for(layer, batch: int, key, reps: int):
 
 
 def _candidates_for(layer, batch: int, max_candidates: int):
-    if layer.FL == 1:
-        return autotune.gemm_candidates(_gemm_rows(layer, batch), layer.IC,
-                                        layer.K, max_candidates=max_candidates)
-    x_shape = (batch, layer.IL, layer.IL, layer.IC)
-    w_shape = (layer.FL, layer.FL, layer.IC, layer.K)
-    return autotune.conv2d_candidates(x_shape, w_shape, stride=layer.S,
-                                      padding=layer.Z,
+    gemm = _gemm_shape(layer, batch)
+    if gemm is not None:
+        return autotune.gemm_candidates(*gemm, max_candidates=max_candidates)
+    return autotune.conv2d_candidates(*_conv_shapes(layer, batch),
+                                      stride=layer.S, padding=layer.Z,
                                       max_candidates=max_candidates)
 
 

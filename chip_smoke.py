@@ -41,8 +41,10 @@ from repro.models.cnn import (  # noqa: E402
 )
 from repro.runtime.compile_cache import use_compilation_cache  # noqa: E402
 
-# max|logits - ref| / max|ref|.  fp32 kernels agree with the fp32 reference to
-# ~1e-5; operands rounded to bf16 anywhere on the path give ~1e-2.
+# max|logits - ref| / max|ref|.  Measured on a TPU v5e at seed 0: 1.0e-7 to
+# 2.1e-7 with the kernels' dots at full fp32 precision, 2.26e-3 to 3.80e-3
+# with Mosaic's default, which rounds fp32 operands to bf16.  The bound sits
+# ~5000x above the first and only 2.2x below the closest of the second.
 REL_ERR_BOUND = 1e-3
 TIMED_CALLS = 5
 KERNEL_CALL = 'custom_call_target="tpu_custom_call"'
@@ -55,11 +57,11 @@ def conv_dispatches(params) -> int:
     return 1 + sum(3 + ("proj" in blk) for blk in blocks)
 
 
-def make_phases(seed: int, *, width: float = 1.0, image: int = 224):
+def make_phases(seed: int, *, width: float = 1.0):
     """(name, params, x) for dense batch 1, dense batch 8 and pruned batch 1."""
     key = jax.random.PRNGKey(seed)
     params = jax.jit(functools.partial(resnet50_init, width=width))(key)
-    x8 = jax.random.normal(jax.random.fold_in(key, 1), (8, image, image, 3))
+    x8 = jax.random.normal(jax.random.fold_in(key, 1), (8, 224, 224, 3))
     pruned, _ = resnet50_prune(params, 0.5)
     return [("dense_b1", params, x8[:1]),
             ("dense_b8", params, x8),

@@ -10,7 +10,9 @@ an MMIE-style per-layer operating point chosen by measurement:
 
   * **Key**: ``(op kind, layer shape, dtype, epilogue signature)`` rendered as
     a flat string (backend lives in the table header, not the key).  1x1 convs
-    flatten to their GEMM shape so ``conv1x1`` and ``gemm`` share entries.
+    flatten to their GEMM shape so ``conv1x1`` and ``gemm`` share entries,
+    and strided convs, which the Pallas path runs as an im2col GEMM, key by
+    that GEMM's shape (:func:`conv2d_gemm_shape`).
   * **Entry**: the winning :class:`TileConfig` — tile sizes plus, for GEMM
     shapes, the stationarity (dataflow) choice itself — with the measured
     tuned/default wall times and where the entry came from (``table`` =
@@ -44,6 +46,8 @@ import hashlib
 import json
 import os
 from dataclasses import dataclass
+
+from .modes import Stationarity, select_stationarity
 
 # ---------------------------------------------------------------------------
 # Tile configurations
@@ -115,6 +119,17 @@ def conv2d_key(x_shape, w_shape, stride: int, padding: int, dtype,
 
 def gemm_key(m: int, c: int, k: int, dtype, epilogue: str = "none") -> str:
     return f"gemm|m{m}|c{c}|k{k}|{dtype}|ep:{epilogue}"
+
+
+def conv2d_gemm_shape(x_shape, w_shape, stride: int,
+                      padding: int) -> tuple[int, int, int]:
+    """(M, C, K) of the im2col GEMM a strided conv runs as on the Pallas path:
+    one row per output pixel, one column per (tap, input channel)."""
+    b, h, w, cin = x_shape
+    fh, fw, _, k = w_shape
+    oh = (h - fh + 2 * padding) // stride + 1
+    ow = (w - fw + 2 * padding) // stride + 1
+    return b * oh * ow, fh * fw * cin, k
 
 
 def _ep_none(key: str) -> str:
@@ -244,6 +259,10 @@ def lookup(key: str) -> Entry | None:
 
 def lookup_conv2d(x_shape, w_shape, stride, padding, dtype,
                   epilogue: str = "none") -> Entry | None:
+    """A strided conv runs as an im2col GEMM, so its tiles are GEMM tiles."""
+    if stride > 1:
+        return lookup_gemm(*conv2d_gemm_shape(x_shape, w_shape, stride,
+                                              padding), dtype, epilogue)
     return lookup(conv2d_key(x_shape, w_shape, stride, padding, dtype,
                              epilogue))
 
@@ -323,6 +342,12 @@ def _clamp(t: int, dim: int) -> int:
     return max(1, min(t, dim))
 
 
+def _lane_tiles(dim: int, sizes=_POW2) -> set[int]:
+    """Tile sizes for a channel (lane) axis of length ``dim``: the whole axis
+    or a multiple of 128 lanes, the only channel blocks Mosaic accepts."""
+    return {_clamp(t, dim) for t in sizes if t >= dim or t % 128 == 0}
+
+
 def conv2d_candidates(x_shape, w_shape, *, stride: int = 1, padding: int = 0,
                       max_candidates: int = 6) -> list[TileConfig]:
     """Tile candidates for the serial-accumulation conv kernel.
@@ -330,7 +355,8 @@ def conv2d_candidates(x_shape, w_shape, *, stride: int = 1, padding: int = 0,
     Seeded by the cost model: candidates are ranked by padded-FLOPs waste
     (channel pads to ``bc``/``bk`` multiples), then grid-step count, then the
     VMEM footprint of the resident input block + weight tile + accumulator.
-    The kernel defaults are always included.
+    The kernel defaults are always included.  Strided convs run as an im2col
+    GEMM and take :func:`gemm_candidates` of :func:`conv2d_gemm_shape`.
     """
     _, h, w, cin = x_shape
     fh, fw, _, k = w_shape
@@ -339,9 +365,9 @@ def conv2d_candidates(x_shape, w_shape, *, stride: int = 1, padding: int = 0,
     hp, wp = h + 2 * padding, w + 2 * padding
 
     cands = {(_clamp(DEFAULT_CONV2D.bk, k), _clamp(DEFAULT_CONV2D.bc, cin))}
-    for bk in _POW2:
-        for bc in _POW2:
-            cands.add((_clamp(bk, k), _clamp(bc, cin)))
+    for bk in _lane_tiles(k):
+        for bc in _lane_tiles(cin):
+            cands.add((bk, bc))
 
     def score(cand):
         bk, bc = cand
@@ -362,16 +388,18 @@ def gemm_candidates(m: int, c: int, k: int, *,
     paper's §III.B/§III.C operand swap): weight-stationary keeps the whole
     ``(M, C)`` activation resident and streams ``(C, bk)`` weight columns
     once, so it is a candidate at *any* M, not just the analytic M < 128 rule.
+    Channel tiles are lane-legal (:func:`_lane_tiles`): the act-stationary
+    kernel slices its resident block at ``c * bc`` lanes.
     """
-    analytic_ws = m < 128   # modes.select_stationarity's rule
+    analytic_ws = select_stationarity(m) == Stationarity.WEIGHT_STATIONARY
     half = max(2, max_candidates // 2)
 
     as_cands = {(_clamp(DEFAULT_GEMM.bm, m), _clamp(DEFAULT_GEMM.bk, k),
                  _clamp(DEFAULT_GEMM.bc, c))}
     for bm in _POW2[:4]:
-        for bk in _POW2[:4]:
-            for bc in _POW2:
-                as_cands.add((_clamp(bm, m), _clamp(bk, k), _clamp(bc, c)))
+        for bk in _lane_tiles(k, _POW2[:4]):
+            for bc in _lane_tiles(c):
+                as_cands.add((_clamp(bm, m), bk, bc))
 
     def as_score(cand):
         bm, bk, bc = cand
@@ -381,8 +409,7 @@ def gemm_candidates(m: int, c: int, k: int, *,
         vmem = 4 * (bm * _ceil_to(c, bc) + bc * bk + bm * bk)
         return (waste, steps, vmem > VMEM_BUDGET, -bm * bk)
 
-    ws_cands = {_clamp(DEFAULT_GEMM.bk, k)} | {_clamp(bk, k)
-                                               for bk in _POW2}
+    ws_cands = _lane_tiles(k)           # holds the clamped default bk
 
     def ws_score(bk):
         waste = _ceil_to(k, bk) / k
@@ -403,8 +430,14 @@ def gemm_candidates(m: int, c: int, k: int, *,
 # ---------------------------------------------------------------------------
 # tile_util — padding waste, the TPU analogue of the paper's PUF
 # ---------------------------------------------------------------------------
-def tile_util_conv2d(x_shape, w_shape, tiles: TileConfig | None = None) -> float:
-    """Logical FLOPs / padded FLOPs under the conv kernel's channel tiling."""
+def tile_util_conv2d(x_shape, w_shape, tiles: TileConfig | None = None, *,
+                     stride: int = 1, padding: int = 0) -> float:
+    """Logical FLOPs / padded FLOPs under the conv kernel's channel tiling,
+    or, for a strided conv, under the tiling of its im2col GEMM."""
+    if stride > 1:
+        m, c, k = conv2d_gemm_shape(x_shape, w_shape, stride, padding)
+        return tile_util_gemm(m, c, k, tiles,
+                              stationarity=select_stationarity(m).value)
     cin, k = w_shape[2], w_shape[3]
     bk = _clamp((tiles.bk if tiles and tiles.bk else DEFAULT_CONV2D.bk), k)
     bc = _clamp((tiles.bc if tiles and tiles.bc else DEFAULT_CONV2D.bc), cin)

@@ -142,7 +142,8 @@ def carla_conv(x: jnp.ndarray, w: jnp.ndarray, *, stride: int = 1,
             else "activation_stationary")
     else:
         tile_util = autotune.tile_util_conv2d(x.shape, w.shape,
-                                              plan.tile_config)
+                                              plan.tile_config, stride=stride,
+                                              padding=padding)
     sparse_attrs = {}
     if sparsity is not None:
         sparse_attrs = {

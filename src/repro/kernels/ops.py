@@ -153,8 +153,9 @@ def conv2d(x, w, *, stride: int = 1, padding: int = 0, impl: str = "auto",
            epilogue: Epilogue | None = None):
     """General NHWC conv; CARLA 3x3/7x7 serial-accumulation dataflow.
 
-    On the pallas path a strided conv runs as an im2col GEMM; the span's
-    ``kernel`` attribute records which kernel ran.
+    On the pallas path a strided conv runs as an im2col GEMM, tuned and
+    reported under that GEMM's shape; the span's ``kernel`` attribute records
+    which kernel ran.
     """
     ep = epilogue or _NO_EPILOGUE
     impl = _resolve(impl)
@@ -174,14 +175,17 @@ def conv2d(x, w, *, stride: int = 1, padding: int = 0, impl: str = "auto",
                           stride=stride, padding=padding, impl=impl,
                           tiles=tiles)
         jax.block_until_ready(out)
+        b, oh, ow, _ = out.shape
         if impl == "pallas":
             sp.attrs["kernel"] = "im2col_gemm" if stride > 1 else "conv2d"
-        b, oh, ow, _ = out.shape
+            if stride > 1:
+                sp.attrs["stationarity"] = _gemm_stationarity(b * oh * ow,
+                                                              tiles).value
         sp.attrs["flops"] = 2 * b * oh * ow * k * fh * fw * x.shape[-1]
         sp.attrs["bytes_touched"] = _nbytes(x, w, out, ep.scale, ep.bias,
                                             ep.residual)
-        sp.attrs["tile_util"] = autotune.tile_util_conv2d(x.shape, w.shape,
-                                                          tiles)
+        sp.attrs["tile_util"] = autotune.tile_util_conv2d(
+            x.shape, w.shape, tiles, stride=stride, padding=padding)
         _tuning_attrs(sp, entry, tiles)
         _epilogue_attrs(sp, ep, out)
     return out

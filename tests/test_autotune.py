@@ -115,6 +115,66 @@ def test_gemm_candidates_cover_both_stationarities():
                 assert c.bm <= m
 
 
+@pytest.mark.parametrize("batch", [1, 8])
+def test_stem_gemm_candidates_are_lane_legal(batch):
+    """ResNet-50's 7x7/2 stem runs as a (B*12544, 147) @ (147, 64) GEMM.  On
+    a TPU its channel tiles must be whole axes or 128-lane multiples, so no
+    candidate gets a bc below 128 (the old conv2d entry had bc=3)."""
+    m, c, k = autotune.conv2d_gemm_shape((batch, 224, 224, 3), (7, 7, 3, 64),
+                                         2, 3)
+    assert (m, c, k) == (batch * 112 * 112, 147, 64)
+    cands = autotune.gemm_candidates(m, c, k, max_candidates=64)
+    assert {t.stationarity for t in cands} == {"weight_stationary",
+                                               "activation_stationary"}
+    for t in cands:
+        assert t.bk == k or t.bk % 128 == 0, t
+        if t.bc is not None:
+            assert t.bc >= 128 and (t.bc == c or t.bc % 128 == 0), t
+
+
+def test_conv2d_candidates_are_lane_legal():
+    cands = autotune.conv2d_candidates((1, 56, 56, 256), (3, 3, 256, 64),
+                                       stride=1, padding=1, max_candidates=64)
+    assert cands
+    for t in cands:
+        assert t.bk == 64 and t.bc in (128, 256), t
+
+
+def test_strided_conv_tunes_as_its_im2col_gemm(iso):
+    """A strided conv's tiles are GEMM tiles: an entry under its conv2d key
+    is never read, the entry under its GEMM key is, and tile_util describes
+    the GEMM that ran."""
+    autotune.enable()
+    key = jax.random.PRNGKey(5)
+    x = jax.random.normal(key, (1, 16, 16, 3))
+    w = jax.random.normal(jax.random.fold_in(key, 1), (7, 7, 3, 8))
+    m, c, k = autotune.conv2d_gemm_shape(x.shape, w.shape, 2, 3)
+    autotune.put(conv2d_key(x.shape, w.shape, 2, 3, x.dtype),
+                 TileConfig(bk=8, bc=3))
+    with trace.capture() as tr:
+        carla.carla_conv(x, w, stride=2, padding=3, impl="pallas")
+    (ksp,) = tr.spans[0].children
+    assert ksp.attrs["tuned"] is False
+    assert ksp.attrs["kernel"] == "im2col_gemm"
+    assert ksp.attrs["tile_util"] == autotune.tile_util_gemm(
+        m, c, k, stationarity=ksp.attrs["stationarity"])
+
+    tiles = TileConfig(bm=32, bk=8, bc=c, stationarity="activation_stationary")
+    autotune.put(gemm_key(m, c, k, x.dtype), tiles)
+    with trace.capture() as tr:
+        out = carla.carla_conv(x, w, stride=2, padding=3, impl="pallas")
+    sp = tr.spans[0]
+    (ksp,) = sp.children
+    for s in (sp, ksp):
+        assert s.attrs["tuned"] is True
+        assert s.attrs["tile_config"] == tiles.short
+        assert s.attrs["tile_util"] == pytest.approx(
+            autotune.tile_util_gemm(m, c, k, tiles))
+    assert ksp.attrs["stationarity"] == "activation_stationary"
+    want = ref.conv2d_ref(x, w, stride=2, padding=3)
+    assert float(jnp.max(jnp.abs(out - want))) < 1e-3
+
+
 # --------------------------- cache + persistence ------------------------------
 def test_lookup_precedence_table_cache_runtime(iso):
     key = gemm_key(100, 64, 32, "float32")

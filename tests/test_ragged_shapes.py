@@ -13,7 +13,12 @@ import jax.numpy as jnp
 import pytest
 
 from repro.core import Epilogue, autotune, carla_conv
-from repro.core.autotune import TileConfig, conv2d_key, gemm_key
+from repro.core.autotune import (
+    TileConfig,
+    conv2d_gemm_shape,
+    conv2d_key,
+    gemm_key,
+)
 from repro.kernels import (
     matmul_act_stationary,
     matmul_weight_stationary,
@@ -157,6 +162,9 @@ def test_carla_conv_ragged_tuned_parity(tag, case, tiles, impl, fused,
     tag_ep = ep.tag if ep is not None else "none"
     if fl == 1:
         cache_key = gemm_key(h * h, c, k, x.dtype, tag_ep)
+    elif s > 1:   # runs as an im2col GEMM, keyed by that GEMM's shape
+        cache_key = gemm_key(*conv2d_gemm_shape(x.shape, w.shape, s, p),
+                             x.dtype, tag_ep)
     else:
         cache_key = conv2d_key(x.shape, w.shape, s, p, x.dtype, tag_ep)
     autotune.put(cache_key, tiles)

@@ -12,6 +12,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core.autotune import TileConfig
 from repro.kernels import ops
 
 KERNEL_CALL = 'custom_call_target="tpu_custom_call"'
@@ -53,12 +54,20 @@ CASES = {
     # conv5 1x1 at M=49 rows: weight-stationary
     "1x1_conv5_ws": ((1, 7, 7, 2048), (1, 1, 2048, 512), 1, 0, False),
     "1x1_c256_b8": ((8, 56, 56, 256), (1, 1, 256, 64), 1, 0, False),
+    # the stem GEMM under a tuned entry that splits its 147 patch columns
+    # into two 128-lane blocks
+    "stem_7x7s2_bc128": ((1, 224, 224, 3), (7, 7, 3, 64), 2, 3, False),
+}
+TILES = {
+    "stem_7x7s2_bc128": TileConfig(bm=256, bk=64, bc=128,
+                                   stationarity="activation_stationary"),
 }
 
 
 @pytest.mark.parametrize("name", list(CASES))
 def test_kernel_compiles_for_v5e(name, one_chip):
     xs, ws, stride, padding, has_res = CASES[name]
+    tiles = TILES.get(name)
     k = ws[-1]
     oh = (xs[1] - ws[0] + 2 * padding) // stride + 1
 
@@ -68,12 +77,13 @@ def test_kernel_compiles_for_v5e(name, one_chip):
     if ws[0] == 1:
         def fwd(x, w, sc, bi, res):
             return ops._conv1x1_jit(x, w[0, 0], sc, bi, res, relu=True,
-                                    stride=stride, impl="pallas")
+                                    stride=stride, impl="pallas",
+                                    tiles=tiles)
     else:
         def fwd(x, w, sc, bi, res):
             return ops._conv2d_jit(x, w, sc, bi, res, relu=True,
                                    stride=stride, padding=padding,
-                                   impl="pallas")
+                                   impl="pallas", tiles=tiles)
 
     res = spec(xs[0], oh, oh, k) if has_res else None
     compiled = jax.jit(fwd).lower(spec(*xs), spec(*ws), spec(k), spec(k),
