@@ -9,7 +9,7 @@ Runs every conv layer of ResNet-50 (and VGG-16 with --net vgg16) through
 
 Run:  PYTHONPATH=src python -m benchmarks.telemetry_report [--net resnet50]
           [--batch 1] [--reps 3] [--limit N] [--json out.json]
-          [--chrome out.trace.json] [--smoke] [--fused] [--tuned] [--sparse]
+          [--smoke] [--fused] [--tuned] [--sparse]
 
 ``--sparse`` swaps in the structured-pruned twin of the layer set (paper
 Table I: the first two convs of every bottleneck halve their filters, the
@@ -31,8 +31,7 @@ round-trip bytes the fusion eliminated per layer.
 
 ``--smoke`` swaps in the tiny ``smoke_conv_layers`` set (one layer per
 dataflow, reps=1, overhead check skipped) so CI can keep this CLI alive in
-seconds.  ``--chrome`` additionally exports the captured spans in Chrome
-``trace_event`` format (open at https://ui.perfetto.dev).
+seconds.
 
 Also measures the tracing-disabled dispatch overhead (the acceptance gate for
 the zero-overhead requirement): the same dispatch with tracing off must cost
@@ -456,8 +455,6 @@ def main() -> None:
                     help="backend peak for util%% (0 = best layer in run)")
     ap.add_argument("--json", default=None,
                     help="also export the raw span trace to this path")
-    ap.add_argument("--chrome", default=None,
-                    help="export a chrome://tracing / Perfetto trace here")
     ap.add_argument("--smoke", action="store_true",
                     help="tiny layer set, 1 rep, no overhead check (seconds)")
     ap.add_argument("--skip-overhead", action="store_true")
@@ -511,11 +508,6 @@ def main() -> None:
         with open(args.json, "w") as f:
             _json.dump([s.to_dict() for s in spans], f, indent=2)
         print(f"trace -> {args.json}")
-
-    if args.chrome:
-        from repro.observability import export_chrome_trace
-        export_chrome_trace(spans, args.chrome)
-        print(f"chrome trace -> {args.chrome} (open in ui.perfetto.dev)")
 
     if not skip_overhead:
         wrapped, raw = measure_disabled_overhead()

@@ -114,13 +114,16 @@ def carla_conv(x: jnp.ndarray, w: jnp.ndarray, *, stride: int = 1,
     (``core.sparsity.SparsityTag``) — the span then records ``keep_fraction``
     and ``dense_twin_macs`` so pruned-vs-dense is measurable per layer.
 
-    With tracing enabled (``observability.trace``) every dispatch records a
-    ``carla_conv`` span carrying both sides of the paper's ledger: the
-    dataflow the controller picked with its analytic ``LayerCost``
+    The dispatch always runs under ``jax.named_scope(name)``, so inside a
+    jitted forward every device op of the layer carries its name in the
+    compiled program and in a profiler trace; that costs nothing at run time.
+    With tracing enabled (``observability.trace``) an eager dispatch also
+    records a ``carla_conv`` span carrying both sides of the paper's ledger:
+    the dataflow the controller picked with its analytic ``LayerCost``
     (cycles / DRAM bytes / PUF), the epilogue combination that was fused
     (``epilogue=`` attr + ``epilogue_hbm_saved`` bytes), and the measured wall
     time + bytes of the kernel it actually ran (as a child span from
-    ``kernels.ops``).
+    ``kernels.ops``).  A dispatch traced inside a jit opens no span.
     """
     if w.ndim == 2:
         w = w[None, None]
@@ -128,9 +131,17 @@ def carla_conv(x: jnp.ndarray, w: jnp.ndarray, *, stride: int = 1,
     plan = plan_conv(x.shape, w.shape, stride, padding, name=name,
                      dtype=str(x.dtype), epilogue_tag=ep.tag)
 
-    if not trace.enabled():
-        return _dispatch(x, w, plan, stride, padding, impl, epilogue)
+    with jax.named_scope(name):
+        if not trace.timed(x):
+            return _dispatch(x, w, plan, stride, padding, impl, epilogue)
+        return _timed_dispatch(x, w, plan, stride, padding, impl, epilogue,
+                               ep, sparsity)
 
+
+def _timed_dispatch(x, w, plan: ConvPlan, stride: int, padding: int,
+                    impl: str, epilogue: Epilogue | None, ep: Epilogue,
+                    sparsity: SparsityTag | None):
+    """An eager dispatch inside a ``carla_conv`` span (see ``carla_conv``)."""
     cost = plan.cost
     if plan.layer.FL == 1:
         rows = (x.shape[0] * -(-x.shape[1] // stride)

@@ -163,6 +163,9 @@ def conv2d(x: jnp.ndarray, w: jnp.ndarray, *, padding: int = 0,
         out_specs=pl.BlockSpec((1, oh, ow, bk), lambda i, j, l: (i, 0, 0, j)),
         out_shape=jax.ShapeDtypeStruct((b, oh, ow, k + kpad), x.dtype),
         scratch_shapes=[pltpu.VMEM((oh, ow, bk), jnp.float32)],
+        # the name is a contract: a profiler trace and the compiled text
+        # name the kernel by it, and the benchmark's reduction matches it
+        name="_conv2d_kernel",
         interpret=interpret,
     )(*operands)
     return out[..., :k]

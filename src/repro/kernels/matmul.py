@@ -151,6 +151,9 @@ def matmul_act_stationary(x: jnp.ndarray, w: jnp.ndarray, *,
         out_specs=pl.BlockSpec((bm, bk), lambda i, j, l: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, kp), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bk), jnp.float32)],
+        # the name is a contract: a profiler trace and the compiled text
+        # name the kernel by it, and the benchmark's reduction matches it
+        name="_mm_act_stationary_kernel",
         interpret=interpret,
     )(*operands)
     return out[:m, :k]
@@ -206,6 +209,9 @@ def matmul_weight_stationary(x: jnp.ndarray, w: jnp.ndarray, *,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((m, bk), lambda j: (0, j)),
         out_shape=jax.ShapeDtypeStruct((m, kp), x.dtype),
+        # the name is a contract: a profiler trace and the compiled text
+        # name the kernel by it, and the benchmark's reduction matches it
+        name="_mm_weight_stationary_kernel",
         interpret=interpret,
     )(*operands)
     return out[:, :k]

@@ -31,14 +31,18 @@ epilogue was fused (``epilogue=`` attr) and the HBM bytes the fusion saved
 vs. the unfused op sequence (``epilogue_hbm_saved``).
 
 Every public entry point is telemetry-instrumented: when the global tracer is
-enabled (``observability.trace``), the dispatch records which mode the
-controller picked, operand shapes/bytes, FLOPs, wall time under
+enabled (``observability.trace``) and the call is eager, the dispatch records
+which mode the controller picked, operand shapes/bytes, FLOPs, wall time under
 ``block_until_ready``, and the tuning ledger — ``tuned`` (did the cache hit),
 ``tile_config``/``tuning_source`` (what ran and why), and ``tile_util`` (the
 padding-waste PUF analogue: logical FLOPs / padded FLOPs under the tiling
-that actually ran).  When tracing is disabled (the default) the only cost is
-one module-attribute read per call — the jitted function is invoked directly,
-no span objects or clock reads.
+that actually ran).  When tracing is disabled (the default), or the call is
+traced inside an outer ``jax.jit``, the jitted function is invoked directly:
+no span objects, no clock reads, no ``block_until_ready``.  There the record
+is the name of the ops: the strided route's patches run under
+``jax.named_scope("im2col")`` and its GEMM under ``"gemm"``, inside the
+layer's scope that ``carla_conv`` opens, and each Pallas kernel keeps its
+``pallas_call`` name.
 """
 from __future__ import annotations
 
@@ -132,11 +136,13 @@ def _conv2d_jit(x, w, scale=None, bias=None, residual=None, *,
         # patches: Mosaic refuses a strided slice inside the conv2d kernel,
         # and K = FH*FW*C fills lanes that a 3-channel input block would not.
         fh, fw, _, k = w.shape
-        p = _im2col(x, fh, fw, stride, padding)
+        with jax.named_scope("im2col"):
+            p = _im2col(x, fh, fw, stride, padding)
         b, oh, ow, kk = p.shape
         rf = residual.reshape(b * oh * ow, k) if residual is not None else None
-        out = _tiled_matmul(p.reshape(b * oh * ow, kk), w.reshape(kk, k),
-                            scale, bias, relu, rf, tiles)
+        with jax.named_scope("gemm"):
+            out = _tiled_matmul(p.reshape(b * oh * ow, kk), w.reshape(kk, k),
+                                scale, bias, relu, rf, tiles)
         return out.reshape(b, oh, ow, k)
     kw = {}
     if tiles is not None:
@@ -162,7 +168,7 @@ def conv2d(x, w, *, stride: int = 1, padding: int = 0, impl: str = "auto",
     entry = _lookup("conv2d",
                     (x.shape, w.shape, stride, padding, x.dtype, ep.tag), impl)
     tiles = entry.config if entry is not None else None
-    if not trace.enabled():
+    if not trace.timed(x):
         return _conv2d_jit(x, w, ep.scale, ep.bias, ep.residual, relu=ep.relu,
                            stride=stride, padding=padding, impl=impl,
                            tiles=tiles)
@@ -258,7 +264,7 @@ def conv1x1(x, w, *, stride: int = 1, impl: str = "auto",
     rows = b * -(-h // stride) * -(-wd // stride)   # x[:, ::s, ::s] row count
     entry = _lookup("gemm", (rows, c, w.shape[-1], x.dtype, ep.tag), impl)
     tiles = entry.config if entry is not None else None
-    if not trace.enabled():
+    if not trace.timed(x):
         return _conv1x1_jit(x, w, ep.scale, ep.bias, ep.residual, relu=ep.relu,
                             stride=stride, impl=impl, tiles=tiles)
     st = _gemm_stationarity(rows, tiles)
@@ -304,7 +310,7 @@ def gemm(x, w, *, impl: str = "auto",
     entry = _lookup("gemm", (x.shape[0], x.shape[1], w.shape[-1], x.dtype,
                              ep.tag), impl)
     tiles = entry.config if entry is not None else None
-    if not trace.enabled():
+    if not trace.timed(x):
         return _gemm_jit(x, w, ep.scale, ep.bias, ep.residual, relu=ep.relu,
                          impl=impl, stationarity=stationarity, tiles=tiles)
     st = _gemm_stationarity(x.shape[0], tiles, stationarity)
@@ -334,7 +340,7 @@ def _conv1d_jit(x, w, *, impl: str = "auto"):
 def conv1d_causal(x, w, *, impl: str = "auto"):
     """Depthwise causal conv1d (Mamba2 short conv / RWKV token shift)."""
     impl = _resolve(impl)
-    if not trace.enabled():
+    if not trace.timed(x):
         return _conv1d_jit(x, w, impl=impl)
     with trace.span("kernels.conv1d_causal", impl=impl,
                     x_shape=list(x.shape), w_shape=list(w.shape),

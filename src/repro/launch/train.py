@@ -24,7 +24,6 @@ from repro.observability import (
     MetricsExporter,
     MetricsRegistry,
     events,
-    export_chrome_trace,
     trace,
 )
 from repro.runtime import TrainSupervisor
@@ -47,8 +46,6 @@ def main():
     ap.add_argument("--ckpt-every", type=int, default=20)
     ap.add_argument("--trace-out", default=None,
                     help="export the span trace to this JSON path")
-    ap.add_argument("--trace-chrome", default=None,
-                    help="export a chrome://tracing / Perfetto trace here")
     ap.add_argument("--metrics-port", type=int,
                     default=int(os.environ.get("REPRO_METRICS_PORT", "-1")),
                     help="serve Prometheus /metrics on this port "
@@ -59,7 +56,7 @@ def main():
                          "(env REPRO_EVENT_LOG)")
     args = ap.parse_args()
     use_compilation_cache()
-    if args.trace_out or args.trace_chrome:
+    if args.trace_out:
         trace.enable()
     if args.event_log:
         events.install(args.event_log)
@@ -124,10 +121,6 @@ def main():
         if args.trace_out:
             trace.tracer.export(args.trace_out)
             print(f"trace: {len(trace.tracer.spans)} spans -> {args.trace_out}")
-        if args.trace_chrome:
-            export_chrome_trace(trace.tracer.spans, args.trace_chrome)
-            print(f"chrome trace -> {args.trace_chrome} "
-                  "(open in ui.perfetto.dev)")
         if exporter is not None:
             exporter.stop()
         if args.event_log:

@@ -181,6 +181,12 @@ def resnet50_apply(params, x, *, impl: str = "auto", fused: bool = True,
     (``SparsityTag``) so traced spans carry keep-fraction / dense-twin MACs.
     A pytree that is *already* pruned runs as-is with ``sparse=False`` —
     the forward is shape-polymorphic; the flags exist to prune and to tag.
+
+    The work runs under named scopes, which a profiler trace and the
+    compiled program show: each conv (with its fused epilogue) under its
+    layer's name (``conv1``, ``conv3_b0_proj``, ``conv3_b0_1x1a``,
+    ``conv3_b0_3x3``, ``conv3_b0_1x1b``, ...), the max pool under
+    ``maxpool``, and the mean and fc under ``head``.
     """
     if sparse and keep_fractions is None:
         keep_fractions = 0.5
@@ -202,8 +208,9 @@ def resnet50_apply(params, x, *, impl: str = "auto", fused: bool = True,
     x = _conv_bn(x, params["conv1"], params["bn1"], fused=fused, relu=True,
                  stride=2, padding=3, impl=impl, name="conv1")
     # 3x3/2 maxpool
-    x = jax.lax.reduce_window(x, -jnp.inf, jax.lax.max, (1, 3, 3, 1),
-                              (1, 2, 2, 1), "SAME")
+    with jax.named_scope("maxpool"):
+        x = jax.lax.reduce_window(x, -jnp.inf, jax.lax.max, (1, 3, 3, 1),
+                                  (1, 2, 2, 1), "SAME")
     for gname, nb in RESNET50_BLOCKS.items():
         for b in range(nb):
             bname = f"{gname}_b{b}"
@@ -223,7 +230,8 @@ def resnet50_apply(params, x, *, impl: str = "auto", fused: bool = True,
             x = _conv_bn(h, blk["c3"], blk["bn3"], fused=fused, relu=True,
                          residual=sc, impl=impl, name=f"{bname}_1x1b",
                          sparsity=tag(bname, "c3", blk["c3"]))
-    return _classifier(params, x)
+    with jax.named_scope("head"):
+        return _classifier(params, x)
 
 
 # -------------------------------- VGG-16 -------------------------------------

@@ -3,12 +3,10 @@
 ``trace``   — span recorder (nesting, JSON export, zero-overhead disabled);
 ``metrics`` — counters/gauges/histograms and rolling latency percentiles;
 ``report``  — planned-vs-measured reconciliation (paper Table II mirror);
-``export``  — Chrome/Perfetto ``trace_event`` JSON exporter;
 ``prom``    — Prometheus text exposition + stdlib HTTP exporter;
 ``events``  — structured JSONL event log for the control planes.
 """
-from . import events, export, metrics, prom, report, trace
-from .export import export_chrome_trace, to_chrome_trace
+from . import events, metrics, prom, report, trace
 from .metrics import Counter, Gauge, Histogram, LatencyWindow, MetricsRegistry
 from .prom import MetricsExporter
 from .report import ReconRow, format_table, reconcile, totals
@@ -17,7 +15,6 @@ from .trace import Capture, Span, Tracer, capture, span, tracer
 __all__ = [
     "Capture", "Counter", "Gauge", "Histogram", "LatencyWindow",
     "MetricsExporter", "MetricsRegistry", "ReconRow", "Span", "Tracer",
-    "capture", "events", "export", "export_chrome_trace", "format_table",
-    "metrics", "prom", "reconcile", "report", "span", "to_chrome_trace",
-    "totals", "trace", "tracer",
+    "capture", "events", "format_table", "metrics", "prom", "reconcile",
+    "report", "span", "totals", "trace", "tracer",
 ]

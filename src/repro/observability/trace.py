@@ -3,18 +3,26 @@
 The CARLA paper evaluates entirely through an analytic model (cycles, DRAM
 words, PUF per layer — ``core.cost_model``).  This module records what the
 JAX/Pallas side *actually does* so the two can be reconciled: every
-instrumented dispatch (``kernels.ops``, ``core.carla.carla_conv``) opens a
-span that captures the mode the controller picked, the operand shapes, the
-wall time (callers sync with ``jax.block_until_ready`` inside the span), the
-bytes the arrays touch, and — for ``carla_conv`` — the analytic ``LayerCost``
-the ASIC model predicts for the same layer.
+instrumented dispatch (``kernels.ops``, ``core.carla.carla_conv``) called
+eagerly opens a span that captures the mode the controller picked, the
+operand shapes, the wall time (callers sync with ``jax.block_until_ready``
+inside the span), the bytes the arrays touch, and — for ``carla_conv`` — the
+analytic ``LayerCost`` the ASIC model predicts for the same layer.
+
+Inside a ``jax.jit`` there is nothing to time: a dispatch traced there opens
+no span (:func:`timed` is false for tracer inputs) and its record is the
+``jax.named_scope`` it runs under, which names its device ops in the compiled
+program and in a ``jax.profiler`` trace.  Each span also enters
+``jax.profiler.TraceAnnotation(name)``, so when a profiler trace is being
+recorded the spans land on its host plane, on the same clock as the device's
+ops, and the trace opens in Perfetto with both.
 
 Design constraints:
 
   * **Zero overhead when disabled** (the default).  Instrumented call sites
-    gate on ``trace.enabled()`` — a single module-attribute read — and call
-    the jitted function directly when tracing is off.  No span objects, no
-    context managers, no clock reads on the disabled path.
+    gate on ``trace.timed(x)`` — a single module-attribute read when
+    tracing is off — and call the jitted function directly.  No span
+    objects, no clock reads on the disabled path.
   * **Nesting** — spans opened while another span is active become children
     (thread-local stack), so a ``carla_conv`` span contains the
     ``kernels.conv2d`` span it dispatched to.
@@ -29,6 +37,8 @@ import time
 from contextlib import contextmanager
 from dataclasses import dataclass, field
 from typing import Any, Iterator
+
+import jax
 
 
 @dataclass
@@ -111,7 +121,8 @@ class Tracer:
         t0 = time.perf_counter()
         sp.start_s = t0
         try:
-            yield sp
+            with jax.profiler.TraceAnnotation(name):
+                yield sp
         finally:
             sp.duration_s = time.perf_counter() - t0
             stack.pop()
@@ -150,6 +161,12 @@ tracer = Tracer()
 def enabled() -> bool:
     """The hot-path gate: one global read, nothing else."""
     return tracer.enabled
+
+
+def timed(x) -> bool:
+    """Whether a dispatch on ``x`` records a timed span: tracing is on and
+    ``x`` is a concrete array, not a tracer inside a ``jax.jit``."""
+    return tracer.enabled and not isinstance(x, jax.core.Tracer)
 
 
 def enable() -> None:

@@ -22,16 +22,11 @@ def _run(*argv, env_extra=None):
 
 
 @pytest.mark.slow
-def test_telemetry_report_smoke_cli(tmp_path):
-    chrome = str(tmp_path / "trace.json")
-    r = _run("benchmarks.telemetry_report", "--smoke", "--chrome", chrome)
+def test_telemetry_report_smoke_cli():
+    r = _run("benchmarks.telemetry_report", "--smoke")
     assert r.returncode == 0, r.stderr
     assert "smoke_3x3" in r.stdout
     assert "modes:" in r.stdout
-    with open(chrome) as f:
-        doc = json.load(f)
-    assert any(e["ph"] == "X" and e["name"] == "carla_conv"
-               for e in doc["traceEvents"])
 
 
 @pytest.mark.slow

@@ -54,6 +54,43 @@ def test_disabled_mode_records_nothing():
     assert trace.tracer.spans == []
 
 
+def test_no_timed_span_inside_jit_the_scope_names_the_layer():
+    """Traced inside a jit, carla_conv opens no span (a span there would fire
+    once, at trace time, and time the compile); the layer's named scope is
+    its record in the compiled program.  Eager, the span is there."""
+    x = jnp.ones((1, 8, 8, 4))
+    w = jnp.ones((3, 3, 4, 8))
+    trace.enable()
+    f = jax.jit(lambda x, w: carla_conv(x, w, padding=1, name="res_l1"))
+    f(x, w).block_until_ready()
+    assert trace.tracer.spans == []
+    assert "/res_l1/" in f.lower(x, w).compile().as_text()
+    carla_conv(x, w, padding=1, name="res_l1")
+    assert [s.name for s in trace.tracer.spans] == ["carla_conv"]
+    assert [c.name for c in trace.tracer.spans[0].children] == ["kernels.conv2d"]
+
+
+def test_span_lands_on_the_profiler_host_plane(tmp_path):
+    """An enabled span also enters jax.profiler.TraceAnnotation, so a
+    profiler trace holds it on its host plane, beside the device's ops."""
+    import glob
+    from jax.profiler import ProfileData
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with trace.capture() as tr:
+            with trace.span("res.host_span"):
+                jnp.ones(4).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    assert [s.name for s in tr.spans] == ["res.host_span"]
+    files = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    assert len(files) == 1
+    names = {e.name for plane in ProfileData.from_file(files[0]).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for e in line.events}
+    assert "res.host_span" in names
+
+
 def test_json_roundtrip_exact():
     trace.enable()
     with trace.span("a", mode="3x3", n=7):

@@ -1,6 +1,5 @@
-"""Export-layer tests: Chrome trace_event structure, Prometheus exposition,
-the HTTP exporter, the JSONL event log (and its instrumentation sites), and
-the perf-regression gate."""
+"""Export-layer tests: Prometheus exposition, the HTTP exporter, the JSONL
+event log (and its instrumentation sites), and the perf-regression gate."""
 import json
 import urllib.request
 
@@ -8,13 +7,11 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.core import carla_conv
 from repro.observability import (
     MetricsExporter,
     MetricsRegistry,
     events,
     prom,
-    to_chrome_trace,
     trace,
 )
 
@@ -28,80 +25,6 @@ def _clean_state():
     trace.disable()
     trace.clear()
     events.uninstall()
-
-
-def _traced_conv_spans():
-    x = jnp.ones((1, 14, 14, 8))
-    w = jnp.ones((3, 3, 8, 16))
-    with trace.capture() as tr:
-        carla_conv(x, w, padding=1, name="l1")
-    return tr.spans
-
-
-# ------------------------- chrome trace exporter ------------------------------
-def test_chrome_trace_structure_from_carla_conv():
-    """A carla_conv trace must produce Perfetto-loadable trace events with
-    complete spans, counter tracks for the analytic cost, and flow arrows."""
-    doc = to_chrome_trace(_traced_conv_spans())
-    payload = json.loads(json.dumps(doc))           # must be pure JSON
-    evs = payload["traceEvents"]
-
-    xev = [e for e in evs if e["ph"] == "X"]
-    assert [e["name"] for e in xev] == ["carla_conv", "kernels.conv2d"]
-    for e in xev:
-        for k in ("ts", "dur", "pid", "tid", "args"):
-            assert k in e, e
-        assert e["ts"] >= 0 and e["dur"] > 0
-    # child starts within the parent and on the same track here
-    parent, child = xev
-    assert parent["ts"] <= child["ts"]
-    assert child["ts"] + child["dur"] <= parent["ts"] + parent["dur"] + 1e-3
-
-    counters = [e for e in evs if e["ph"] == "C"]
-    assert counters, "analytic-cost counter tracks missing"
-    names = {e["name"] for e in counters}
-    assert "carla predicted vs measured (ms)" in names
-    pvm = next(e for e in counters
-               if e["name"] == "carla predicted vs measured (ms)")
-    assert pvm["args"]["analytic_ms"] > 0
-    assert pvm["args"]["measured_ms"] > 0
-
-    flows = [e for e in evs if e["ph"] in ("s", "f")]
-    assert len(flows) == 2
-    start, finish = (e for e in flows)
-    assert start["ph"] == "s" and finish["ph"] == "f"
-    assert start["id"] == finish["id"]
-
-    meta = [e for e in evs if e["ph"] == "M"]
-    assert {e["name"] for e in meta} >= {"process_name", "thread_name"}
-
-
-def test_chrome_trace_roundtrips_through_span_json():
-    """Export must work on a trace restored from Tracer.to_json (offline)."""
-    spans = _traced_conv_spans()
-    restored = trace.tracer.from_json(
-        json.dumps([s.to_dict() for s in spans]))
-    doc = to_chrome_trace(restored)
-    assert doc["traceEvents"] == to_chrome_trace(spans)["traceEvents"]
-
-
-def test_chrome_trace_separates_threads():
-    import threading
-
-    trace.enable()
-    with trace.span("main_work"):
-        pass
-
-    def worker():
-        with trace.span("thread_work"):
-            pass
-
-    t = threading.Thread(target=worker)
-    t.start()
-    t.join()
-    doc = to_chrome_trace(trace.tracer.spans)
-    xev = {e["name"]: e for e in doc["traceEvents"] if e["ph"] == "X"}
-    assert xev["main_work"]["tid"] != xev["thread_work"]["tid"]
 
 
 # ----------------------- prometheus exposition --------------------------------

@@ -7,12 +7,16 @@ lower to exactly one Mosaic kernel.  The topology is described inside a
 fixture, never at import, so only the worker that runs this file loads the
 TPU library.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core.autotune import TileConfig
+from repro.core.carla import carla_conv
+from repro.core.fuse import Epilogue
 from repro.kernels import ops
 
 KERNEL_CALL = 'custom_call_target="tpu_custom_call"'
@@ -89,3 +93,59 @@ def test_kernel_compiles_for_v5e(name, one_chip):
     compiled = jax.jit(fwd).lower(spec(*xs), spec(*ws), spec(k), spec(k),
                                   res).compile()
     assert compiled.as_text().count(KERNEL_CALL) == 1
+
+
+def _named_custom_calls(text: str) -> list[str]:
+    """The ``op_name`` of every Mosaic kernel in a compiled program's text."""
+    return [re.search(r'op_name="([^"]*)"', line).group(1)
+            for line in text.splitlines() if KERNEL_CALL in line]
+
+
+def _stem_and_bottleneck(spec):
+    """ResNet-50's stem and its first bottleneck, each conv named as
+    ``resnet50_apply`` names it."""
+    def fwd(x, w1, proj, c1, c2, c3):
+        ep = Epilogue(relu=True)
+        h = carla_conv(x, w1, stride=2, padding=3, impl="pallas", epilogue=ep,
+                       name="conv1")[:, ::2, ::2]
+        sc = carla_conv(h, proj, impl="pallas", name="conv2_b0_proj")
+        y = carla_conv(h, c1, impl="pallas", epilogue=ep, name="conv2_b0_1x1a")
+        y = carla_conv(y, c2, padding=1, impl="pallas", epilogue=ep,
+                       name="conv2_b0_3x3")
+        return carla_conv(y, c3, impl="pallas", name="conv2_b0_1x1b",
+                          epilogue=Epilogue(relu=True, residual=sc))
+    args = (spec(1, 224, 224, 3), spec(7, 7, 3, 64), spec(64, 256),
+            spec(64, 64), spec(3, 3, 64, 64), spec(64, 256))
+    kernels = {"conv1": "_mm_act_stationary_kernel",
+               "conv2_b0_proj": "_mm_act_stationary_kernel",
+               "conv2_b0_1x1a": "_mm_act_stationary_kernel",
+               "conv2_b0_3x3": "_conv2d_kernel",
+               "conv2_b0_1x1b": "_mm_act_stationary_kernel"}
+    return fwd, args, kernels
+
+
+def _conv5_1x1(spec):
+    """A conv5 1x1 at batch 1 (49 rows): the weight-stationary GEMM."""
+    def fwd(x, w):
+        return carla_conv(x, w, impl="pallas", name="conv5_b1_1x1a")
+    return fwd, (spec(1, 7, 7, 2048), spec(2048, 512)), \
+        {"conv5_b1_1x1a": "_mm_weight_stationary_kernel"}
+
+
+@pytest.mark.parametrize("program", [_stem_and_bottleneck, _conv5_1x1])
+def test_layer_and_kernel_names_reach_the_compiled_program(program, one_chip):
+    """Under an outer jit, as users run the forward, every kernel carries its
+    layer's scope and its own ``pallas_call`` name in ``op_name``, and the
+    stem's patches carry ``im2col``: the names a profiler trace shows."""
+    def spec(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    fwd, args, kernels = program(spec)
+    text = jax.jit(fwd).lower(*args).compile().as_text()
+    calls = _named_custom_calls(text)
+    assert len(calls) == len(kernels)
+    for layer, kernel in kernels.items():
+        assert sum(f"/{layer}/" in c and f"/{kernel}/" in c for c in calls) == 1
+    if "conv1" in kernels:
+        assert re.search(r'op_name="[^"]*/conv1/jit\(_conv2d_jit\)/im2col/', text)
+        assert sum("/conv1/" in c and "/gemm/" in c for c in calls) == 1
