@@ -124,12 +124,15 @@ def gemm_key(m: int, c: int, k: int, dtype, epilogue: str = "none") -> str:
 def conv2d_gemm_shape(x_shape, w_shape, stride: int,
                       padding: int) -> tuple[int, int, int]:
     """(M, C, K) of the im2col GEMM a strided conv runs as on the Pallas path:
-    one row per output pixel, one column per (tap, input channel)."""
+    one row per output pixel, one column per (tap, input channel) of the
+    filter padded with zero taps to ``stride * ceil(F / stride)`` a side
+    (``kernels.ops._im2col``): 192 columns for the 7x7/2 stem, not 147."""
     b, h, w, cin = x_shape
     fh, fw, _, k = w_shape
     oh = (h - fh + 2 * padding) // stride + 1
     ow = (w - fw + 2 * padding) // stride + 1
-    return b * oh * ow, fh * fw * cin, k
+    return (b * oh * ow,
+            _ceil_to(fh, stride) * _ceil_to(fw, stride) * cin, k)
 
 
 def _ep_none(key: str) -> str:
@@ -433,11 +436,13 @@ def gemm_candidates(m: int, c: int, k: int, *,
 def tile_util_conv2d(x_shape, w_shape, tiles: TileConfig | None = None, *,
                      stride: int = 1, padding: int = 0) -> float:
     """Logical FLOPs / padded FLOPs under the conv kernel's channel tiling,
-    or, for a strided conv, under the tiling of its im2col GEMM."""
+    or, for a strided conv, under the tiling of its im2col GEMM, whose zero
+    taps count as padding."""
     if stride > 1:
         m, c, k = conv2d_gemm_shape(x_shape, w_shape, stride, padding)
-        return tile_util_gemm(m, c, k, tiles,
-                              stationarity=select_stationarity(m).value)
+        taps = w_shape[0] * w_shape[1] * w_shape[2]
+        return taps / c * tile_util_gemm(
+            m, c, k, tiles, stationarity=select_stationarity(m).value)
     cin, k = w_shape[2], w_shape[3]
     bk = _clamp((tiles.bk if tiles and tiles.bk else DEFAULT_CONV2D.bk), k)
     bc = _clamp((tiles.bc if tiles and tiles.bc else DEFAULT_CONV2D.bc), cin)

@@ -13,7 +13,8 @@ The paper's §III.A dataflow, transplanted to the TPU memory hierarchy:
   loop level and the 7x7 decomposition lives only in the analytic model.
 * **Unit stride only**: every tap window is a plain shifted slice.  Mosaic
   refuses a strided in-kernel slice, so ``kernels.ops`` runs strided convs
-  (ResNet-50's 7x7/2 stem) as an im2col GEMM on the matmul kernels instead.
+  (ResNet-50's 7x7/2 stem) as an im2col GEMM on the matmul kernels instead,
+  its patches built by space-to-depth and unit-stride slices in XLA.
 * **Feedback-path reuse**: the input spatial block is fetched to VMEM *once*
   per (batch, channel-block) and re-read for every tap — the halo rows are
   never re-fetched from HBM, which is exactly the economics of the paper's

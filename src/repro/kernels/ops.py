@@ -43,6 +43,11 @@ is the name of the ops: the strided route's patches run under
 ``jax.named_scope("im2col")`` and its GEMM under ``"gemm"``, inside the
 layer's scope that ``carla_conv`` opens, and each Pallas kernel keeps its
 ``pallas_call`` name.
+
+A strided conv (``stride > 1``, ResNet-50's 7x7/2 stem) runs as one GEMM on
+the matmul kernels, over patches built by space-to-depth and unit-stride
+slices (:func:`_im2col`), never by strided indexing, which XLA lowers to one
+gather per tap.
 """
 from __future__ import annotations
 
@@ -110,16 +115,36 @@ def _tuning_attrs(sp, entry, tiles: TileConfig | None) -> None:
     sp.attrs["tuning_source"] = entry.source if entry is not None else "default"
 
 
-def _im2col(x, fh: int, fw: int, stride: int, padding: int):
-    """(B, H, W, C) -> (B, OH, OW, FH*FW*C) patches in HWIO tap order."""
-    _, h, wd, _ = x.shape
-    oh = (h - fh + 2 * padding) // stride + 1
-    ow = (wd - fw + 2 * padding) // stride + 1
-    xp = jnp.pad(x, ((0, 0), (padding, padding), (padding, padding), (0, 0)))
-    return jnp.concatenate(
-        [xp[:, r:r + stride * (oh - 1) + 1:stride,
-            s:s + stride * (ow - 1) + 1:stride, :]
-         for r in range(fh) for s in range(fw)], axis=-1)
+def _im2col(x, w, stride: int, padding: int):
+    """Patches and weights of a strided conv as one GEMM, by space-to-depth.
+
+    The padded input is folded into ``S*S*C`` channels, one per (row phase,
+    column phase, channel), so each group of taps ``(r, q)`` of the filter,
+    ``TH = ceil(FH/S)`` by ``TW = ceil(FW/S)`` of them, is a unit-stride
+    window of the folded input: reshapes, one transpose and static slices,
+    no strided index (which XLA lowers to a gather).  The weights are padded
+    with zero taps to ``(S*TH, S*TW)`` and folded the same way.  Returns
+    ``(B, OH, OW, TH*TW*S*S*C)`` patches and ``(TH*TW*S*S*C, K)`` weights.
+    """
+    b, h, wd, c = x.shape
+    fh, fw, _, k = w.shape
+    s = stride
+    oh = (h - fh + 2 * padding) // s + 1
+    ow = (wd - fw + 2 * padding) // s + 1
+    th, tw = -(-fh // s), -(-fw // s)
+    hp, wp = s * (oh + th - 1), s * (ow + tw - 1)
+    # the bottom and right pads may be negative: rows no output reads
+    xp = jax.lax.pad(x, jnp.zeros((), x.dtype),
+                     ((0, 0, 0), (padding, hp - h - padding, 0),
+                      (padding, wp - wd - padding, 0), (0, 0, 0)))
+    xs = (xp.reshape(b, hp // s, s, wp // s, s * c)
+          .transpose(0, 1, 3, 2, 4).reshape(b, hp // s, wp // s, s * s * c))
+    p = jnp.concatenate([xs[:, r:r + oh, q:q + ow, :]
+                         for r in range(th) for q in range(tw)], axis=-1)
+    wz = jnp.pad(w, ((0, s * th - fh), (0, s * tw - fw), (0, 0), (0, 0)))
+    wf = (wz.reshape(th, s, tw, s, c, k).transpose(0, 2, 1, 3, 4, 5)
+          .reshape(th * tw * s * s * c, k))
+    return p, wf
 
 
 @functools.partial(
@@ -134,14 +159,17 @@ def _conv2d_jit(x, w, scale=None, bias=None, residual=None, *,
     if stride > 1:
         # Strided convs (ResNet-50's 7x7/2 stem) run as one GEMM over im2col
         # patches: Mosaic refuses a strided slice inside the conv2d kernel,
-        # and K = FH*FW*C fills lanes that a 3-channel input block would not.
-        fh, fw, _, k = w.shape
+        # and the patch columns fill lanes that a 3-channel input block would
+        # not.  For the stem, 16 slices of a (B, 115, 115, 12) space-to-depth
+        # give 192 columns, 45 of them zero taps inside the 256 lanes that
+        # 147 would occupy anyway.
+        k = w.shape[-1]
         with jax.named_scope("im2col"):
-            p = _im2col(x, fh, fw, stride, padding)
+            p, wf = _im2col(x, w, stride, padding)
         b, oh, ow, kk = p.shape
         rf = residual.reshape(b * oh * ow, k) if residual is not None else None
         with jax.named_scope("gemm"):
-            out = _tiled_matmul(p.reshape(b * oh * ow, kk), w.reshape(kk, k),
+            out = _tiled_matmul(p.reshape(b * oh * ow, kk), wf,
                                 scale, bias, relu, rf, tiles)
         return out.reshape(b, oh, ow, k)
     kw = {}

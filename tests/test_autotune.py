@@ -117,12 +117,14 @@ def test_gemm_candidates_cover_both_stationarities():
 
 @pytest.mark.parametrize("batch", [1, 8])
 def test_stem_gemm_candidates_are_lane_legal(batch):
-    """ResNet-50's 7x7/2 stem runs as a (B*12544, 147) @ (147, 64) GEMM.  On
-    a TPU its channel tiles must be whole axes or 128-lane multiples, so no
-    candidate gets a bc below 128 (the old conv2d entry had bc=3)."""
+    """ResNet-50's 7x7/2 stem runs as a (B*12544, 192) @ (192, 64) GEMM: its
+    7x7 filter padded with zero taps to 8x8, folded by space-to-depth into
+    4x4 taps of 12 channels.  On a TPU its channel tiles must be whole axes
+    or 128-lane multiples, so no candidate gets a bc below 128 (the old
+    conv2d entry had bc=3)."""
     m, c, k = autotune.conv2d_gemm_shape((batch, 224, 224, 3), (7, 7, 3, 64),
                                          2, 3)
-    assert (m, c, k) == (batch * 112 * 112, 147, 64)
+    assert (m, c, k) == (batch * 112 * 112, 192, 64)
     cands = autotune.gemm_candidates(m, c, k, max_candidates=64)
     assert {t.stationarity for t in cands} == {"weight_stationary",
                                                "activation_stationary"}
@@ -143,12 +145,14 @@ def test_conv2d_candidates_are_lane_legal():
 def test_strided_conv_tunes_as_its_im2col_gemm(iso):
     """A strided conv's tiles are GEMM tiles: an entry under its conv2d key
     is never read, the entry under its GEMM key is, and tile_util describes
-    the GEMM that ran."""
+    the GEMM that ran, its zero taps (147 of 192 columns are real) counted
+    as padding."""
     autotune.enable()
     key = jax.random.PRNGKey(5)
     x = jax.random.normal(key, (1, 16, 16, 3))
     w = jax.random.normal(jax.random.fold_in(key, 1), (7, 7, 3, 8))
     m, c, k = autotune.conv2d_gemm_shape(x.shape, w.shape, 2, 3)
+    real = 7 * 7 * 3 / c
     autotune.put(conv2d_key(x.shape, w.shape, 2, 3, x.dtype),
                  TileConfig(bk=8, bc=3))
     with trace.capture() as tr:
@@ -156,8 +160,9 @@ def test_strided_conv_tunes_as_its_im2col_gemm(iso):
     (ksp,) = tr.spans[0].children
     assert ksp.attrs["tuned"] is False
     assert ksp.attrs["kernel"] == "im2col_gemm"
-    assert ksp.attrs["tile_util"] == autotune.tile_util_gemm(
-        m, c, k, stationarity=ksp.attrs["stationarity"])
+    assert ksp.attrs["tile_util"] == pytest.approx(
+        real * autotune.tile_util_gemm(
+            m, c, k, stationarity=ksp.attrs["stationarity"]))
 
     tiles = TileConfig(bm=32, bk=8, bc=c, stationarity="activation_stationary")
     autotune.put(gemm_key(m, c, k, x.dtype), tiles)
@@ -169,7 +174,7 @@ def test_strided_conv_tunes_as_its_im2col_gemm(iso):
         assert s.attrs["tuned"] is True
         assert s.attrs["tile_config"] == tiles.short
         assert s.attrs["tile_util"] == pytest.approx(
-            autotune.tile_util_gemm(m, c, k, tiles))
+            real * autotune.tile_util_gemm(m, c, k, tiles))
     assert ksp.attrs["stationarity"] == "activation_stationary"
     want = ref.conv2d_ref(x, w, stride=2, padding=3)
     assert float(jnp.max(jnp.abs(out - want))) < 1e-3

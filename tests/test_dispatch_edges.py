@@ -110,3 +110,62 @@ def test_pallas_conv2d_span_records_the_kernel_that_ran(stride, padding,
     assert kernel_sp.attrs["kernel"] == kernel
     want = conv2d_ref(x, w, stride=stride, padding=padding)
     assert float(jnp.max(jnp.abs(got - want))) < 1e-4
+
+
+# ------------- strided conv2d: space-to-depth im2col parity -------------------
+# (h, c, k, fl, stride, pad): strides 2 and 3, filters 3/5/7, paddings below
+# stride - 1 (the padded input is cropped), and sizes where
+# (h + 2p - fl) % stride != 0 (rows no output reads)
+S2D_CASES = [
+    (224, 3, 64, 7, 2, 3),   # ResNet-50's stem
+    (17, 3, 8, 7, 2, 3),     # odd size, (17 + 6 - 7) % 2 == 0
+    (16, 4, 8, 3, 2, 1),
+    (16, 4, 8, 3, 2, 0),     # crop; (16 - 3) % 2 == 1
+    (15, 2, 8, 5, 3, 2),
+    (16, 5, 8, 5, 3, 1),     # crop; (16 + 2 - 5) % 3 == 1
+    (13, 3, 8, 3, 3, 0),     # crop; (13 - 3) % 3 == 1
+    (18, 3, 8, 5, 2, 2),     # (18 + 4 - 5) % 2 == 1
+]
+
+
+@pytest.mark.parametrize("fused", [False, True])
+@pytest.mark.parametrize("h,c,k,fl,s,p", S2D_CASES)
+def test_strided_conv2d_space_to_depth_matches_reference(h, c, k, fl, s, p,
+                                                         fused):
+    """The strided route builds its patches by space-to-depth and its weights
+    with zero taps, so a conv of any stride, filter and padding equals the
+    plain conv, with and without the fused scale/bias/residual/ReLU."""
+    from repro.core.fuse import Epilogue
+    from repro.kernels import ops
+    from repro.kernels.ref import conv2d_ref
+    key = jax.random.PRNGKey(h * 10 + fl + s)
+    x = jax.random.normal(key, (2 if h < 64 else 1, h, h, c))
+    w = jax.random.normal(jax.random.fold_in(key, 1), (fl, fl, c, k))
+    oh = (h + 2 * p - fl) // s + 1
+    kw = {}
+    if fused:
+        kw = dict(scale=jax.random.normal(jax.random.fold_in(key, 2), (k,)),
+                  bias=jax.random.normal(jax.random.fold_in(key, 3), (k,)),
+                  residual=jax.random.normal(jax.random.fold_in(key, 4),
+                                             (x.shape[0], oh, oh, k)),
+                  relu=True)
+    got = ops.conv2d(x, w, stride=s, padding=p, impl="pallas",
+                     epilogue=Epilogue(**kw))
+    want = conv2d_ref(x, w, stride=s, padding=p, **kw)
+    assert got.shape == want.shape == (x.shape[0], oh, oh, k)
+    err = float(jnp.max(jnp.abs(got - want)) / jnp.max(jnp.abs(want)))
+    assert err < 1e-5, err
+
+
+def test_strided_im2col_has_no_gather():
+    """The stem's patches are slices of a space-to-depth of its input: 16
+    unit-stride windows of 12 channels (192 GEMM columns, 45 of them zero
+    taps), and no gather, which a strided index would lower to."""
+    from repro.kernels import ops
+    x = jnp.zeros((1, 224, 224, 3))
+    w = jnp.zeros((7, 7, 3, 64))
+    p, wf = ops._im2col(x, w, 2, 3)
+    assert p.shape == (1, 112, 112, 192) and wf.shape == (192, 64)
+    jaxpr = str(jax.make_jaxpr(lambda x, w: ops._im2col(x, w, 2, 3))(x, w))
+    assert "gather" not in jaxpr
+    assert jaxpr.count(" slice[") == 16

@@ -58,7 +58,7 @@ CASES = {
     # conv5 1x1 at M=49 rows: weight-stationary
     "1x1_conv5_ws": ((1, 7, 7, 2048), (1, 1, 2048, 512), 1, 0, False),
     "1x1_c256_b8": ((8, 56, 56, 256), (1, 1, 256, 64), 1, 0, False),
-    # the stem GEMM under a tuned entry that splits its 147 patch columns
+    # the stem GEMM under a tuned entry that splits its 192 patch columns
     # into two 128-lane blocks
     "stem_7x7s2_bc128": ((1, 224, 224, 3), (7, 7, 3, 64), 2, 3, False),
 }
@@ -149,3 +149,20 @@ def test_layer_and_kernel_names_reach_the_compiled_program(program, one_chip):
     if "conv1" in kernels:
         assert re.search(r'op_name="[^"]*/conv1/jit\(_conv2d_jit\)/im2col/', text)
         assert sum("/conv1/" in c and "/gemm/" in c for c in calls) == 1
+
+
+def test_stem_compiles_without_a_gather(one_chip):
+    """The stem's patches come from a space-to-depth of its input, so the
+    compiled forward holds no gather under ``conv1`` (a strided index there
+    lowers to one gather per tap, which took most of the stem's time on a
+    v5e), and the stem is one Mosaic kernel, its GEMM."""
+    def spec(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    fwd, args, _ = _stem_and_bottleneck(spec)
+    text = jax.jit(fwd).lower(*args).compile().as_text()
+    gathers = [line for line in text.splitlines()
+               if re.search(r"\bgather\(", line) and "/conv1/" in line]
+    assert gathers == []
+    stem = [c for c in _named_custom_calls(text) if "/conv1/" in c]
+    assert len(stem) == 1 and "/gemm/" in stem[0]
