@@ -9,7 +9,8 @@ every unique (layer shape, dtype, epilogue, backend) key of a network it
      ``bk/bc`` channel tiles for the serial-accumulation conv kernel,
      ``bm/bk/bc`` tiles x both stationarities for the dual-residency GEMM
      (1x1 layers flatten to their GEMM shape, so ``conv1x1``/``gemm`` share
-     entries, and strided convs key by the shape of their im2col GEMM);
+     entries, and the convs that run as an im2col GEMM, strided or with few
+     patch columns, key by that GEMM's shape);
   2. times each candidate through the jitted kernel wrappers
      (best-of-``reps`` wall time, compile excluded), *including the hardcoded
      defaults* — the PR 8 operating point;
@@ -75,13 +76,13 @@ def _conv_shapes(layer, batch: int):
 
 def _gemm_shape(layer, batch: int):
     """(M, C, K) of the GEMM a layer runs as: a 1x1 flattens to its strided
-    view's rows, a strided conv to its im2col patches.  None for a layer that
-    runs the conv2d kernel."""
+    view's rows, a conv that ``autotune.runs_as_gemm`` routes to its im2col
+    patches.  None for a layer that runs the conv2d kernel."""
     if layer.FL == 1:
         return _gemm_rows(layer, batch), layer.IC, layer.K
-    if layer.S > 1:
-        return autotune.conv2d_gemm_shape(*_conv_shapes(layer, batch),
-                                          layer.S, layer.Z)
+    x_shape, w_shape = _conv_shapes(layer, batch)
+    if autotune.runs_as_gemm(w_shape, layer.S):
+        return autotune.conv2d_gemm_shape(x_shape, w_shape, layer.S, layer.Z)
     return None
 
 

@@ -11,8 +11,9 @@ an MMIE-style per-layer operating point chosen by measurement:
   * **Key**: ``(op kind, layer shape, dtype, epilogue signature)`` rendered as
     a flat string (backend lives in the table header, not the key).  1x1 convs
     flatten to their GEMM shape so ``conv1x1`` and ``gemm`` share entries,
-    and strided convs, which the Pallas path runs as an im2col GEMM, key by
-    that GEMM's shape (:func:`conv2d_gemm_shape`).
+    and the convs that the Pallas path runs as an im2col GEMM (strided, or
+    with a patch row of at most 128 lanes: :func:`runs_as_gemm`) key by that
+    GEMM's shape (:func:`conv2d_gemm_shape`).
   * **Entry**: the winning :class:`TileConfig` — tile sizes plus, for GEMM
     shapes, the stationarity (dataflow) choice itself — with the measured
     tuned/default wall times and where the entry came from (``table`` =
@@ -121,12 +122,23 @@ def gemm_key(m: int, c: int, k: int, dtype, epilogue: str = "none") -> str:
     return f"gemm|m{m}|c{c}|k{k}|{dtype}|ep:{epilogue}"
 
 
+def runs_as_gemm(w_shape, stride: int) -> bool:
+    """Whether the Pallas path runs a conv (not a 1x1) as an im2col GEMM:
+    a strided one, since Mosaic refuses a strided slice inside the conv2d
+    kernel, or a unit-stride one whose patch row ``FH*FW*C`` fits one
+    128-lane tile (VGG-16's conv1_1: 27 columns), which the conv2d kernel
+    would contract 3 lanes at a time.  The one place that decides it."""
+    fh, fw, cin, _ = w_shape
+    return stride > 1 or (fh * fw > 1 and fh * fw * cin <= 128)
+
+
 def conv2d_gemm_shape(x_shape, w_shape, stride: int,
                       padding: int) -> tuple[int, int, int]:
-    """(M, C, K) of the im2col GEMM a strided conv runs as on the Pallas path:
-    one row per output pixel, one column per (tap, input channel) of the
-    filter padded with zero taps to ``stride * ceil(F / stride)`` a side
-    (``kernels.ops._im2col``): 192 columns for the 7x7/2 stem, not 147."""
+    """(M, C, K) of the im2col GEMM a conv runs as on the Pallas path
+    (:func:`runs_as_gemm`): one row per output pixel, one column per (tap,
+    input channel) of the filter padded with zero taps to
+    ``stride * ceil(F / stride)`` a side (``kernels.ops._im2col``): 192
+    columns for the 7x7/2 stem, not 147; 27 for VGG-16's conv1_1."""
     b, h, w, cin = x_shape
     fh, fw, _, k = w_shape
     oh = (h - fh + 2 * padding) // stride + 1
@@ -262,8 +274,8 @@ def lookup(key: str) -> Entry | None:
 
 def lookup_conv2d(x_shape, w_shape, stride, padding, dtype,
                   epilogue: str = "none") -> Entry | None:
-    """A strided conv runs as an im2col GEMM, so its tiles are GEMM tiles."""
-    if stride > 1:
+    """A conv that runs as an im2col GEMM has GEMM tiles."""
+    if runs_as_gemm(w_shape, stride):
         return lookup_gemm(*conv2d_gemm_shape(x_shape, w_shape, stride,
                                               padding), dtype, epilogue)
     return lookup(conv2d_key(x_shape, w_shape, stride, padding, dtype,
@@ -356,16 +368,17 @@ def conv2d_candidates(x_shape, w_shape, *, stride: int = 1, padding: int = 0,
     """Tile candidates for the serial-accumulation conv kernel.
 
     Seeded by the cost model: candidates are ranked by padded-FLOPs waste
-    (channel pads to ``bc``/``bk`` multiples), then grid-step count, then the
-    VMEM footprint of the resident input block + weight tile + accumulator.
-    The kernel defaults are always included.  Strided convs run as an im2col
-    GEMM and take :func:`gemm_candidates` of :func:`conv2d_gemm_shape`.
+    (channel and last-row-block pads), then grid-step count, row blocks
+    included, each from the tiles the kernel would run
+    (``kernels.conv2d.step_tiles``).  Every candidate fits VMEM, since the
+    kernel picks its row blocks to fit.
+    The kernel defaults are always included.  A conv that runs as an im2col
+    GEMM (:func:`runs_as_gemm`) takes :func:`gemm_candidates` of
+    :func:`conv2d_gemm_shape` instead.
     """
-    _, h, w, cin = x_shape
-    fh, fw, _, k = w_shape
-    oh = (h - fh + 2 * padding) // stride + 1
-    ow = (w - fw + 2 * padding) // stride + 1
-    hp, wp = h + 2 * padding, w + 2 * padding
+    from repro.kernels.conv2d import step_tiles
+    cin, k = x_shape[3], w_shape[3]
+    oh = (x_shape[1] - w_shape[0] + 2 * padding) // stride + 1
 
     cands = {(_clamp(DEFAULT_CONV2D.bk, k), _clamp(DEFAULT_CONV2D.bc, cin))}
     for bk in _lane_tiles(k):
@@ -373,11 +386,12 @@ def conv2d_candidates(x_shape, w_shape, *, stride: int = 1, padding: int = 0,
             cands.add((bk, bc))
 
     def score(cand):
-        bk, bc = cand
-        waste = (_ceil_to(k, bk) * _ceil_to(cin, bc)) / (k * cin)
-        steps = -(-k // bk) * -(-cin // bc)
-        vmem = 4 * (hp * wp * bc + fh * fw * bc * bk + 2 * oh * ow * bk)
-        return (waste, steps, vmem > VMEM_BUDGET, -bk * bc)
+        th, bk, bc = step_tiles(x_shape, w_shape, padding=padding,
+                                bk=cand[0], bc=cand[1])
+        waste = (_ceil_to(k, bk) * _ceil_to(cin, bc) * _ceil_to(oh, th)
+                 / (k * cin * oh))
+        steps = -(-oh // th) * -(-k // bk) * -(-cin // bc)
+        return (waste, steps, -cand[0] * cand[1])
 
     ranked = sorted(cands, key=score)[:max_candidates]
     return [TileConfig(bk=bk, bc=bc) for bk, bc in ranked]
@@ -389,8 +403,9 @@ def gemm_candidates(m: int, c: int, k: int, *,
 
     Both stationarities are always represented (the empirical twin of the
     paper's §III.B/§III.C operand swap): weight-stationary keeps the whole
-    ``(M, C)`` activation resident and streams ``(C, bk)`` weight columns
-    once, so it is a candidate at *any* M, not just the analytic M < 128 rule.
+    ``(M, C)`` activation resident and streams each weight block once, in
+    ``kernels.matmul.ws_blocks``' C blocks where a whole-C block does not
+    fit, so it is a candidate at *any* M, not just the analytic M < 128 rule.
     Channel tiles are lane-legal (:func:`_lane_tiles`): the act-stationary
     kernel slices its resident block at ``c * bc`` lanes.
     """
@@ -415,9 +430,10 @@ def gemm_candidates(m: int, c: int, k: int, *,
     ws_cands = _lane_tiles(k)           # holds the clamped default bk
 
     def ws_score(bk):
-        waste = _ceil_to(k, bk) / k
-        return (waste, -(-k // bk), 4 * (m * c + c * bk + m * bk)
-                > VMEM_BUDGET, -bk)
+        from repro.kernels.matmul import ws_blocks
+        bk, bc = ws_blocks(c, k, bk)
+        waste = _ceil_to(k, bk) * _ceil_to(c, bc) / (k * c)
+        return (waste, -(-k // bk) * -(-c // bc), -bk)
 
     out = [TileConfig(bk=bk, stationarity="weight_stationary")
            for bk in sorted(ws_cands, key=ws_score)[:half]]
@@ -434,19 +450,26 @@ def gemm_candidates(m: int, c: int, k: int, *,
 # tile_util — padding waste, the TPU analogue of the paper's PUF
 # ---------------------------------------------------------------------------
 def tile_util_conv2d(x_shape, w_shape, tiles: TileConfig | None = None, *,
-                     stride: int = 1, padding: int = 0) -> float:
-    """Logical FLOPs / padded FLOPs under the conv kernel's channel tiling,
-    or, for a strided conv, under the tiling of its im2col GEMM, whose zero
-    taps count as padding."""
-    if stride > 1:
+                     stride: int = 1, padding: int = 0,
+                     has_res: bool = False) -> float:
+    """Logical FLOPs / padded FLOPs under the conv kernel's channel tiling
+    and row blocks (a last block that runs past the plane computes padded
+    rows), or, for a conv that runs as an im2col GEMM, under that GEMM's
+    tiling, whose zero taps count as padding."""
+    if runs_as_gemm(w_shape, stride):
         m, c, k = conv2d_gemm_shape(x_shape, w_shape, stride, padding)
         taps = w_shape[0] * w_shape[1] * w_shape[2]
         return taps / c * tile_util_gemm(
             m, c, k, tiles, stationarity=select_stationarity(m).value)
+    from repro.kernels.conv2d import step_tiles
     cin, k = w_shape[2], w_shape[3]
-    bk = _clamp((tiles.bk if tiles and tiles.bk else DEFAULT_CONV2D.bk), k)
-    bc = _clamp((tiles.bc if tiles and tiles.bc else DEFAULT_CONV2D.bc), cin)
-    return (cin * k) / (_ceil_to(cin, bc) * _ceil_to(k, bk))
+    oh = x_shape[1] - w_shape[0] + 2 * padding + 1
+    th, bk, bc = step_tiles(
+        x_shape, w_shape, padding=padding, has_res=has_res,
+        bk=tiles.bk if tiles and tiles.bk else DEFAULT_CONV2D.bk,
+        bc=tiles.bc if tiles and tiles.bc else DEFAULT_CONV2D.bc)
+    return (cin * k * oh) / (_ceil_to(cin, bc) * _ceil_to(k, bk)
+                             * _ceil_to(oh, th))
 
 
 def tile_util_gemm(m: int, c: int, k: int,
@@ -455,9 +478,12 @@ def tile_util_gemm(m: int, c: int, k: int,
     """Logical FLOPs / padded FLOPs for the GEMM under either stationarity."""
     st = (tiles.stationarity if tiles and tiles.stationarity
           else stationarity)
-    bk = _clamp((tiles.bk if tiles and tiles.bk else DEFAULT_GEMM.bk), k)
     if st == "weight_stationary":
-        return k / _ceil_to(k, bk)       # only K is padded; (M, C) resident
+        # (M, C) resident; K and, in C blocks, C are padded
+        from repro.kernels.matmul import ws_blocks
+        bk, bc = ws_blocks(c, k, tiles.bk if tiles and tiles.bk else None)
+        return (k * c) / (_ceil_to(k, bk) * _ceil_to(c, bc))
+    bk = _clamp((tiles.bk if tiles and tiles.bk else DEFAULT_GEMM.bk), k)
     bm = _clamp((tiles.bm if tiles and tiles.bm else DEFAULT_GEMM.bm), m)
     bc = _clamp((tiles.bc if tiles and tiles.bc else DEFAULT_GEMM.bc), c)
     return (m * c * k) / (_ceil_to(m, bm) * _ceil_to(c, bc) * _ceil_to(k, bk))

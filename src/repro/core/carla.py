@@ -154,7 +154,8 @@ def _timed_dispatch(x, w, plan: ConvPlan, stride: int, padding: int,
     else:
         tile_util = autotune.tile_util_conv2d(x.shape, w.shape,
                                               plan.tile_config, stride=stride,
-                                              padding=padding)
+                                              padding=padding,
+                                              has_res=ep.residual is not None)
     sparse_attrs = {}
     if sparsity is not None:
         sparse_attrs = {

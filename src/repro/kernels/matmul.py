@@ -11,10 +11,16 @@ residency choices, mirroring the paper's §III.B / §III.C reconfiguration:
   results living in the wide SRAM pair.  Use when M (tokens) >= one MXU tile:
   training / prefill.
 
-* **weight-stationary** (§III.C analogue): M is tiny (decode: one token per
-  sequence), so the whole activation ``(M, C)`` is resident and weight column
-  blocks ``(C, bk)`` stream through exactly once — Eq (11)'s "each filter
-  weight is only fetched once".  Use when M < one MXU tile: decode.
+* **weight-stationary** (§III.C analogue): M is tiny (batch-1 conv5 1x1s,
+  a classifier at a small batch), so the activation is resident and the
+  weights stream through exactly once — Eq (11)'s "each filter weight is
+  only fetched once".  When a whole-C column block ``(C, bk)`` fits VMEM
+  (ResNet-50's conv5 1x1s), the whole ``(M, C)`` activation stays resident
+  and each block is one grid step.  When it does not (VGG-16's fc6, C =
+  25088), a reduction axis over C streams ``(bc, bk)`` weight blocks, whole
+  weight rows where 128 of them fit, past ``(M, bc)`` slices of the
+  activation into an fp32 accumulator.  :func:`ws_blocks` chooses.  Use
+  when M < one MXU tile.
 
 Both kernels accept the same fused epilogue as ``conv2d``: per-column
 scale/bias (folded BN), a residual operand, and ReLU, applied on the fp32
@@ -42,6 +48,10 @@ from jax.experimental.pallas import tpu as pltpu
 # ``kernels.ops`` threads the cached winner through the keyword arguments
 # below.  ``core.autotune.DEFAULT_GEMM`` mirrors these values (test-enforced).
 BM, BK, BC = 128, 128, 512
+# VMEM for the weight-stationary kernel's streamed weight blocks, fp32,
+# double-buffered: blocks of up to 4 MiB keep the DMA engine busy between
+# grid steps.
+WS_BUDGET = 8 * 2**20
 
 
 def _pad_to(x: jnp.ndarray, axis: int, mult: int) -> jnp.ndarray:
@@ -160,55 +170,110 @@ def matmul_act_stationary(x: jnp.ndarray, w: jnp.ndarray, *,
 
 
 # ---------------------------- weight-stationary ------------------------------
-def _mm_weight_stationary_kernel(*refs, has_sb: bool, has_res: bool,
-                                 relu: bool):
-    """grid = (K/bk,); x fully resident; each weight block fetched once."""
+def _weight_vmem(rows: int, cols: int) -> int:
+    """VMEM of a double-buffered fp32 (rows, cols) weight block, tiled (8, 128)."""
+    return 2 * 4 * (-(-rows // 8) * 8) * (-(-cols // 128) * 128)
+
+
+def ws_blocks(c: int, k: int, bk: int | None = None) -> tuple[int, int]:
+    """``(bk, bc)`` of the weight-stationary GEMM; ``bc == c`` is one C block.
+
+    Unless a tuned ``bk`` is given, ``bk`` divides K (a whole-K block where
+    128 does not), so the weights are not copied to a padded array on each
+    call.  The whole C is one block when its ``(C, bk)`` weight block fits
+    :data:`WS_BUDGET`.  Otherwise the kernel streams whole weight rows
+    (``bk = K``) where 128 of them fit, each block then one contiguous
+    stretch of HBM, in the most rows that fit, preferring a count that
+    divides C."""
+    if bk is None:
+        bk = min(BK, k)
+        if k % bk:
+            bk = k
+    bk = min(bk, k)
+    if _weight_vmem(c, bk) <= WS_BUDGET:
+        return bk, c
+    if _weight_vmem(128, k) <= WS_BUDGET:
+        bk = k
+    fit = max(128, WS_BUDGET // _weight_vmem(128, bk) * 128)
+    divisors = [d for d in range(128, fit + 1, 128) if c % d == 0]
+    return bk, max(divisors) if divisors else fit
+
+
+def _mm_weight_stationary_kernel(*refs, n_c: int, has_sb: bool,
+                                 has_res: bool, relu: bool):
+    """grid = (K/bk,) with x whole and each weight block fetched once, or
+    (K/bk, C/bc), c innermost, into an fp32 accumulator."""
     it = iter(refs)
     x_ref, w_ref = next(it), next(it)
     sb_ref = next(it) if has_sb else None
     res_ref = next(it) if has_res else None
     o_ref = next(it)
-    y = mxu_dot(x_ref[...], w_ref[...])
-    o_ref[...] = _epilogue(y, sb_ref, res_ref, relu).astype(o_ref.dtype)
+    if n_c == 1:
+        y = mxu_dot(x_ref[...], w_ref[...])
+        o_ref[...] = _epilogue(y, sb_ref, res_ref, relu).astype(o_ref.dtype)
+        return
+    acc_ref = next(it)
+    c = pl.program_id(1)
+
+    @pl.when(c == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    acc_ref[...] += mxu_dot(x_ref[...], w_ref[...])
+
+    @pl.when(c == n_c - 1)
+    def _flush():
+        y = _epilogue(acc_ref[...], sb_ref, res_ref, relu)
+        o_ref[...] = y.astype(o_ref.dtype)
 
 
 def matmul_weight_stationary(x: jnp.ndarray, w: jnp.ndarray, *,
-                             bk: int = BK,
+                             bk: int | None = None,
                              scale: jnp.ndarray | None = None,
                              bias: jnp.ndarray | None = None,
                              relu: bool = False,
                              residual: jnp.ndarray | None = None,
                              interpret: bool) -> jnp.ndarray:
-    """(M, C) @ (C, K) with small M: the decode GEMV-like shape."""
+    """(M, C) @ (C, K) with small M: a weight stream (:func:`ws_blocks`)."""
     m, c = x.shape
     c2, k = w.shape
     assert c == c2, (x.shape, w.shape)
-    bk = min(bk, k)
-    wp = _pad_to(w, 1, bk)
-    kp = wp.shape[1]
+    bk, bc = ws_blocks(c, k, bk)
+    wp = _pad_to(_pad_to(w, 1, bk), 0, bc)
+    cp, kp = wp.shape
+    n_c = cp // bc
+
+    def spec(block, index):
+        """A BlockSpec on the grid that runs: with one C block the grid has
+        no C axis."""
+        if n_c == 1:
+            return pl.BlockSpec(block, lambda j: index(j, 0))
+        return pl.BlockSpec(block, index)
 
     has_sb = scale is not None or bias is not None
     has_res = residual is not None
-    operands = [x, wp]
+    operands = [_pad_to(x, 1, bc), wp]
     in_specs = [
-        pl.BlockSpec((m, c), lambda j: (0, 0)),     # resident activations
-        pl.BlockSpec((c, bk), lambda j: (0, j)),    # weights stream once
+        spec((m, bc), lambda j, l: (0, l)),     # activations, a C block each
+        spec((bc, bk), lambda j, l: (l, j)),    # weights stream once
     ]
     if has_sb:
         operands.append(_pack_scale_bias(scale, bias, k, bk))
-        in_specs.append(pl.BlockSpec((2, bk), lambda j: (0, j)))
+        in_specs.append(spec((2, bk), lambda j, l: (0, j)))
     if has_res:
         assert residual.shape == (m, k), (residual.shape, (m, k))
         operands.append(_pad_to(residual, 1, bk))
-        in_specs.append(pl.BlockSpec((m, bk), lambda j: (0, j)))
+        in_specs.append(spec((m, bk), lambda j, l: (0, j)))
 
     out = pl.pallas_call(
-        functools.partial(_mm_weight_stationary_kernel, has_sb=has_sb,
-                          has_res=has_res, relu=relu),
-        grid=(kp // bk,),
+        functools.partial(_mm_weight_stationary_kernel, n_c=n_c,
+                          has_sb=has_sb, has_res=has_res, relu=relu),
+        grid=(kp // bk,) if n_c == 1 else (kp // bk, n_c),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((m, bk), lambda j: (0, j)),
+        out_specs=spec((m, bk), lambda j, l: (0, j)),
         out_shape=jax.ShapeDtypeStruct((m, kp), x.dtype),
+        scratch_shapes=([] if n_c == 1
+                        else [pltpu.VMEM((m, bk), jnp.float32)]),
         # the name is a contract: a profiler trace and the compiled text
         # name the kernel by it, and the benchmark's reduction matches it
         name="_mm_weight_stationary_kernel",

@@ -44,10 +44,15 @@ is the name of the ops: the strided route's patches run under
 layer's scope that ``carla_conv`` opens, and each Pallas kernel keeps its
 ``pallas_call`` name.
 
-A strided conv (``stride > 1``, ResNet-50's 7x7/2 stem) runs as one GEMM on
-the matmul kernels, over patches built by space-to-depth and unit-stride
-slices (:func:`_im2col`), never by strided indexing, which XLA lowers to one
-gather per tap.
+A strided conv (``stride > 1``, ResNet-50's 7x7/2 stem) and a unit-stride
+one whose patch row ``FH*FW*C`` fits one 128-lane tile (VGG-16's conv1_1,
+3 channels) run as one GEMM on the matmul kernels
+(``core.autotune.runs_as_gemm``), over patches built by space-to-depth and
+unit-stride slices (:func:`_im2col`), never by strided indexing, which XLA
+lowers to one gather per tap.  Every other conv runs the conv2d kernel, in
+row blocks where the whole plane does not fit VMEM; an eager span records
+``row_blocks`` and counts the halo rows that blocks re-read in
+``bytes_touched``, and a 1x1's span records the GEMM's ``c_blocks``.
 """
 from __future__ import annotations
 
@@ -64,10 +69,12 @@ from repro.core.modes import Stationarity, select_stationarity
 from repro.observability import trace
 from . import ref as _ref
 from .conv1d import conv1d_causal as _conv1d_pallas
-from .conv2d import conv2d as _conv2d_pallas
+from .conv2d import conv2d as _conv2d_pallas, row_block
 from .matmul import (
+    BC as _GEMM_BC,
     matmul_act_stationary,
     matmul_weight_stationary,
+    ws_blocks,
 )
 
 _NO_EPILOGUE = Epilogue()
@@ -156,13 +163,14 @@ def _conv2d_jit(x, w, scale=None, bias=None, residual=None, *,
         return _ref.conv2d_ref(x, w, stride=stride, padding=padding,
                                scale=scale, bias=bias, relu=relu,
                                residual=residual).astype(x.dtype)
-    if stride > 1:
+    if autotune.runs_as_gemm(w.shape, stride):
         # Strided convs (ResNet-50's 7x7/2 stem) run as one GEMM over im2col
         # patches: Mosaic refuses a strided slice inside the conv2d kernel,
         # and the patch columns fill lanes that a 3-channel input block would
         # not.  For the stem, 16 slices of a (B, 115, 115, 12) space-to-depth
         # give 192 columns, 45 of them zero taps inside the 256 lanes that
-        # 147 would occupy anyway.
+        # 147 would occupy anyway.  VGG-16's conv1_1 (3x3, 3 channels) gives
+        # 27 columns from 9 slices, against 9 contractions over 3 lanes.
         k = w.shape[-1]
         with jax.named_scope("im2col"):
             p, wf = _im2col(x, w, stride, padding)
@@ -172,15 +180,16 @@ def _conv2d_jit(x, w, scale=None, bias=None, residual=None, *,
             out = _tiled_matmul(p.reshape(b * oh * ow, kk), wf,
                                 scale, bias, relu, rf, tiles)
         return out.reshape(b, oh, ow, k)
-    kw = {}
-    if tiles is not None:
-        if tiles.bk:
-            kw["bk"] = tiles.bk
-        if tiles.bc:
-            kw["bc"] = tiles.bc
     return _conv2d_pallas(x, w, padding=padding, scale=scale, bias=bias,
                           relu=relu, residual=residual,
-                          interpret=not _on_tpu(), **kw)
+                          interpret=not _on_tpu(), **_conv2d_tiles(tiles))
+
+
+def _conv2d_tiles(tiles: TileConfig | None) -> dict:
+    """The conv2d kernel's tile keywords from a tuning entry."""
+    if tiles is None:
+        return {}
+    return {n: v for n, v in (("bk", tiles.bk), ("bc", tiles.bc)) if v}
 
 
 def conv2d(x, w, *, stride: int = 1, padding: int = 0, impl: str = "auto",
@@ -210,16 +219,28 @@ def conv2d(x, w, *, stride: int = 1, padding: int = 0, impl: str = "auto",
                           tiles=tiles)
         jax.block_until_ready(out)
         b, oh, ow, _ = out.shape
+        halo = 0
         if impl == "pallas":
-            sp.attrs["kernel"] = "im2col_gemm" if stride > 1 else "conv2d"
-            if stride > 1:
+            as_gemm = autotune.runs_as_gemm(w.shape, stride)
+            sp.attrs["kernel"] = "im2col_gemm" if as_gemm else "conv2d"
+            if as_gemm:
                 sp.attrs["stationarity"] = _gemm_stationarity(b * oh * ow,
                                                               tiles).value
+            else:
+                th = row_block(x.shape, w.shape, padding=padding,
+                               has_res=ep.residual is not None,
+                               **_conv2d_tiles(tiles))
+                n_r = -(-oh // th)
+                sp.attrs["row_blocks"] = n_r
+                # each block past the first re-reads fh - 1 padded rows
+                halo = ((n_r - 1) * (fh - 1) * (x.shape[2] + 2 * padding)
+                        * x.shape[-1] * x.dtype.itemsize)
         sp.attrs["flops"] = 2 * b * oh * ow * k * fh * fw * x.shape[-1]
-        sp.attrs["bytes_touched"] = _nbytes(x, w, out, ep.scale, ep.bias,
-                                            ep.residual)
+        sp.attrs["bytes_touched"] = halo + _nbytes(x, w, out, ep.scale,
+                                                   ep.bias, ep.residual)
         sp.attrs["tile_util"] = autotune.tile_util_conv2d(
-            x.shape, w.shape, tiles, stride=stride, padding=padding)
+            x.shape, w.shape, tiles, stride=stride, padding=padding,
+            has_res=ep.residual is not None)
         _tuning_attrs(sp, entry, tiles)
         _epilogue_attrs(sp, ep, out)
     return out
@@ -311,9 +332,21 @@ def conv1x1(x, w, *, stride: int = 1, impl: str = "auto",
                                                ep.residual))
         sp.attrs["tile_util"] = autotune.tile_util_gemm(
             rows, c, w.shape[-1], tiles, stationarity=st.value)
+        if impl == "pallas":
+            sp.attrs["c_blocks"] = _c_blocks(c, w.shape[-1], tiles, st)
         _tuning_attrs(sp, entry, tiles)
         _epilogue_attrs(sp, ep, out)
     return out
+
+
+def _c_blocks(c: int, k: int, tiles: TileConfig | None,
+              st: Stationarity) -> int:
+    """Blocks of the GEMM's reduction axis over C that the kernel runs."""
+    if st == Stationarity.WEIGHT_STATIONARY:
+        bc = ws_blocks(c, k, tiles.bk if tiles is not None else None)[1]
+    else:
+        bc = min(tiles.bc if tiles is not None and tiles.bc else _GEMM_BC, c)
+    return -(-c // bc)
 
 
 @functools.partial(

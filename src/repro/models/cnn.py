@@ -5,12 +5,19 @@ models exercises all four dataflows (7x7 decomposed, 3x3 serial accumulation,
 1x1 feature-stationary, 1x1 weight-stationary).  ``network_plan`` returns the
 per-layer mode + analytic cost — the exact tables behind the paper's Figs 8-10.
 
-The forwards run **fused by default**: inference-folded BN (scale/bias), ReLU,
-and the bottleneck residual add ride the kernels' flush epilogue
-(``core.fuse.Epilogue``), so each conv output crosses HBM exactly once — in
-particular the shortcut add is fused into the block's last 1x1 conv.
+The forwards run **fused by default**: inference-folded BN (scale/bias), a
+conv's bias, ReLU, and the bottleneck residual add ride the kernels' flush
+epilogue (``core.fuse.Epilogue``), so each conv output crosses HBM exactly
+once — in particular the shortcut add is fused into the block's last 1x1 conv.
 ``fused=False`` runs the same math as separate element-wise ops (the parity
 oracle, and the unfused baseline for the bytes-saved benchmarks).
+
+VGG-16 is configuration D of Simonyan & Zisserman (arXiv:1409.1556, Table 1)
+as published: 13 3x3 convs with biases, five 2x2/2 max pools, and fc6-fc8
+with biases, run through ``carla_conv`` as 1x1 convs on a (B, 1, 1, C) map,
+so at a small batch the classifier streams its weights through the
+weight-stationary GEMM.  Its 224x224 and 112x112 3x3s run the conv2d kernel
+in row blocks, and conv1_1 (3 channels) runs as an im2col GEMM.
 
 Supports a ``width`` scale factor so smoke tests can instantiate the same
 topology at reduced width, and the structured-sparse variant (§IV.A):
@@ -49,8 +56,11 @@ def _bn_init(k: int):
 
 
 def _bn(params, x):
-    """Inference-folded batch norm (scale+shift; stats folded into weights)."""
-    return x * params["scale"] + params["bias"]
+    """Inference-folded batch norm (scale+shift; stats folded into weights),
+    or a conv's bias alone when ``params`` has no ``scale``."""
+    if "scale" in params:
+        x = x * params["scale"]
+    return x + params["bias"]
 
 
 def _conv_bn(x, w, bn, *, fused: bool, relu: bool = False,
@@ -59,7 +69,7 @@ def _conv_bn(x, w, bn, *, fused: bool, relu: bool = False,
     """conv + folded-BN (+residual) (+ReLU), fused into the kernel flush or
     as the unfused op-by-op sequence (the parity/bytes baseline)."""
     if fused:
-        ep = Epilogue(scale=None if bn is None else bn["scale"],
+        ep = Epilogue(scale=None if bn is None else bn.get("scale"),
                       bias=None if bn is None else bn["bias"],
                       relu=relu, residual=residual)
         return carla_conv(x, w, stride=stride, padding=padding, impl=impl,
@@ -235,31 +245,65 @@ def resnet50_apply(params, x, *, impl: str = "auto", fused: bool = True,
 
 
 # -------------------------------- VGG-16 -------------------------------------
-VGG_SPEC = [(64, 2), (128, 2), (256, 3), (512, 3), (512, 3)]
+# (convs, channels) of each group of configuration D, arXiv:1409.1556 Table 1
+VGG_SPEC = [(2, 64), (2, 128), (3, 256), (3, 512), (3, 512)]
+VGG_FC = (4096, 4096)
 
 
-def vgg16_init(key, *, width: float = 1.0, num_classes: int = 1000):
+def vgg16_init(key, *, width: float = 1.0, num_classes: int = 1000,
+               image_size: int = 224):
+    """VGG-16 (D): ``conv<g>_<i>`` and ``fc6``-``fc8``, each ``{"w", "b"}``
+    with ``w`` HWIO for a conv and (C, K) for an fc.  Weights normal with
+    variance 2/fan-in (He et al.), which keeps the activations' scale through
+    the 15 ReLU layers, fc8's 1/fan-in; biases normal with deviation 0.1.
+    ``width`` scales every width (smoke tests); fc6's fan-in follows the
+    map that ``image_size`` leaves after five pools, flattened (H, W, C)."""
     w = lambda c: max(4, int(c * width))
-    keys = iter(jax.random.split(key, 64))
+    keys = iter(jax.random.split(key, 32))
+
+    def layer(shape, gain):
+        fan_in = int(np.prod(shape[:-1]))
+        return {"w": jax.random.normal(next(keys), shape, jnp.float32)
+                * (gain / fan_in) ** 0.5,
+                "b": 0.1 * jax.random.normal(next(keys), shape[-1:],
+                                             jnp.float32)}
+
     params = {}
     cin = 3
-    for gi, (c, n) in enumerate(VGG_SPEC):
-        for li in range(n):
-            params[f"g{gi}_c{li}"] = _conv_init(next(keys), 3, cin, w(c))
+    for g, (n, c) in enumerate(VGG_SPEC, start=1):
+        for i in range(1, n + 1):
+            params[f"conv{g}_{i}"] = layer((3, 3, cin, w(c)), 2.0)
             cin = w(c)
-    params["fc"] = {"w": jax.random.normal(next(keys), (cin, num_classes),
-                                           jnp.float32) * cin ** -0.5}
+    side = image_size // 2 ** len(VGG_SPEC)
+    cin = side * side * cin
+    for name, k in zip(("fc6", "fc7"), VGG_FC):
+        params[name] = layer((cin, w(k)), 2.0)
+        cin = w(k)
+    params["fc8"] = layer((cin, num_classes), 1.0)
     return params
 
 
 def vgg16_apply(params, x, *, impl: str = "auto", fused: bool = True):
-    for gi, (c, n) in enumerate(VGG_SPEC):
-        for li in range(n):
-            x = _conv_bn(x, params[f"g{gi}_c{li}"], None, fused=fused,
-                         relu=True, padding=1, impl=impl)
-        x = jax.lax.reduce_window(x, -jnp.inf, jax.lax.max, (1, 2, 2, 1),
-                                  (1, 2, 2, 1), "VALID")
-    return _classifier(params, x)
+    """x: (B, H, W, 3) -> (B, num_classes) logits.  All convs and the three
+    fcs via ``carla_conv``, each under its layer's scope (``conv1_1`` ...
+    ``conv5_3``, ``fc6``, ``fc7``, ``fc8``; conv1_1's patches and GEMM under
+    ``conv1_1/im2col`` and ``conv1_1/gemm``), the pools under ``pool1`` ...
+    ``pool5`` (``pool5`` also flattens).  No dropout: inference."""
+    for g, (n, _) in enumerate(VGG_SPEC, start=1):
+        for i in range(1, n + 1):
+            name = f"conv{g}_{i}"
+            x = _conv_bn(x, params[name]["w"], {"bias": params[name]["b"]},
+                         fused=fused, relu=True, padding=1, impl=impl,
+                         name=name)
+        with jax.named_scope(f"pool{g}"):
+            x = jax.lax.reduce_window(x, -jnp.inf, jax.lax.max, (1, 2, 2, 1),
+                                      (1, 2, 2, 1), "VALID")
+            if g == len(VGG_SPEC):
+                x = x.reshape(x.shape[0], 1, 1, -1)
+    for name in ("fc6", "fc7", "fc8"):
+        x = _conv_bn(x, params[name]["w"], {"bias": params[name]["b"]},
+                     fused=fused, relu=name != "fc8", impl=impl, name=name)
+    return x.reshape(x.shape[0], -1)
 
 
 def network_plan(layers) -> list:

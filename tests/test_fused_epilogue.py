@@ -305,8 +305,9 @@ def test_resnet50_fused_residual_rides_last_conv():
 def test_vgg16_fused_forward_parity():
     from repro.models.cnn import vgg16_apply, vgg16_init
     key = jax.random.PRNGKey(2)
-    params = vgg16_init(key, width=0.0625, num_classes=10)
+    params = vgg16_init(key, width=0.0625, num_classes=10, image_size=32)
     x = jax.random.normal(key, (1, 32, 32, 3))
     fused = vgg16_apply(params, x, impl="ref", fused=True)
     unfused = vgg16_apply(params, x, impl="ref", fused=False)
+    assert fused.shape == (1, 10)     # fc8's logits
     assert _err(fused, unfused) < 1e-5

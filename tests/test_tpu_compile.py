@@ -1,4 +1,5 @@
-"""The main path's kernels compile for a TPU v5e, at ResNet-50's real widths.
+"""The main path's kernels compile for a TPU v5e, at ResNet-50's and
+VGG-16's real widths.
 
 No chip is needed: the TPU compiler compiles for a v5e that is described, not
 attached.  Each case is one conv dispatch as ``kernels.ops`` runs it on a
@@ -61,6 +62,16 @@ CASES = {
     # the stem GEMM under a tuned entry that splits its 192 patch columns
     # into two 128-lane blocks
     "stem_7x7s2_bc128": ((1, 224, 224, 3), (7, 7, 3, 64), 2, 3, False),
+    # VGG-16's conv1_2 and conv2_1: the conv2d kernel in row blocks
+    "vgg_conv1_2": ((1, 224, 224, 64), (3, 3, 64, 64), 1, 1, False),
+    "vgg_conv2_1": ((1, 112, 112, 64), (3, 3, 64, 128), 1, 1, False),
+    # VGG-16's conv1_1: 27 patch columns, the unit-stride im2col GEMM
+    "vgg_conv1_1": ((1, 224, 224, 3), (3, 3, 3, 64), 1, 1, False),
+    # VGG-16's fc6 at batch 1: the weight-stationary GEMM in C blocks
+    "vgg_fc6": ((1, 1, 1, 25088), (1, 1, 25088, 4096), 1, 0, False),
+    # a 224x224 plane of 256 channels: row blocks of the whole C, above the
+    # 128 lanes of a default channel block
+    "3x3_224x224x256_rows": ((1, 224, 224, 256), (3, 3, 256, 256), 1, 1, False),
 }
 TILES = {
     "stem_7x7s2_bc128": TileConfig(bm=256, bk=64, bc=128,
@@ -149,6 +160,61 @@ def test_layer_and_kernel_names_reach_the_compiled_program(program, one_chip):
     if "conv1" in kernels:
         assert re.search(r'op_name="[^"]*/conv1/jit\(_conv2d_jit\)/im2col/', text)
         assert sum("/conv1/" in c and "/gemm/" in c for c in calls) == 1
+
+
+def test_vgg16_names_reach_the_compiled_forward(one_chip):
+    """VGG-16's forward, at a quarter of its widths on 32x32 images, names
+    its convs, pools and fcs as the paper does, conv1_1's patches and GEMM,
+    and runs the other 3x3s on ``_conv2d_kernel`` and the fcs on the
+    weight-stationary GEMM."""
+    from repro.models.cnn import vgg16_apply, vgg16_init
+    params = jax.eval_shape(lambda k: vgg16_init(k, width=0.25, image_size=32),
+                            jax.random.PRNGKey(0))
+    args = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        (params, jax.ShapeDtypeStruct((1, 32, 32, 3), jnp.float32)))
+    text = jax.jit(lambda p, x: vgg16_apply(p, x, impl="pallas")).lower(
+        *args).compile().as_text()
+    calls = _named_custom_calls(text)
+    assert len(calls) == 16
+    assert sum("/conv1_1/" in c and "/gemm/" in c for c in calls) == 1
+    assert re.search(r'op_name="[^"]*/conv1_1/jit\(_conv2d_jit\)/im2col/', text)
+    for layer in ("conv1_2", "conv3_3", "conv5_3"):
+        assert sum(f"/{layer}/" in c and "/_conv2d_kernel/" in c
+                   for c in calls) == 1
+    for layer in ("fc6", "fc7", "fc8"):
+        assert sum(f"/{layer}/" in c and "/_mm_weight_stationary_kernel/" in c
+                   for c in calls) == 1
+    assert re.search(r'op_name="[^"]*/pool5/', text)
+
+
+def test_resnet50_keeps_one_row_block_and_one_c_block():
+    """Every ResNet-50 3x3, dense and pruned, at batch 1 and 32, runs its
+    whole plane in one grid step, and every weight-stationary 1x1 (conv5's,
+    49 rows at batch 1) one C block: the program the ResNet-50 cells ran
+    before row and C blocks existed."""
+    from repro.core.modes import Stationarity, select_stationarity
+    from repro.core.networks import (
+        resnet50_conv_layers,
+        resnet50_projection_shortcuts,
+    )
+    from repro.kernels.conv2d import row_block
+    from repro.kernels.matmul import ws_blocks
+    ws = 0
+    for sparse in (False, True):
+        layers = (resnet50_conv_layers(sparse)
+                  + resnet50_projection_shortcuts(sparse))
+        for l in layers:
+            for batch in (1, 32):
+                if l.FL == 3:
+                    assert row_block((batch, l.IL, l.IL, l.IC),
+                                     (3, 3, l.IC, l.K), padding=1) == l.IL
+                rows = batch * (l.IL // l.S) ** 2
+                if l.FL == 1 and select_stationarity(rows) == \
+                        Stationarity.WEIGHT_STATIONARY:
+                    assert ws_blocks(l.IC, l.K) == (128, l.IC), l
+                    ws += 1
+    assert ws == 2 * 7   # conv5's six 1x1s and its projection at b1, twice
 
 
 def test_stem_compiles_without_a_gather(one_chip):
