@@ -9,8 +9,9 @@ every unique (layer shape, dtype, epilogue, backend) key of a network it
      ``bk/bc`` channel tiles for the serial-accumulation conv kernel,
      ``bm/bk/bc`` tiles x both stationarities for the dual-residency GEMM
      (1x1 layers flatten to their GEMM shape, so ``conv1x1``/``gemm`` share
-     entries, and the convs that run as an im2col GEMM, strided or with few
-     patch columns, key by that GEMM's shape);
+     entries, the convs that run as an im2col GEMM, strided or with few
+     patch columns, key by that GEMM's shape, and those that fold columns
+     into the lanes by the folded conv's);
   2. times each candidate through the jitted kernel wrappers
      (best-of-``reps`` wall time, compile excluded), *including the hardcoded
      defaults* — the PR 8 operating point;
@@ -87,11 +88,13 @@ def _gemm_shape(layer, batch: int):
 
 
 def _layer_key(layer, batch: int, dtype="float32") -> str:
-    gemm = _gemm_shape(layer, batch)
-    if gemm is not None:
-        return autotune.gemm_key(*gemm, dtype)
-    return autotune.conv2d_key(*_conv_shapes(layer, batch), layer.S, layer.Z,
-                               dtype)
+    """The key the dispatch looks the layer up by: a 1x1's GEMM, else
+    ``autotune.conv2d_tuning_key`` (an im2col GEMM's, or the folded conv's
+    where ``autotune.col_fold`` folds columns into the lanes)."""
+    if layer.FL == 1:
+        return autotune.gemm_key(*_gemm_shape(layer, batch), dtype)
+    return autotune.conv2d_tuning_key(*_conv_shapes(layer, batch), layer.S,
+                                      layer.Z, dtype)
 
 
 def _best_of(fn, reps: int) -> float:

@@ -13,7 +13,9 @@ an MMIE-style per-layer operating point chosen by measurement:
     flatten to their GEMM shape so ``conv1x1`` and ``gemm`` share entries,
     and the convs that the Pallas path runs as an im2col GEMM (strided, or
     with a patch row of at most 128 lanes: :func:`runs_as_gemm`) key by that
-    GEMM's shape (:func:`conv2d_gemm_shape`).
+    GEMM's shape (:func:`conv2d_gemm_shape`), and those that fold output
+    columns into the lanes (:func:`col_fold`) by the folded conv's
+    (:func:`conv2d_kernel_shapes`): :func:`conv2d_tuning_key`.
   * **Entry**: the winning :class:`TileConfig` — tile sizes plus, for GEMM
     shapes, the stationarity (dataflow) choice itself — with the measured
     tuned/default wall times and where the entry came from (``table`` =
@@ -95,6 +97,7 @@ class TileConfig:
 # importing the kernels here would cycle through repro.kernels.__init__).
 DEFAULT_GEMM = TileConfig(bm=128, bk=128, bc=512)     # matmul.BM/BK/BC
 DEFAULT_CONV2D = TileConfig(bk=128, bc=128)           # conv2d.BK/BC
+LANES = 128     # the MXU's width, and the lanes a channel block occupies
 
 
 @dataclass(frozen=True)
@@ -130,6 +133,55 @@ def runs_as_gemm(w_shape, stride: int) -> bool:
     would contract 3 lanes at a time.  The one place that decides it."""
     fh, fw, cin, _ = w_shape
     return stride > 1 or (fh * fw > 1 and fh * fw * cin <= 128)
+
+
+def col_fold(x_shape, w_shape, stride: int) -> int:
+    """How many adjacent output columns the Pallas path folds into the
+    lanes of a conv on the conv2d kernel: ``128 // C`` for a unit-stride
+    conv of more than one tap whose C is below 128 and divides it (2 for
+    VGG-16's conv1_2 and ResNet-50's conv2 3x3s, 4 for the pruned conv2
+    3x3s' 32 channels), else 1.  Folded, each dot contracts and writes 128
+    lanes, where C = K = 64 would fill a quarter of the MXU.  From the
+    shapes alone; the one place that decides it."""
+    fh, fw, _, _ = w_shape
+    cin = x_shape[3]
+    if (stride != 1 or fh * fw == 1 or runs_as_gemm(w_shape, stride)
+            or cin >= LANES or LANES % cin):
+        return 1
+    return LANES // cin
+
+
+def conv2d_kernel_shapes(x_shape, w_shape, stride: int, padding: int):
+    """``(x_shape, w_shape, padding)`` of the conv the conv2d kernel runs
+    (``kernels.ops._col_fold``): with :func:`col_fold` ``f > 1``, the input
+    padded once and read as ``f*C`` lanes of ``OWf + TWf - 1`` column
+    groups, ``OWf = ceil(OW / f)`` rounded up to a multiple of 8 (so the
+    kernel's ``(th, OWf, 128)`` windows flatten without a relayout), the
+    weights ``(FH, TWf, f*C, f*K)`` with ``TWf = ceil((f + FW - 1) / f)``
+    column taps, and padding 0; else the conv's own."""
+    f = col_fold(x_shape, w_shape, stride)
+    if f == 1:
+        return tuple(x_shape), tuple(w_shape), padding
+    b, h, wd, cin = x_shape
+    fh, fw, _, k = w_shape
+    ow = wd + 2 * padding - fw + 1
+    twf = -(-(f + fw - 1) // f)
+    owf = _ceil_to(-(-ow // f), 8)
+    return ((b, h + 2 * padding, owf + twf - 1, f * cin),
+            (fh, twf, f * cin, f * k), 0)
+
+
+def conv2d_tuning_key(x_shape, w_shape, stride: int, padding: int, dtype,
+                      epilogue: str = "none") -> str:
+    """The key under which the Pallas path looks up a conv's tiles: its
+    im2col GEMM's (:func:`runs_as_gemm`), else the conv the conv2d kernel
+    runs (:func:`conv2d_kernel_shapes`), so tiles tuned for an unfolded
+    shape never reach a folded call."""
+    if runs_as_gemm(w_shape, stride):
+        return gemm_key(*conv2d_gemm_shape(x_shape, w_shape, stride, padding),
+                        dtype, epilogue)
+    xs, ws, pad = conv2d_kernel_shapes(x_shape, w_shape, stride, padding)
+    return conv2d_key(xs, ws, stride, pad, dtype, epilogue)
 
 
 def conv2d_gemm_shape(x_shape, w_shape, stride: int,
@@ -274,12 +326,10 @@ def lookup(key: str) -> Entry | None:
 
 def lookup_conv2d(x_shape, w_shape, stride, padding, dtype,
                   epilogue: str = "none") -> Entry | None:
-    """A conv that runs as an im2col GEMM has GEMM tiles."""
-    if runs_as_gemm(w_shape, stride):
-        return lookup_gemm(*conv2d_gemm_shape(x_shape, w_shape, stride,
-                                              padding), dtype, epilogue)
-    return lookup(conv2d_key(x_shape, w_shape, stride, padding, dtype,
-                             epilogue))
+    """A conv that runs as an im2col GEMM has GEMM tiles, a folded one its
+    folded conv's (:func:`conv2d_tuning_key`)."""
+    return lookup(conv2d_tuning_key(x_shape, w_shape, stride, padding, dtype,
+                                    epilogue))
 
 
 def lookup_gemm(m, c, k, dtype, epilogue: str = "none") -> Entry | None:
@@ -374,9 +424,12 @@ def conv2d_candidates(x_shape, w_shape, *, stride: int = 1, padding: int = 0,
     kernel picks its row blocks to fit.
     The kernel defaults are always included.  A conv that runs as an im2col
     GEMM (:func:`runs_as_gemm`) takes :func:`gemm_candidates` of
-    :func:`conv2d_gemm_shape` instead.
+    :func:`conv2d_gemm_shape` instead, and a folded one (:func:`col_fold`)
+    the candidates of the conv the kernel runs (:func:`conv2d_kernel_shapes`).
     """
     from repro.kernels.conv2d import step_tiles
+    x_shape, w_shape, padding = conv2d_kernel_shapes(x_shape, w_shape, stride,
+                                                     padding)
     cin, k = x_shape[3], w_shape[3]
     oh = (x_shape[1] - w_shape[0] + 2 * padding) // stride + 1
 
@@ -452,24 +505,30 @@ def gemm_candidates(m: int, c: int, k: int, *,
 def tile_util_conv2d(x_shape, w_shape, tiles: TileConfig | None = None, *,
                      stride: int = 1, padding: int = 0,
                      has_res: bool = False) -> float:
-    """Logical FLOPs / padded FLOPs under the conv kernel's channel tiling
-    and row blocks (a last block that runs past the plane computes padded
-    rows), or, for a conv that runs as an im2col GEMM, under that GEMM's
-    tiling, whose zero taps count as padding."""
+    """Logical FLOPs / the MXU's FLOPs under the conv kernel's tiling: each
+    channel block of C and K takes 128 lanes of the MXU (a block of 64
+    fills half of each), a last row block that runs past the plane computes
+    padded rows, and a folded conv (:func:`col_fold`) computes its zero taps
+    and padded column groups.  For a conv that runs as an im2col GEMM, the
+    padded FLOPs of that GEMM's tiling, whose zero taps count as padding."""
     if runs_as_gemm(w_shape, stride):
         m, c, k = conv2d_gemm_shape(x_shape, w_shape, stride, padding)
         taps = w_shape[0] * w_shape[1] * w_shape[2]
         return taps / c * tile_util_gemm(
             m, c, k, tiles, stationarity=select_stationarity(m).value)
     from repro.kernels.conv2d import step_tiles
-    cin, k = w_shape[2], w_shape[3]
-    oh = x_shape[1] - w_shape[0] + 2 * padding + 1
+    fh, fw, cin, k = w_shape
+    oh = x_shape[1] - fh + 2 * padding + 1
+    ow = x_shape[2] - fw + 2 * padding + 1
+    xk, wk, pk = conv2d_kernel_shapes(x_shape, w_shape, stride, padding)
     th, bk, bc = step_tiles(
-        x_shape, w_shape, padding=padding, has_res=has_res,
+        xk, wk, padding=pk, has_res=has_res,
         bk=tiles.bk if tiles and tiles.bk else DEFAULT_CONV2D.bk,
         bc=tiles.bc if tiles and tiles.bc else DEFAULT_CONV2D.bc)
-    return (cin * k * oh) / (_ceil_to(cin, bc) * _ceil_to(k, bk)
-                             * _ceil_to(oh, th))
+    owk = xk[2] - wk[1] + 2 * pk + 1
+    mxu = (wk[0] * wk[1] * -(-wk[2] // bc) * _ceil_to(bc, LANES)
+           * -(-wk[3] // bk) * _ceil_to(bk, LANES) * _ceil_to(oh, th) * owk)
+    return fh * fw * cin * k * oh * ow / mxu
 
 
 def tile_util_gemm(m: int, c: int, k: int,

@@ -17,6 +17,14 @@ The paper's §III.A dataflow, transplanted to the TPU memory hierarchy:
   its patches built by space-to-depth and unit-stride slices in XLA.  A
   3x3 over 3 channels (VGG-16's conv1_1) runs that way too: its 27 patch
   columns fill one lane tile, where this kernel would contract 3 lanes.
+* **Full lanes, by folding columns**: a channel block narrower than the
+  MXU's 128 lanes wastes the rest of them in both the contraction and the
+  output (C = K = 64 uses a quarter of the MXU).  For a conv whose C is 32
+  or 64, ``kernels.ops`` folds ``f = 128 / C`` adjacent output columns into
+  the lanes before calling this kernel (``core.autotune.col_fold``): the
+  input becomes ``f*C`` lanes per column group, the weights ``(FH, TWf,
+  f*C, f*K)`` with the zero taps of the fold, and the output ``f*K`` lanes
+  per group.  This kernel runs the folded conv as any other, at padding 0.
 * **Feedback-path reuse, in row blocks with a halo**: a grid step holds
   ``th`` output rows and reads the ``th + FH - 1`` padded input rows they
   need (its own rows and a halo of ``FH - 1``) into VMEM *once* per (batch,

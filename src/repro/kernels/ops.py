@@ -40,9 +40,9 @@ that actually ran).  When tracing is disabled (the default), or the call is
 traced inside an outer ``jax.jit``, the jitted function is invoked directly:
 no span objects, no clock reads, no ``block_until_ready``.  There the record
 is the name of the ops: the strided route's patches run under
-``jax.named_scope("im2col")`` and its GEMM under ``"gemm"``, inside the
-layer's scope that ``carla_conv`` opens, and each Pallas kernel keeps its
-``pallas_call`` name.
+``jax.named_scope("im2col")`` and its GEMM under ``"gemm"``, the column
+fold under ``"col_fold"``, inside the layer's scope that ``carla_conv``
+opens, and each Pallas kernel keeps its ``pallas_call`` name.
 
 A strided conv (``stride > 1``, ResNet-50's 7x7/2 stem) and a unit-stride
 one whose patch row ``FH*FW*C`` fits one 128-lane tile (VGG-16's conv1_1,
@@ -50,9 +50,18 @@ one whose patch row ``FH*FW*C`` fits one 128-lane tile (VGG-16's conv1_1,
 (``core.autotune.runs_as_gemm``), over patches built by space-to-depth and
 unit-stride slices (:func:`_im2col`), never by strided indexing, which XLA
 lowers to one gather per tap.  Every other conv runs the conv2d kernel, in
-row blocks where the whole plane does not fit VMEM; an eager span records
-``row_blocks`` and counts the halo rows that blocks re-read in
-``bytes_touched``, and a 1x1's span records the GEMM's ``c_blocks``.
+row blocks where the whole plane does not fit VMEM.  One whose C is below
+128 lanes and divides 128 (VGG-16's conv1_2 and conv2_1, ResNet-50's conv2
+3x3s, the pruned conv3 3x3s) runs it with ``f = 128 // C`` adjacent output
+columns folded into the lanes (``core.autotune.col_fold``,
+:func:`_conv2d_folded`): the padded input read as ``f*C`` lanes, the
+weights refolded with zero taps, so each dot fills the MXU's 128 lanes of
+C and of K where 64 channels filled a quarter of it.  The kernel, its row
+blocks and its tiles then see the folded conv
+(``core.autotune.conv2d_kernel_shapes``).  An eager span records
+``col_fold`` (f, or 1) and ``row_blocks`` and counts the halo rows that
+blocks re-read in ``bytes_touched``, and a 1x1's span records the GEMM's
+``c_blocks``.
 """
 from __future__ import annotations
 
@@ -154,6 +163,62 @@ def _im2col(x, w, stride: int, padding: int):
     return p, wf
 
 
+def _col_fold(x, w, scale, bias, residual, padding: int, rows: int):
+    """The operands of a conv folded along W into the lanes, for the conv2d
+    kernel at padding 0 (:func:`~repro.core.autotune.conv2d_kernel_shapes`),
+    with ``rows`` more zero rows below the plane (and the residual): the
+    kernel's last row block, which it would otherwise pad in a second copy.
+
+    ``f`` adjacent output columns become one column group of ``f*K`` lanes.
+    The input, padded once, is read as ``f*C`` lanes per column group (a
+    reshape; in HBM, where 32 or 64 channels take 128 lanes a column, a
+    layout copy), and column tap ``t`` of the folded weights holds
+    ``W'[r, t, (p, c), (q, k)] = w[r, f*t + p - q, c, k]``, zero outside
+    ``[0, FW)``: for each output phase ``q`` the taps shifted by ``q``, from
+    pads, reshapes and one concatenate.  Scale and bias repeat ``f`` times
+    along the lanes; the residual is padded to ``f * OWf`` columns and folded
+    as the output is.
+    """
+    b, _, wd, c = x.shape
+    fh, fw, _, k = w.shape
+    xs, ws, _ = autotune.conv2d_kernel_shapes(x.shape, w.shape, 1, padding)
+    f, twf = xs[3] // c, ws[1]
+    owf = xs[2] - twf + 1
+    xp = jnp.pad(x, ((0, 0), (padding, padding + rows),
+                     (padding, f * xs[2] - wd - padding), (0, 0)))
+    wf = jnp.concatenate(
+        [jnp.pad(w, ((0, 0), (q, f * twf - fw - q), (0, 0), (0, 0)))
+         .reshape(fh, twf, f * c, k) for q in range(f)], axis=-1)
+    scale, bias = (None if v is None else jnp.tile(v, f) for v in (scale, bias))
+    if residual is not None:
+        _, oh, ow, _ = residual.shape
+        residual = jnp.pad(residual, ((0, 0), (0, rows), (0, f * owf - ow),
+                                      (0, 0))).reshape(b, oh + rows, owf, f * k)
+    return xp.reshape(b, -1, xs[2], xs[3]), wf, scale, bias, residual
+
+
+def _conv2d_folded(x, w, scale, bias, residual, *, relu: bool, padding: int,
+                   tiles: TileConfig | None):
+    """A conv whose C fills half or a quarter of the MXU's 128 lanes, run on
+    the conv2d kernel with ``autotune.col_fold`` adjacent output columns
+    folded into the lanes, so that each dot contracts and writes all 128;
+    the fold and the unfold run under ``jax.named_scope("col_fold")``."""
+    b, h, wd, _ = x.shape
+    fh, fw, _, k = w.shape
+    oh, ow = h + 2 * padding - fh + 1, wd + 2 * padding - fw + 1
+    xs, ws, _ = autotune.conv2d_kernel_shapes(x.shape, w.shape, 1, padding)
+    th = row_block(xs, ws, padding=0, has_res=residual is not None,
+                   **_conv2d_tiles(tiles))
+    with jax.named_scope("col_fold"):
+        x, w, scale, bias, residual = _col_fold(
+            x, w, scale, bias, residual, padding, -(-oh // th) * th - oh)
+    out = _conv2d_pallas(x, w, padding=0, scale=scale, bias=bias, relu=relu,
+                         residual=residual, interpret=not _on_tpu(),
+                         **_conv2d_tiles(tiles))
+    with jax.named_scope("col_fold"):
+        return out.reshape(b, out.shape[1], -1, k)[:, :oh, :ow]
+
+
 @functools.partial(
     jax.jit, static_argnames=("stride", "padding", "impl", "relu", "tiles"))
 def _conv2d_jit(x, w, scale=None, bias=None, residual=None, *,
@@ -180,6 +245,9 @@ def _conv2d_jit(x, w, scale=None, bias=None, residual=None, *,
             out = _tiled_matmul(p.reshape(b * oh * ow, kk), wf,
                                 scale, bias, relu, rf, tiles)
         return out.reshape(b, oh, ow, k)
+    if autotune.col_fold(x.shape, w.shape, stride) > 1:
+        return _conv2d_folded(x, w, scale, bias, residual, relu=relu,
+                              padding=padding, tiles=tiles)
     return _conv2d_pallas(x, w, padding=padding, scale=scale, bias=bias,
                           relu=relu, residual=residual,
                           interpret=not _on_tpu(), **_conv2d_tiles(tiles))
@@ -197,8 +265,9 @@ def conv2d(x, w, *, stride: int = 1, padding: int = 0, impl: str = "auto",
     """General NHWC conv; CARLA 3x3/7x7 serial-accumulation dataflow.
 
     On the pallas path a strided conv runs as an im2col GEMM, tuned and
-    reported under that GEMM's shape; the span's ``kernel`` attribute records
-    which kernel ran.
+    reported under that GEMM's shape, and a conv of 32 or 64 channels as
+    its column fold, tuned under the folded conv's shape; the span's
+    ``kernel`` attribute records which kernel ran, ``col_fold`` the fold.
     """
     ep = epilogue or _NO_EPILOGUE
     impl = _resolve(impl)
@@ -227,14 +296,19 @@ def conv2d(x, w, *, stride: int = 1, padding: int = 0, impl: str = "auto",
                 sp.attrs["stationarity"] = _gemm_stationarity(b * oh * ow,
                                                               tiles).value
             else:
-                th = row_block(x.shape, w.shape, padding=padding,
+                # the conv the kernel runs: folded, where col_fold > 1
+                xk, wk, pk = autotune.conv2d_kernel_shapes(x.shape, w.shape,
+                                                           stride, padding)
+                sp.attrs["col_fold"] = autotune.col_fold(x.shape, w.shape,
+                                                         stride)
+                th = row_block(xk, wk, padding=pk,
                                has_res=ep.residual is not None,
                                **_conv2d_tiles(tiles))
                 n_r = -(-oh // th)
                 sp.attrs["row_blocks"] = n_r
                 # each block past the first re-reads fh - 1 padded rows
-                halo = ((n_r - 1) * (fh - 1) * (x.shape[2] + 2 * padding)
-                        * x.shape[-1] * x.dtype.itemsize)
+                halo = ((n_r - 1) * (fh - 1) * (xk[2] + 2 * pk)
+                        * xk[3] * x.dtype.itemsize)
         sp.attrs["flops"] = 2 * b * oh * ow * k * fh * fw * x.shape[-1]
         sp.attrs["bytes_touched"] = halo + _nbytes(x, w, out, ep.scale,
                                                    ep.bias, ep.residual)

@@ -248,13 +248,20 @@ def test_save_user_cache_merges(iso):
 
 # ------------------------------- tile_util ------------------------------------
 def test_tile_util_math():
-    # conv2d: cin=16 -> bc=128 clamps to 16 (no pad); k=16 with bk=128 ->
-    # bk=16 (9 * 16 = 144 columns: too wide for the im2col route)
-    assert autotune.tile_util_conv2d((1, 14, 14, 16), (3, 3, 16, 16)) == 1.0
-    # odd tiles pad: cin=16 over bc=3 -> 18; k=16 over bk=5 -> 20
-    got = autotune.tile_util_conv2d((1, 14, 14, 16), (3, 3, 16, 16),
+    # conv2d: cin=24 -> bc=128 clamps to 24, k=16 -> bk=16 (9 * 24 = 216
+    # columns: too wide for the im2col route; 24 does not divide 128, so no
+    # fold): each block takes 128 of the MXU's lanes
+    assert autotune.tile_util_conv2d((1, 14, 14, 24), (3, 3, 24, 16)) \
+        == pytest.approx((24 * 16) / (128 * 128))
+    # odd tiles pad: cin=24 over bc=3 -> 8 blocks, k=16 over bk=5 -> 4
+    # blocks, 128 lanes each
+    got = autotune.tile_util_conv2d((1, 14, 14, 24), (3, 3, 24, 16),
                                     TileConfig(bk=5, bc=3))
-    assert got == pytest.approx((16 * 16) / (18 * 20))
+    assert got == pytest.approx((24 * 16) / (8 * 128 * 4 * 128))
+    # cin=16 folds 8 columns into 128 lanes: 2 column taps of 8 columns
+    # hold the 3 taps (zero taps), and 12 columns take 2 groups, padded to 8
+    assert autotune.tile_util_conv2d((1, 14, 14, 16), (3, 3, 16, 16)) \
+        == pytest.approx((9 * 16 * 16 * 12 * 12) / (3 * 2 * 128 * 128 * 12 * 8))
     # gemm WS: only K pads
     assert autotune.tile_util_gemm(
         7, 64, 30, TileConfig(bk=8, stationarity="weight_stationary")
@@ -301,9 +308,10 @@ def test_plan_conv_tuned_stationarity_flips_effective_dataflow(iso):
 def test_tuned_conv2d_dispatch_matches_ref_and_records_span(iso):
     autotune.enable()
     key = jax.random.PRNGKey(2)
-    # 16 channels: 144 patch columns, too wide for the im2col route
-    x = jax.random.normal(key, (1, 10, 10, 16))
-    w = jax.random.normal(jax.random.fold_in(key, 1), (3, 3, 16, 16))
+    # 24 channels: 216 patch columns, too wide for the im2col route, and
+    # not a divisor of 128 lanes, so the conv2d kernel runs it unfolded
+    x = jax.random.normal(key, (1, 10, 10, 24))
+    w = jax.random.normal(jax.random.fold_in(key, 1), (3, 3, 24, 16))
     autotune.put(conv2d_key(x.shape, w.shape, 1, 1, x.dtype),
                  TileConfig(bk=5, bc=3))
     with trace.capture() as tr:
@@ -314,7 +322,8 @@ def test_tuned_conv2d_dispatch_matches_ref_and_records_span(iso):
     assert sp.attrs["tuned"] is True
     assert sp.attrs["tile_config"] == "bk5/bc3"
     assert sp.attrs["tuning_source"] == "runtime"
-    assert sp.attrs["tile_util"] == pytest.approx((16 * 16) / (18 * 20))
+    assert sp.attrs["tile_util"] == pytest.approx(
+        (24 * 16) / (8 * 128 * 4 * 128))
     # the kernel child span carries the same tuning ledger
     (ksp,) = sp.children
     assert ksp.attrs["tile_config"] == "bk5/bc3"

@@ -8,6 +8,7 @@ lower to exactly one Mosaic kernel.  The topology is described inside a
 fixture, never at import, so only the worker that runs this file loads the
 TPU library.
 """
+import collections
 import re
 
 import jax
@@ -72,6 +73,10 @@ CASES = {
     # a 224x224 plane of 256 channels: row blocks of the whole C, above the
     # 128 lanes of a default channel block
     "3x3_224x224x256_rows": ((1, 224, 224, 256), (3, 3, 256, 256), 1, 1, False),
+    # the folded 3x3s of the offline cells (2 or 4 columns in 128 lanes)
+    "3x3_56x56x64_b32": ((32, 56, 56, 64), (3, 3, 64, 64), 1, 1, False),
+    "3x3_56x56x32_b32_pruned": ((32, 56, 56, 32), (3, 3, 32, 32), 1, 1, False),
+    "3x3_28x28x64_b32_pruned": ((32, 28, 28, 64), (3, 3, 64, 64), 1, 1, False),
 }
 TILES = {
     "stem_7x7s2_bc128": TileConfig(bm=256, bk=64, bc=128,
@@ -190,9 +195,11 @@ def test_vgg16_names_reach_the_compiled_forward(one_chip):
 
 def test_resnet50_keeps_one_row_block_and_one_c_block():
     """Every ResNet-50 3x3, dense and pruned, at batch 1 and 32, runs its
-    whole plane in one grid step, and every weight-stationary 1x1 (conv5's,
+    whole plane in one grid step, folded (C = 64: conv2 dense, conv3 pruned;
+    C = 32: conv2 pruned) or not, and every weight-stationary 1x1 (conv5's,
     49 rows at batch 1) one C block: the program the ResNet-50 cells ran
     before row and C blocks existed."""
+    from repro.core import autotune
     from repro.core.modes import Stationarity, select_stationarity
     from repro.core.networks import (
         resnet50_conv_layers,
@@ -200,21 +207,62 @@ def test_resnet50_keeps_one_row_block_and_one_c_block():
     )
     from repro.kernels.conv2d import row_block
     from repro.kernels.matmul import ws_blocks
-    ws = 0
+    ws, folded = 0, collections.Counter()
     for sparse in (False, True):
         layers = (resnet50_conv_layers(sparse)
                   + resnet50_projection_shortcuts(sparse))
         for l in layers:
             for batch in (1, 32):
                 if l.FL == 3:
-                    assert row_block((batch, l.IL, l.IL, l.IC),
-                                     (3, 3, l.IC, l.K), padding=1) == l.IL
+                    xs, w_s = (batch, l.IL, l.IL, l.IC), (3, 3, l.IC, l.K)
+                    xk, wk, pk = autotune.conv2d_kernel_shapes(xs, w_s, 1, 1)
+                    assert row_block(xk, wk, padding=pk) == l.IL, l
+                    folded[sparse, l.IC] += xk != xs
                 rows = batch * (l.IL // l.S) ** 2
                 if l.FL == 1 and select_stationarity(rows) == \
                         Stationarity.WEIGHT_STATIONARY:
                     assert ws_blocks(l.IC, l.K) == (128, l.IC), l
                     ws += 1
     assert ws == 2 * 7   # conv5's six 1x1s and its projection at b1, twice
+    # 3 dense conv2 3x3s (64), 3 pruned conv2 (32) and 4 pruned conv3 (64)
+    assert {k: v // 2 for k, v in folded.items() if v} == \
+        {(False, 64): 3, (True, 32): 3, (True, 64): 4}
+
+
+# layer scope: (x shape, K) of the folded 3x3s the cells run
+FOLDED = {
+    "vgg16_conv1_2": ((1, 224, 224, 64), 64),
+    "vgg16_conv2_1": ((1, 112, 112, 64), 128),
+    "resnet50_conv2_3x3_b1": ((1, 56, 56, 64), 64),
+    "resnet50_conv2_3x3_b32": ((32, 56, 56, 64), 64),
+    "pruned_conv2_3x3_b32": ((32, 56, 56, 32), 32),
+    "pruned_conv3_3x3_b32": ((32, 28, 28, 64), 64),
+}
+
+
+@pytest.mark.parametrize("layer", list(FOLDED))
+def test_folded_3x3_compiles_as_one_conv2d_kernel_in_its_scope(layer, one_chip):
+    """A folded 3x3, inside its layer's scope as ``carla_conv`` runs it, is
+    one ``_conv2d_kernel`` within VMEM, its fold runs under
+    ``<layer>/.../col_fold`` (the scope ``bench/layers.py`` shows), and
+    nothing in it is a gather."""
+    xs, k = FOLDED[layer]
+
+    def spec(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    def fwd(x, w, b):
+        return carla_conv(x, w, padding=1, impl="pallas", name=layer,
+                          epilogue=Epilogue(bias=b, relu=True))
+
+    text = jax.jit(fwd).lower(spec(*xs), spec(3, 3, xs[3], k),
+                              spec(k)).compile().as_text()
+    calls = _named_custom_calls(text)
+    assert len(calls) == 1
+    assert f"/{layer}/" in calls[0] and "/_conv2d_kernel/" in calls[0]
+    assert re.search(rf'op_name="[^"]*/{layer}/jit\(_conv2d_jit\)/col_fold/',
+                     text)
+    assert not re.search(r"\bgather\(", text)
 
 
 def test_stem_compiles_without_a_gather(one_chip):

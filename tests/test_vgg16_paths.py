@@ -66,7 +66,8 @@ def test_conv2d_in_row_blocks_matches_reference(th, epilogue, monkeypatch):
 
 def test_row_blocks_take_the_whole_channel_axis(monkeypatch):
     """A plane in row blocks reads its whole C in one channel block, whatever
-    ``bc`` asks for, so ``tile_util`` counts no channel padding there."""
+    ``bc`` asks for, so ``tile_util`` counts one block of 128 lanes for C
+    and one for K, not three blocks of ``bc = 8``."""
     monkeypatch.setattr(sys.modules["repro.kernels.conv2d"], "row_block",
                         lambda *args, **kw: 6)
     key = jax.random.PRNGKey(11)
@@ -78,7 +79,7 @@ def test_row_blocks_take_the_whole_channel_axis(monkeypatch):
     assert _err(got, ref.conv2d_ref(x, w, padding=1, **ep)) < 1e-4
     tiles = autotune.TileConfig(bk=6, bc=8)
     assert autotune.tile_util_conv2d(x.shape, w.shape, tiles, padding=1) \
-        == pytest.approx(20 / 24)    # the last block's 4 padded rows alone
+        == pytest.approx(20 * 6 / (128 * 128) * 20 / 24)  # 4 padded rows
 
 
 def test_row_blocks_split_vgg16s_large_planes_and_bound_the_halo():
@@ -93,20 +94,25 @@ def test_row_blocks_split_vgg16s_large_planes_and_bound_the_halo():
 
 def test_row_block_span_counts_the_halo_bytes(monkeypatch):
     """An eager dispatch in row blocks records them, and each block past the
-    first re-reads FH - 1 padded rows of C channels."""
+    first re-reads FH - 1 padded rows of the input the kernel reads: 32
+    channels fold 4 columns into 128 lanes, so a row is 11 groups of 128
+    (40 columns in 10 groups padded to 16, and one more for the taps)."""
     monkeypatch.setattr(sys.modules["repro.kernels.conv2d"], "VMEM_BUDGET",
                         2**20)
     x = jax.random.normal(jax.random.PRNGKey(4), (1, 40, 40, 32))
     w = jax.random.normal(jax.random.PRNGKey(5), (3, 3, 32, 16))
-    th = row_block(x.shape, w.shape, padding=1)
+    xk, wk, pk = autotune.conv2d_kernel_shapes(x.shape, w.shape, 1, 1)
+    assert xk[2:] == (17, 128) and pk == 0
+    th = row_block(xk, wk, padding=pk)
     n_r = -(-40 // th)
     assert n_r > 1
     with trace.capture() as tr:
         got = ops.conv2d(x, w, padding=1, impl="pallas")
     (sp,) = tr.spans
     assert sp.attrs["kernel"] == "conv2d" and sp.attrs["row_blocks"] == n_r
+    assert sp.attrs["col_fold"] == 4
     plain = 4 * (x.size + w.size + got.size)
-    assert sp.attrs["bytes_touched"] - plain == 4 * (n_r - 1) * 2 * 42 * 32
+    assert sp.attrs["bytes_touched"] - plain == 4 * (n_r - 1) * 2 * 17 * 128
     assert _err(got, ref.conv2d_ref(x, w, padding=1)) < 1e-4
 
 
@@ -191,8 +197,9 @@ def test_vgg16_pallas_forward_matches_the_benchmark_reference(seed):
 
 def test_the_tuner_keys_a_conv_by_the_kernel_that_runs_it():
     """The tuner keys VGG-16's conv1_1 and the smoke set's small 3x3s by
-    their im2col GEMM, as the dispatch looks them up, and no committed table
-    holds an entry that no dispatch can hit."""
+    their im2col GEMM, and conv1_2 and conv2_1 (64 channels) by the folded
+    conv, as the dispatch looks them up, and no committed table holds an
+    entry that no dispatch can hit."""
     import json
 
     sys.path.insert(0, ROOT)
@@ -201,6 +208,8 @@ def test_the_tuner_keys_a_conv_by_the_kernel_that_runs_it():
     keys = [tuner._layer_key(layer, 1) for layer in vgg16_conv_layers()]
     assert keys[0] == "gemm|m50176|c27|k64|float32|ep:none"
     assert all(k.startswith("conv2d|") for k in keys[1:])
+    assert keys[1] == "conv2d|x1x226x113x128|f3x2x128|s1p0|float32|ep:none"
+    assert keys[2] == "conv2d|x1x114x57x128|f3x2x256|s1p0|float32|ep:none"
     assert tuner._layer_key(smoke_conv_layers()[0], 1).startswith("gemm|m196|c72|")
     tables = os.path.join(ROOT, "src", "repro", "kernels", "tuned")
     for name in sorted(os.listdir(tables)):
@@ -208,7 +217,10 @@ def test_the_tuner_keys_a_conv_by_the_kernel_that_runs_it():
             for key in json.load(f)["entries"]:
                 if key.startswith("conv2d|"):
                     fh, fw, k = map(int, key.split("|")[2][1:].split("x"))
-                    c = int(key.split("|")[1].split("x")[-1])
+                    x_shape = tuple(map(int, key.split("|")[1][1:].split("x")))
                     stride = int(key.split("|")[3][1:].split("p")[0])
-                    assert not autotune.runs_as_gemm((fh, fw, c, k), stride), \
+                    w_shape = (fh, fw, x_shape[3], k)
+                    assert not autotune.runs_as_gemm(w_shape, stride), \
+                        (name, key)
+                    assert autotune.col_fold(x_shape, w_shape, stride) == 1, \
                         (name, key)
