@@ -33,7 +33,6 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
-from repro.core import autotune  # noqa: E402
 from repro.models.cnn import (  # noqa: E402
     resnet50_apply,
     resnet50_init,
@@ -128,10 +127,6 @@ def main(argv=None) -> int:
     if os.environ.get("REPRO_IMPL", "pallas") != "pallas":
         print(f"chip_smoke: REPRO_IMPL={os.environ['REPRO_IMPL']!r} would "
               "replace the pallas path; unset it", file=sys.stderr)
-        return 1
-    if autotune.enabled():
-        print("chip_smoke: autotuning must be off (REPRO_AUTOTUNE)",
-              file=sys.stderr)
         return 1
 
     print(f"device: {dev.device_kind} x{len(devices)} ({dev.platform})")

@@ -1,6 +1,5 @@
 """CARLA core: the paper's contribution as composable JAX modules."""
-from . import autotune
-from .autotune import TileConfig, kernel_signature_hash
+from . import route
 from .carla import ConvPlan, carla_conv, plan_conv
 from .cost_model import (
     LayerCost,
@@ -37,12 +36,12 @@ from .sparsity import (
 
 __all__ = [
     "ConvLayer", "ConvPlan", "Dataflow", "Epilogue", "LayerCost",
-    "NetworkCost", "SparsityTag", "Stationarity", "TileConfig",
-    "apply_epilogue", "autotune", "carla_conv",
+    "NetworkCost", "SparsityTag", "Stationarity",
+    "apply_epilogue", "carla_conv",
     "epilogue_dram_delta", "epilogue_dram_delta_bytes", "fold_bn",
-    "fold_bn_into_conv", "kernel_signature_hash", "layer_cost",
+    "fold_bn_into_conv", "layer_cost",
     "network_cost", "plan_conv", "prune_bn", "prune_conv_weights",
-    "prune_plan",
+    "prune_plan", "route",
     "resnet50_conv_layers", "resnet50_projection_shortcuts", "resnet50_cost",
     "select_dataflow", "select_stationarity", "smoke_conv_layers",
     "sparse_conv_layers", "topk_channel_mask",

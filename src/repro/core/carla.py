@@ -1,11 +1,15 @@
 """CARLA public API: reconfigurable convolution with per-layer mode dispatch.
 
 ``carla_conv`` is the paper's accelerator as a composable JAX op: given any
-NHWC convolution, it consults the controller (``core.modes``) to pick the
-dataflow the ASIC would have used, routes to the corresponding kernel, and can
-report the analytic cost (cycles / DRAM accesses / PUF) the ASIC model
-predicts for that layer — so a network built from ``carla_conv`` carries its
-own performance model, exactly like the paper's evaluation methodology.
+NHWC convolution, it plans the layer (:func:`plan_conv`) and runs the plan.
+The plan carries both of the controller's decisions.  ``dataflow`` is the
+mode the ASIC would have used (``core.modes.select_dataflow``), with the
+analytic cost (cycles / DRAM accesses / PUF) the ASIC model predicts for
+that layer, so a network built from ``carla_conv`` carries its own
+performance model, exactly like the paper's evaluation methodology.
+``route`` is what runs on the TPU (``core.route.route``): the Pallas
+kernel, the GEMM's stationarity and the column fold, the same function
+``kernels.ops`` branches on.
 
 Passing ``epilogue=Epilogue(scale, bias, relu, residual)`` fuses folded-BN,
 the shortcut add, and the activation into the kernel's flush step (see
@@ -15,22 +19,26 @@ on-chip partial-result residency.
 """
 from __future__ import annotations
 
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 
 import jax
 import jax.numpy as jnp
 
 from repro.kernels import ops
 from repro.observability import trace
-from . import autotune
-from .autotune import TileConfig
 from .cost_model import LayerCost, layer_cost
 from .fuse import Epilogue
 from .modes import ConvLayer, Dataflow, select_dataflow
+from .route import Route, route
 from .sparsity import SparsityTag
 
 
 _NO_EPILOGUE = Epilogue()
+
+# What the kernel span measures of the dispatch it ran; the carla_conv span
+# copies them from its child rather than computing them a second time.
+_MEASURED = ("kernel", "stationarity", "col_fold", "bytes_touched",
+             "tile_util", "epilogue_hbm_saved")
 
 
 @dataclass(frozen=True)
@@ -38,58 +46,25 @@ class ConvPlan:
     layer: ConvLayer
     dataflow: Dataflow          # the analytic controller rule's choice
     cost: LayerCost
-    # empirical tuning-cache hit for this layer's shape key (None = miss or
-    # tuning disabled); ``tuning_source`` says where the plan came from.
-    tile_config: TileConfig | None = field(default=None, compare=False)
-    tuning_source: str = field(default="analytic", compare=False)
-
-    @property
-    def effective_dataflow(self) -> Dataflow:
-        """The dataflow the dispatch will actually run: a measured
-        stationarity in the tuning cache overrides the analytic 1x1 rule."""
-        if (self.layer.FL == 1 and self.tile_config is not None
-                and self.tile_config.stationarity):
-            if self.tile_config.stationarity == "weight_stationary":
-                return Dataflow.CONV1X1_WEIGHT_STATIONARY
-            return Dataflow.CONV1X1_FEATURE_STATIONARY
-        return self.dataflow
+    route: Route                # what the Pallas path runs
 
 
 def plan_conv(x_shape: tuple[int, ...], w_shape: tuple[int, ...],
-              stride: int = 1, padding: int = 0, name: str = "conv",
-              dtype: str = "float32",
-              epilogue_tag: str = "none") -> ConvPlan:
-    """Controller decision + analytic cost for a conv of the given shapes.
-
-    When the empirical tuning cache is enabled (``core.autotune``) the plan
-    consults it first: a hit carries measured tile sizes — and, for 1x1
-    layers, the measured stationarity choice (``effective_dataflow``) — while
-    ``dataflow``/``cost`` always report the paper's analytic rule so the two
-    can be reconciled.
-    """
-    b, h, w_sp, cin = x_shape
-    fh, fw, _, k = w_shape
+              stride: int = 1, padding: int = 0,
+              name: str = "conv") -> ConvPlan:
+    """Controller decision + analytic cost for a conv of the given shapes:
+    the ASIC's ``dataflow`` and ``cost``, and the TPU's ``route``."""
+    _, h, _, cin = x_shape
+    fh, _, _, k = w_shape
     layer = ConvLayer(name, IL=h, IC=cin, K=k, FL=fh, S=stride, Z=padding)
-    entry = None
-    if autotune.enabled():
-        if fh == 1 and fw == 1:
-            rows = b * -(-h // stride) * -(-w_sp // stride)
-            entry = autotune.lookup_gemm(rows, cin, k, dtype, epilogue_tag)
-        else:
-            entry = autotune.lookup_conv2d(x_shape, w_shape, stride, padding,
-                                           dtype, epilogue_tag)
     return ConvPlan(layer, select_dataflow(layer), layer_cost(layer),
-                    tile_config=entry.config if entry is not None else None,
-                    tuning_source=(entry.source if entry is not None
-                                   else "analytic"))
+                    route(x_shape, w_shape, stride, padding))
 
 
 def _dispatch(x, w, plan: ConvPlan, stride: int, padding: int, impl: str,
               epilogue: Epilogue | None):
-    if plan.dataflow in (Dataflow.CONV1X1_FEATURE_STATIONARY,
-                         Dataflow.CONV1X1_WEIGHT_STATIONARY):
-        # Both 1x1 modes are the dual-stationarity GEMM; ops.conv1x1 picks the
-        # residency from the feature count (the same quantity the paper uses).
+    if plan.route.kernel == "gemm":
+        # Both 1x1 modes are the dual-stationarity GEMM.
         return ops.conv1x1(x, w[0, 0], stride=stride, impl=impl,
                            epilogue=epilogue)
 
@@ -121,41 +96,27 @@ def carla_conv(x: jnp.ndarray, w: jnp.ndarray, *, stride: int = 1,
     records a ``carla_conv`` span carrying both sides of the paper's ledger:
     the dataflow the controller picked with its analytic ``LayerCost``
     (cycles / DRAM bytes / PUF), the epilogue combination that was fused
-    (``epilogue=`` attr + ``epilogue_hbm_saved`` bytes), and the measured wall
-    time + bytes of the kernel it actually ran (as a child span from
-    ``kernels.ops``).  A dispatch traced inside a jit opens no span.
+    (``epilogue=`` attr), and what its one ``kernels.*`` child span measured
+    of the kernel that ran: ``kernel``, ``stationarity``, ``col_fold``,
+    ``bytes_touched``, ``tile_util`` and ``epilogue_hbm_saved``.  A dispatch
+    traced inside a jit opens no span.
     """
     if w.ndim == 2:
         w = w[None, None]
-    ep = epilogue or _NO_EPILOGUE
-    plan = plan_conv(x.shape, w.shape, stride, padding, name=name,
-                     dtype=str(x.dtype), epilogue_tag=ep.tag)
+    plan = plan_conv(x.shape, w.shape, stride, padding, name=name)
 
     with jax.named_scope(name):
         if not trace.timed(x):
             return _dispatch(x, w, plan, stride, padding, impl, epilogue)
         return _timed_dispatch(x, w, plan, stride, padding, impl, epilogue,
-                               ep, sparsity)
+                               sparsity)
 
 
 def _timed_dispatch(x, w, plan: ConvPlan, stride: int, padding: int,
-                    impl: str, epilogue: Epilogue | None, ep: Epilogue,
+                    impl: str, epilogue: Epilogue | None,
                     sparsity: SparsityTag | None):
     """An eager dispatch inside a ``carla_conv`` span (see ``carla_conv``)."""
     cost = plan.cost
-    if plan.layer.FL == 1:
-        rows = (x.shape[0] * -(-x.shape[1] // stride)
-                * -(-x.shape[2] // stride))
-        tile_util = autotune.tile_util_gemm(
-            rows, plan.layer.IC, plan.layer.K, plan.tile_config,
-            stationarity="weight_stationary"
-            if plan.effective_dataflow == Dataflow.CONV1X1_WEIGHT_STATIONARY
-            else "activation_stationary")
-    else:
-        tile_util = autotune.tile_util_conv2d(x.shape, w.shape,
-                                              plan.tile_config, stride=stride,
-                                              padding=padding,
-                                              has_res=ep.residual is not None)
     sparse_attrs = {}
     if sparsity is not None:
         sparse_attrs = {
@@ -166,7 +127,8 @@ def _timed_dispatch(x, w, plan: ConvPlan, stride: int, padding: int,
         }
     with trace.span(
             "carla_conv", layer=plan.layer.name,
-            dataflow=plan.dataflow.value, epilogue=ep.tag,
+            dataflow=plan.dataflow.value,
+            epilogue=(epilogue or _NO_EPILOGUE).tag,
             x_shape=list(x.shape), w_shape=list(w.shape),
             stride=stride, padding=padding, batch=int(x.shape[0]),
             macs=cost.macs, dense_macs=plan.layer.dense_macs,
@@ -174,29 +136,10 @@ def _timed_dispatch(x, w, plan: ConvPlan, stride: int, padding: int,
             analytic_time_ms=cost.time_s * 1e3,
             analytic_dram_bytes=cost.dram_bytes,
             analytic_puf=cost.puf,
-            tuned=plan.tile_config is not None,
-            tile_config=(plan.tile_config.short
-                         if plan.tile_config is not None else "default"),
-            tuning_source=plan.tuning_source,
-            tile_util=tile_util,
-            effective_dataflow=plan.effective_dataflow.value,
             **sparse_attrs) as sp:
         out = _dispatch(x, w, plan, stride, padding, impl, epilogue)
         jax.block_until_ready(out)
-        # bytes the dispatch actually touched (operands + result); the child
-        # kernel span records the same so nested sums stay consistent.  A
-        # strided 1x1 only reads the subsampled input view, and fused epilogue
-        # operands (scale/bias vectors, residual) are part of the footprint.
-        if plan.layer.FL == 1 and stride != 1:
-            x_bytes = (x.shape[0] * -(-x.shape[1] // stride)
-                       * -(-x.shape[2] // stride) * x.shape[3]
-                       * x.dtype.itemsize)
-        else:
-            x_bytes = x.size * x.dtype.itemsize
-        sp.attrs["bytes_touched"] = x_bytes + sum(
-            a.size * a.dtype.itemsize for a in (w, out, ep.scale, ep.bias,
-                                                ep.residual) if a is not None)
-        if ep.n_fused_ops:
-            sp.attrs["epilogue_hbm_saved"] = \
-                2 * ep.n_fused_ops * out.size * out.dtype.itemsize
+        (child,) = sp.children
+        sp.attrs.update({k: child.attrs[k] for k in _MEASURED
+                         if k in child.attrs})
     return out

@@ -21,7 +21,7 @@ The paper's §III.A dataflow, transplanted to the TPU memory hierarchy:
   MXU's 128 lanes wastes the rest of them in both the contraction and the
   output (C = K = 64 uses a quarter of the MXU).  For a conv whose C is 32
   or 64, ``kernels.ops`` folds ``f = 128 / C`` adjacent output columns into
-  the lanes before calling this kernel (``core.autotune.col_fold``): the
+  the lanes before calling this kernel (``core.route.col_fold``): the
   input becomes ``f*C`` lanes per column group, the weights ``(FH, TWf,
   f*C, f*K)`` with the zero taps of the fold, and the output ``f*K`` lanes
   per group.  This kernel runs the folded conv as any other, at padding 0.
@@ -68,11 +68,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .matmul import mxu_dot
 
-# Default channel tiles — the fallback operating point.  The empirical
-# autotuner (``core.autotune``) selects per-layer-shape ``bk/bc`` by
-# measurement; ``kernels.ops`` passes the cached winner through the keyword
-# arguments of ``conv2d``.  ``core.autotune.DEFAULT_CONV2D`` mirrors these
-# values (test-enforced).
+# Channel tiles: the ones every dispatch of ``kernels.ops`` runs, and the ones
+# ``core.route.tile_util_conv2d`` counts.  ``conv2d``'s ``bk``/``bc`` keyword
+# arguments exist for tests of ragged tiles.
 BK = 128   # output-channel tile
 BC = 128   # input-channel tile
 

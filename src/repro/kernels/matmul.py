@@ -27,10 +27,11 @@ scale/bias (folded BN), a residual operand, and ReLU, applied on the fp32
 accumulator in the flush step so the output crosses HBM exactly once (the
 1x1 convs of a bottleneck block route here via ``ops.conv1x1``).
 
-``kernels.ops`` picks the variant via ``core.modes.select_stationarity`` — the
-software twin of CARLA's controller — and decides ``interpret`` from the
-platform.  Grid pipelining double-buffers the streamed operand, the TPU
-analogue of the paper's paired wide/narrow SRAMs.
+``kernels.ops`` runs the variant ``core.route.route`` picks (by
+``core.modes.select_stationarity``, the software twin of CARLA's controller)
+and decides ``interpret`` from the platform.  Grid pipelining
+double-buffers the streamed operand, the TPU analogue of the paper's paired
+wide/narrow SRAMs.
 """
 from __future__ import annotations
 
@@ -42,11 +43,9 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# MXU-aligned default tiles.  These are the *fallback* operating point: the
-# empirical autotuner (``core.autotune`` + ``benchmarks/autotune.py``) selects
-# per-shape ``bm/bk/bc`` — and the stationarity itself — by measurement, and
-# ``kernels.ops`` threads the cached winner through the keyword arguments
-# below.  ``core.autotune.DEFAULT_GEMM`` mirrors these values (test-enforced).
+# MXU-aligned blocks of the activation-stationary kernel: the ones every
+# dispatch of ``kernels.ops`` runs, and the ones ``core.route.tile_util_gemm``
+# counts.  The keyword arguments below exist for tests of ragged blocks.
 BM, BK, BC = 128, 128, 512
 # VMEM for the weight-stationary kernel's streamed weight blocks, fp32,
 # double-buffered: blocks of up to 4 MiB keep the DMA engine busy between
@@ -178,7 +177,7 @@ def _weight_vmem(rows: int, cols: int) -> int:
 def ws_blocks(c: int, k: int, bk: int | None = None) -> tuple[int, int]:
     """``(bk, bc)`` of the weight-stationary GEMM; ``bc == c`` is one C block.
 
-    Unless a tuned ``bk`` is given, ``bk`` divides K (a whole-K block where
+    Unless a ``bk`` is given, ``bk`` divides K (a whole-K block where
     128 does not), so the weights are not copied to a padded array on each
     call.  The whole C is one block when its ``(C, bk)`` weight block fits
     :data:`WS_BUDGET`.  Otherwise the kernel streams whole weight rows

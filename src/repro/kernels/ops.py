@@ -9,19 +9,15 @@
                    plain HLO convolutions/GEMMs);
   * ``"auto"``   — pallas on TPU backends, ref elsewhere.
 
-The ``REPRO_IMPL`` environment variable overrides all of it — benchmarks and
-the autotuner force ``pallas``/``ref`` without editing call sites.  The
-resolved impl is recorded as the ``impl`` span attribute.
+The ``REPRO_IMPL`` environment variable overrides all of it, so a whole
+program can be forced onto ``pallas`` or ``ref`` without editing call sites.
+The resolved impl is recorded as the ``impl`` span attribute.
 
-Mode selection (which dataflow/stationarity) is orthogonal to ``impl`` and
-follows ``core.modes`` — the software twin of CARLA's controller — unless the
-empirical tuning cache (``core.autotune``) holds a measured winner for the
-layer's shape key, in which case the cached tile sizes *and* stationarity are
-used instead.  The lookup is gated on ``autotune.enabled()`` (one attribute
-read, so the disabled path costs nothing) and is an O(1) dict hit; the
-resulting :class:`~repro.core.autotune.TileConfig` is hashable and rides
-through ``jax.jit`` as a static argument, so a cache hit re-uses the already
-compiled tuned kernel with zero per-call overhead.
+Which kernel runs a conv on the pallas path, in which GEMM stationarity and
+with which column fold, is ``core.route.route``'s decision, from the shapes
+alone; ``conv2d`` and ``conv1x1`` branch on it, and each kernel runs its own
+default blocks.  ``gemm`` takes an explicit ``stationarity=`` from its
+callers, else ``core.modes.select_stationarity``'s, the rule ``route`` uses.
 
 ``conv2d``/``conv1x1``/``gemm`` accept an ``epilogue=`` (``core.fuse.Epilogue``):
 folded-BN scale/bias, residual add, and ReLU are applied inside the kernel's
@@ -32,36 +28,34 @@ vs. the unfused op sequence (``epilogue_hbm_saved``).
 
 Every public entry point is telemetry-instrumented: when the global tracer is
 enabled (``observability.trace``) and the call is eager, the dispatch records
-which mode the controller picked, operand shapes/bytes, FLOPs, wall time under
-``block_until_ready``, and the tuning ledger — ``tuned`` (did the cache hit),
-``tile_config``/``tuning_source`` (what ran and why), and ``tile_util`` (the
-padding-waste PUF analogue: logical FLOPs / padded FLOPs under the tiling
-that actually ran).  When tracing is disabled (the default), or the call is
+operand shapes, FLOPs, wall time under ``block_until_ready``, the bytes it
+touched, what ran (``kernel``, ``stationarity``, ``col_fold``) and
+``tile_util`` (logical FLOPs / padded FLOPs under the kernel's blocks).
+These spans are the one record of them: ``carla_conv``'s span copies them
+from its child.  When tracing is disabled (the default), or the call is
 traced inside an outer ``jax.jit``, the jitted function is invoked directly:
 no span objects, no clock reads, no ``block_until_ready``.  There the record
-is the name of the ops: the strided route's patches run under
+is the name of the ops: the im2col route's patches run under
 ``jax.named_scope("im2col")`` and its GEMM under ``"gemm"``, the column
 fold under ``"col_fold"``, inside the layer's scope that ``carla_conv``
 opens, and each Pallas kernel keeps its ``pallas_call`` name.
 
 A strided conv (``stride > 1``, ResNet-50's 7x7/2 stem) and a unit-stride
 one whose patch row ``FH*FW*C`` fits one 128-lane tile (VGG-16's conv1_1,
-3 channels) run as one GEMM on the matmul kernels
-(``core.autotune.runs_as_gemm``), over patches built by space-to-depth and
-unit-stride slices (:func:`_im2col`), never by strided indexing, which XLA
-lowers to one gather per tap.  Every other conv runs the conv2d kernel, in
-row blocks where the whole plane does not fit VMEM.  One whose C is below
-128 lanes and divides 128 (VGG-16's conv1_2 and conv2_1, ResNet-50's conv2
-3x3s, the pruned conv3 3x3s) runs it with ``f = 128 // C`` adjacent output
-columns folded into the lanes (``core.autotune.col_fold``,
-:func:`_conv2d_folded`): the padded input read as ``f*C`` lanes, the
+3 channels) run as one GEMM on the matmul kernels, over patches built by
+space-to-depth and unit-stride slices (:func:`_im2col`), never by strided
+indexing, which XLA lowers to one gather per tap.  Every other conv runs
+the conv2d kernel, in row blocks where the whole plane does not fit VMEM.
+One whose C is below 128 lanes and divides 128 (VGG-16's conv1_2 and
+conv2_1, ResNet-50's conv2 3x3s, the pruned conv3 3x3s) runs it with
+``f = 128 // C`` adjacent output columns folded into the lanes
+(:func:`_conv2d_folded`): the padded input read as ``f*C`` lanes, the
 weights refolded with zero taps, so each dot fills the MXU's 128 lanes of
-C and of K where 64 channels filled a quarter of it.  The kernel, its row
-blocks and its tiles then see the folded conv
-(``core.autotune.conv2d_kernel_shapes``).  An eager span records
-``col_fold`` (f, or 1) and ``row_blocks`` and counts the halo rows that
-blocks re-read in ``bytes_touched``, and a 1x1's span records the GEMM's
-``c_blocks``.
+C and of K where 64 channels filled a quarter of it.  The kernel and its
+row blocks then see the folded conv (``core.route.conv2d_kernel_shapes``).
+An eager span records ``col_fold`` (f, or 1) and ``row_blocks`` and counts
+the halo rows that blocks re-read in ``bytes_touched``, and a 1x1's span
+records the GEMM's ``c_blocks``.
 """
 from __future__ import annotations
 
@@ -71,10 +65,14 @@ import os
 import jax
 import jax.numpy as jnp
 
-from repro.core import autotune
-from repro.core.autotune import TileConfig
 from repro.core.fuse import Epilogue
 from repro.core.modes import Stationarity, select_stationarity
+from repro.core.route import (
+    conv2d_kernel_shapes,
+    route,
+    tile_util_conv2d,
+    tile_util_gemm,
+)
 from repro.observability import trace
 from . import ref as _ref
 from .conv1d import conv1d_causal as _conv1d_pallas
@@ -101,15 +99,6 @@ def _resolve(impl: str) -> str:
     return impl
 
 
-def _lookup(kind: str, key_args, impl: str):
-    """Tuning-cache probe: O(1) dict hit, only on the resolved pallas path."""
-    if not autotune.enabled() or impl != "pallas":
-        return None
-    if kind == "conv2d":
-        return autotune.lookup_conv2d(*key_args)
-    return autotune.lookup_gemm(*key_args)
-
-
 def _nbytes(*arrays) -> int:
     return sum(a.size * a.dtype.itemsize for a in arrays if a is not None)
 
@@ -122,13 +111,6 @@ def _epilogue_attrs(sp, ep: Epilogue, out) -> None:
         # output feature map through HBM; the fused flush does neither.
         sp.attrs["epilogue_hbm_saved"] = \
             2 * ep.n_fused_ops * out.size * out.dtype.itemsize
-
-
-def _tuning_attrs(sp, entry, tiles: TileConfig | None) -> None:
-    """Record what the tuning cache contributed to this dispatch."""
-    sp.attrs["tuned"] = entry is not None
-    sp.attrs["tile_config"] = tiles.short if tiles is not None else "default"
-    sp.attrs["tuning_source"] = entry.source if entry is not None else "default"
 
 
 def _im2col(x, w, stride: int, padding: int):
@@ -165,7 +147,7 @@ def _im2col(x, w, stride: int, padding: int):
 
 def _col_fold(x, w, scale, bias, residual, padding: int, rows: int):
     """The operands of a conv folded along W into the lanes, for the conv2d
-    kernel at padding 0 (:func:`~repro.core.autotune.conv2d_kernel_shapes`),
+    kernel at padding 0 (:func:`~repro.core.route.conv2d_kernel_shapes`),
     with ``rows`` more zero rows below the plane (and the residual): the
     kernel's last row block, which it would otherwise pad in a second copy.
 
@@ -181,7 +163,7 @@ def _col_fold(x, w, scale, bias, residual, padding: int, rows: int):
     """
     b, _, wd, c = x.shape
     fh, fw, _, k = w.shape
-    xs, ws, _ = autotune.conv2d_kernel_shapes(x.shape, w.shape, 1, padding)
+    xs, ws, _ = conv2d_kernel_shapes(x.shape, w.shape, 1, padding)
     f, twf = xs[3] // c, ws[1]
     owf = xs[2] - twf + 1
     xp = jnp.pad(x, ((0, 0), (padding, padding + rows),
@@ -197,38 +179,36 @@ def _col_fold(x, w, scale, bias, residual, padding: int, rows: int):
     return xp.reshape(b, -1, xs[2], xs[3]), wf, scale, bias, residual
 
 
-def _conv2d_folded(x, w, scale, bias, residual, *, relu: bool, padding: int,
-                   tiles: TileConfig | None):
+def _conv2d_folded(x, w, scale, bias, residual, *, relu: bool, padding: int):
     """A conv whose C fills half or a quarter of the MXU's 128 lanes, run on
-    the conv2d kernel with ``autotune.col_fold`` adjacent output columns
+    the conv2d kernel with ``route(...).col_fold`` adjacent output columns
     folded into the lanes, so that each dot contracts and writes all 128;
     the fold and the unfold run under ``jax.named_scope("col_fold")``."""
     b, h, wd, _ = x.shape
     fh, fw, _, k = w.shape
     oh, ow = h + 2 * padding - fh + 1, wd + 2 * padding - fw + 1
-    xs, ws, _ = autotune.conv2d_kernel_shapes(x.shape, w.shape, 1, padding)
-    th = row_block(xs, ws, padding=0, has_res=residual is not None,
-                   **_conv2d_tiles(tiles))
+    xs, ws, _ = conv2d_kernel_shapes(x.shape, w.shape, 1, padding)
+    th = row_block(xs, ws, padding=0, has_res=residual is not None)
     with jax.named_scope("col_fold"):
         x, w, scale, bias, residual = _col_fold(
             x, w, scale, bias, residual, padding, -(-oh // th) * th - oh)
     out = _conv2d_pallas(x, w, padding=0, scale=scale, bias=bias, relu=relu,
-                         residual=residual, interpret=not _on_tpu(),
-                         **_conv2d_tiles(tiles))
+                         residual=residual, interpret=not _on_tpu())
     with jax.named_scope("col_fold"):
         return out.reshape(b, out.shape[1], -1, k)[:, :oh, :ow]
 
 
-@functools.partial(
-    jax.jit, static_argnames=("stride", "padding", "impl", "relu", "tiles"))
+@functools.partial(jax.jit,
+                   static_argnames=("stride", "padding", "impl", "relu"))
 def _conv2d_jit(x, w, scale=None, bias=None, residual=None, *,
                 relu: bool = False, stride: int = 1, padding: int = 0,
-                impl: str = "auto", tiles: TileConfig | None = None):
+                impl: str = "auto"):
     if _resolve(impl) != "pallas":
         return _ref.conv2d_ref(x, w, stride=stride, padding=padding,
                                scale=scale, bias=bias, relu=relu,
                                residual=residual).astype(x.dtype)
-    if autotune.runs_as_gemm(w.shape, stride):
+    r = route(x.shape, w.shape, stride, padding)
+    if r.kernel == "im2col_gemm":
         # Strided convs (ResNet-50's 7x7/2 stem) run as one GEMM over im2col
         # patches: Mosaic refuses a strided slice inside the conv2d kernel,
         # and the patch columns fill lanes that a 3-channel input block would
@@ -243,67 +223,54 @@ def _conv2d_jit(x, w, scale=None, bias=None, residual=None, *,
         rf = residual.reshape(b * oh * ow, k) if residual is not None else None
         with jax.named_scope("gemm"):
             out = _tiled_matmul(p.reshape(b * oh * ow, kk), wf,
-                                scale, bias, relu, rf, tiles)
+                                scale, bias, relu, rf, r.stationarity)
         return out.reshape(b, oh, ow, k)
-    if autotune.col_fold(x.shape, w.shape, stride) > 1:
+    if r.col_fold > 1:
         return _conv2d_folded(x, w, scale, bias, residual, relu=relu,
-                              padding=padding, tiles=tiles)
+                              padding=padding)
     return _conv2d_pallas(x, w, padding=padding, scale=scale, bias=bias,
                           relu=relu, residual=residual,
-                          interpret=not _on_tpu(), **_conv2d_tiles(tiles))
-
-
-def _conv2d_tiles(tiles: TileConfig | None) -> dict:
-    """The conv2d kernel's tile keywords from a tuning entry."""
-    if tiles is None:
-        return {}
-    return {n: v for n, v in (("bk", tiles.bk), ("bc", tiles.bc)) if v}
+                          interpret=not _on_tpu())
 
 
 def conv2d(x, w, *, stride: int = 1, padding: int = 0, impl: str = "auto",
            epilogue: Epilogue | None = None):
     """General NHWC conv; CARLA 3x3/7x7 serial-accumulation dataflow.
 
-    On the pallas path a strided conv runs as an im2col GEMM, tuned and
-    reported under that GEMM's shape, and a conv of 32 or 64 channels as
-    its column fold, tuned under the folded conv's shape; the span's
-    ``kernel`` attribute records which kernel ran, ``col_fold`` the fold.
+    On the pallas path a strided conv runs as an im2col GEMM and a conv of
+    32 or 64 channels as its column fold (``core.route.route``); an
+    unpadded 1x1 is :func:`conv1x1`.  The span's ``kernel`` attribute
+    records which kernel ran, ``col_fold`` the fold.
     """
     ep = epilogue or _NO_EPILOGUE
     impl = _resolve(impl)
-    entry = _lookup("conv2d",
-                    (x.shape, w.shape, stride, padding, x.dtype, ep.tag), impl)
-    tiles = entry.config if entry is not None else None
+    r = route(x.shape, w.shape, stride, padding)
+    if r.kernel == "gemm":
+        return conv1x1(x, w[0, 0], stride=stride, impl=impl, epilogue=epilogue)
     if not trace.timed(x):
         return _conv2d_jit(x, w, ep.scale, ep.bias, ep.residual, relu=ep.relu,
-                           stride=stride, padding=padding, impl=impl,
-                           tiles=tiles)
+                           stride=stride, padding=padding, impl=impl)
     fh, fw, _, k = w.shape
     with trace.span("kernels.conv2d", impl=impl,
                     x_shape=list(x.shape), w_shape=list(w.shape),
                     stride=stride, padding=padding,
                     dtype=str(x.dtype)) as sp:
         out = _conv2d_jit(x, w, ep.scale, ep.bias, ep.residual, relu=ep.relu,
-                          stride=stride, padding=padding, impl=impl,
-                          tiles=tiles)
+                          stride=stride, padding=padding, impl=impl)
         jax.block_until_ready(out)
         b, oh, ow, _ = out.shape
         halo = 0
         if impl == "pallas":
-            as_gemm = autotune.runs_as_gemm(w.shape, stride)
-            sp.attrs["kernel"] = "im2col_gemm" if as_gemm else "conv2d"
-            if as_gemm:
-                sp.attrs["stationarity"] = _gemm_stationarity(b * oh * ow,
-                                                              tiles).value
+            sp.attrs["kernel"] = r.kernel
+            if r.kernel == "im2col_gemm":
+                sp.attrs["stationarity"] = r.stationarity.value
             else:
                 # the conv the kernel runs: folded, where col_fold > 1
-                xk, wk, pk = autotune.conv2d_kernel_shapes(x.shape, w.shape,
-                                                           stride, padding)
-                sp.attrs["col_fold"] = autotune.col_fold(x.shape, w.shape,
-                                                         stride)
+                xk, wk, pk = conv2d_kernel_shapes(x.shape, w.shape, stride,
+                                                  padding)
+                sp.attrs["col_fold"] = r.col_fold
                 th = row_block(xk, wk, padding=pk,
-                               has_res=ep.residual is not None,
-                               **_conv2d_tiles(tiles))
+                               has_res=ep.residual is not None)
                 n_r = -(-oh // th)
                 sp.attrs["row_blocks"] = n_r
                 # each block past the first re-reads fh - 1 padded rows
@@ -312,19 +279,17 @@ def conv2d(x, w, *, stride: int = 1, padding: int = 0, impl: str = "auto",
         sp.attrs["flops"] = 2 * b * oh * ow * k * fh * fw * x.shape[-1]
         sp.attrs["bytes_touched"] = halo + _nbytes(x, w, out, ep.scale,
                                                    ep.bias, ep.residual)
-        sp.attrs["tile_util"] = autotune.tile_util_conv2d(
-            x.shape, w.shape, tiles, stride=stride, padding=padding,
+        sp.attrs["tile_util"] = tile_util_conv2d(
+            x.shape, w.shape, stride=stride, padding=padding,
             has_res=ep.residual is not None)
-        _tuning_attrs(sp, entry, tiles)
         _epilogue_attrs(sp, ep, out)
     return out
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("stride", "impl", "relu", "tiles"))
+@functools.partial(jax.jit, static_argnames=("stride", "impl", "relu"))
 def _conv1x1_jit(x, w, scale=None, bias=None, residual=None, *,
-                 relu: bool = False, stride: int = 1, impl: str = "auto",
-                 tiles: TileConfig | None = None):
+                 relu: bool = False, stride: int = 1, impl: str = "auto"):
+    st = route(x.shape, (1, 1, *w.shape), stride).stationarity
     if stride != 1:
         x = x[:, ::stride, ::stride, :]
     b, h, wd, c = x.shape
@@ -332,50 +297,20 @@ def _conv1x1_jit(x, w, scale=None, bias=None, residual=None, *,
     xf = x.reshape(b * h * wd, c)
     rf = residual.reshape(b * h * wd, k) if residual is not None else None
     if _resolve(impl) == "pallas":
-        out = _tiled_matmul(xf, w, scale, bias, relu, rf, tiles)
+        out = _tiled_matmul(xf, w, scale, bias, relu, rf, st)
     else:
         out = _ref.matmul_ref(xf, w, scale=scale, bias=bias, relu=relu,
                               residual=rf).astype(x.dtype)
     return out.reshape(b, h, wd, k)
 
 
-def _tiled_matmul(xf, w, scale, bias, relu, rf,
-                  tiles: TileConfig | None,
-                  stationarity: Stationarity | None = None):
-    """Shared pallas GEMM dispatch: tuned stationarity + tile overrides.
-
-    Precedence for the dataflow: an explicit ``stationarity`` argument, then
-    the tuning cache's measured choice, then the analytic controller rule.
-    """
-    st = stationarity
-    if st is None and tiles is not None and tiles.stationarity:
-        st = Stationarity(tiles.stationarity)
-    if st is None:
-        st = select_stationarity(xf.shape[0])
-    kw = {}
-    if tiles is not None and tiles.bk:
-        kw["bk"] = tiles.bk
-    if st == Stationarity.WEIGHT_STATIONARY:
-        return matmul_weight_stationary(xf, w, scale=scale, bias=bias,
-                                        relu=relu, residual=rf,
-                                        interpret=not _on_tpu(), **kw)
-    if tiles is not None:
-        if tiles.bm:
-            kw["bm"] = tiles.bm
-        if tiles.bc:
-            kw["bc"] = tiles.bc
-    return matmul_act_stationary(xf, w, scale=scale, bias=bias, relu=relu,
-                                 residual=rf, interpret=not _on_tpu(), **kw)
-
-
-def _gemm_stationarity(rows: int, tiles: TileConfig | None,
-                       stationarity: Stationarity | None = None) -> Stationarity:
-    """The dataflow `_tiled_matmul` will pick, for span reporting."""
-    if stationarity is not None:
-        return stationarity
-    if tiles is not None and tiles.stationarity:
-        return Stationarity(tiles.stationarity)
-    return select_stationarity(rows)
+def _tiled_matmul(xf, w, scale, bias, relu, rf, stationarity: Stationarity):
+    """The pallas GEMM in the given stationarity, at the kernel's blocks."""
+    mm = (matmul_weight_stationary
+          if stationarity == Stationarity.WEIGHT_STATIONARY
+          else matmul_act_stationary)
+    return mm(xf, w, scale=scale, bias=bias, relu=relu, residual=rf,
+              interpret=not _on_tpu())
 
 
 def conv1x1(x, w, *, stride: int = 1, impl: str = "auto",
@@ -383,20 +318,18 @@ def conv1x1(x, w, *, stride: int = 1, impl: str = "auto",
     """Pointwise conv via the dual-stationarity GEMM (paper §III.B/C)."""
     ep = epilogue or _NO_EPILOGUE
     impl = _resolve(impl)
-    b, h, wd, c = x.shape
-    rows = b * -(-h // stride) * -(-wd // stride)   # x[:, ::s, ::s] row count
-    entry = _lookup("gemm", (rows, c, w.shape[-1], x.dtype, ep.tag), impl)
-    tiles = entry.config if entry is not None else None
     if not trace.timed(x):
         return _conv1x1_jit(x, w, ep.scale, ep.bias, ep.residual, relu=ep.relu,
-                            stride=stride, impl=impl, tiles=tiles)
-    st = _gemm_stationarity(rows, tiles)
+                            stride=stride, impl=impl)
+    b, h, wd, c = x.shape
+    rows = b * -(-h // stride) * -(-wd // stride)   # x[:, ::s, ::s] row count
+    st = route(x.shape, (1, 1, *w.shape), stride).stationarity
     with trace.span("kernels.conv1x1", impl=impl,
                     x_shape=list(x.shape), w_shape=list(w.shape),
                     stride=stride, stationarity=st.value,
                     dtype=str(x.dtype)) as sp:
         out = _conv1x1_jit(x, w, ep.scale, ep.bias, ep.residual, relu=ep.relu,
-                           stride=stride, impl=impl, tiles=tiles)
+                           stride=stride, impl=impl)
         jax.block_until_ready(out)
         sp.attrs["flops"] = 2 * rows * c * w.shape[-1]
         # A strided 1x1 subsamples BEFORE the GEMM, so only the strided view
@@ -404,34 +337,29 @@ def conv1x1(x, w, *, stride: int = 1, impl: str = "auto",
         sp.attrs["bytes_touched"] = (rows * c * x.dtype.itemsize
                                      + _nbytes(w, out, ep.scale, ep.bias,
                                                ep.residual))
-        sp.attrs["tile_util"] = autotune.tile_util_gemm(
-            rows, c, w.shape[-1], tiles, stationarity=st.value)
+        sp.attrs["tile_util"] = tile_util_gemm(rows, c, w.shape[-1], st)
         if impl == "pallas":
-            sp.attrs["c_blocks"] = _c_blocks(c, w.shape[-1], tiles, st)
-        _tuning_attrs(sp, entry, tiles)
+            sp.attrs["kernel"] = "gemm"
+            sp.attrs["c_blocks"] = _c_blocks(c, w.shape[-1], st)
         _epilogue_attrs(sp, ep, out)
     return out
 
 
-def _c_blocks(c: int, k: int, tiles: TileConfig | None,
-              st: Stationarity) -> int:
+def _c_blocks(c: int, k: int, st: Stationarity) -> int:
     """Blocks of the GEMM's reduction axis over C that the kernel runs."""
     if st == Stationarity.WEIGHT_STATIONARY:
-        bc = ws_blocks(c, k, tiles.bk if tiles is not None else None)[1]
+        bc = ws_blocks(c, k)[1]
     else:
-        bc = min(tiles.bc if tiles is not None and tiles.bc else _GEMM_BC, c)
+        bc = min(_GEMM_BC, c)
     return -(-c // bc)
 
 
-@functools.partial(
-    jax.jit, static_argnames=("impl", "stationarity", "relu", "tiles"))
+@functools.partial(jax.jit, static_argnames=("impl", "stationarity", "relu"))
 def _gemm_jit(x, w, scale=None, bias=None, residual=None, *,
               relu: bool = False, impl: str = "auto",
-              stationarity: Stationarity | None = None,
-              tiles: TileConfig | None = None):
+              stationarity: Stationarity):
     if _resolve(impl) == "pallas":
-        return _tiled_matmul(x, w, scale, bias, relu, residual, tiles,
-                             stationarity)
+        return _tiled_matmul(x, w, scale, bias, relu, residual, stationarity)
     return _ref.matmul_ref(x, w, scale=scale, bias=bias, relu=relu,
                            residual=residual).astype(x.dtype)
 
@@ -439,28 +367,26 @@ def _gemm_jit(x, w, scale=None, bias=None, residual=None, *,
 def gemm(x, w, *, impl: str = "auto",
          stationarity: Stationarity | None = None,
          epilogue: Epilogue | None = None):
-    """(M, C) @ (C, K) with CARLA stationarity planning."""
+    """(M, C) @ (C, K) with CARLA stationarity planning: ``stationarity``,
+    else ``select_stationarity`` of the rows."""
     ep = epilogue or _NO_EPILOGUE
     impl = _resolve(impl)
-    entry = _lookup("gemm", (x.shape[0], x.shape[1], w.shape[-1], x.dtype,
-                             ep.tag), impl)
-    tiles = entry.config if entry is not None else None
+    st = (stationarity if stationarity is not None
+          else select_stationarity(x.shape[0]))
     if not trace.timed(x):
         return _gemm_jit(x, w, ep.scale, ep.bias, ep.residual, relu=ep.relu,
-                         impl=impl, stationarity=stationarity, tiles=tiles)
-    st = _gemm_stationarity(x.shape[0], tiles, stationarity)
+                         impl=impl, stationarity=st)
     with trace.span("kernels.gemm", impl=impl,
                     x_shape=list(x.shape), w_shape=list(w.shape),
                     stationarity=st.value, dtype=str(x.dtype)) as sp:
         out = _gemm_jit(x, w, ep.scale, ep.bias, ep.residual, relu=ep.relu,
-                        impl=impl, stationarity=stationarity, tiles=tiles)
+                        impl=impl, stationarity=st)
         jax.block_until_ready(out)
         sp.attrs["flops"] = 2 * x.shape[0] * x.shape[1] * w.shape[-1]
         sp.attrs["bytes_touched"] = _nbytes(x, w, out, ep.scale, ep.bias,
                                             ep.residual)
-        sp.attrs["tile_util"] = autotune.tile_util_gemm(
-            x.shape[0], x.shape[1], w.shape[-1], tiles, stationarity=st.value)
-        _tuning_attrs(sp, entry, tiles)
+        sp.attrs["tile_util"] = tile_util_gemm(x.shape[0], x.shape[1],
+                                               w.shape[-1], st)
         _epilogue_attrs(sp, ep, out)
     return out
 

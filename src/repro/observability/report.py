@@ -45,11 +45,7 @@ class ReconRow:
     # fused epilogue (``none`` when the dispatch ran without one)
     epilogue: str = "none"
     fused_saved_mb: float = 0.0  # HBM round-trips the fused flush removed
-    # empirical tuning ledger (PR 9): was a tuned tile config applied, what
-    # ran, where it came from, and the padding-waste PUF analogue
-    tuned: bool = False
-    tile_config: str = "default"
-    tuning_source: str = "analytic"
+    # the padding-waste PUF analogue of the kernel that ran
     tile_util: float = 1.0       # logical FLOPs / padded FLOPs
     # structured-sparsity ledger (PR 10): was the layer channel-pruned, what
     # MAC fraction it kept vs its dense twin
@@ -102,9 +98,6 @@ def reconcile(spans: list[Span],
             measured_util=gflops / peak if peak else 0.0,
             epilogue=a.get("epilogue", "none"),
             fused_saved_mb=a.get("epilogue_hbm_saved", 0) / 1e6,
-            tuned=bool(a.get("tuned", False)),
-            tile_config=a.get("tile_config", "default"),
-            tuning_source=a.get("tuning_source", "analytic"),
             tile_util=float(a.get("tile_util", 1.0)),
             pruned=bool(a.get("pruned", False)),
             macs=int(a.get("macs", 0)),
@@ -140,7 +133,7 @@ def format_table(rows: list[ReconRow]) -> str:
     """Fixed-width text table: analytic columns left, measured columns right."""
     headers = ["layer", "dataflow", "cycles", "an.ms", "an.MB", "PUF%",
                "B", "ms", "MB", "GFLOP/s", "util%", "x-ASIC",
-               "epilogue", "savedMB", "tile%", "tiles", "keep%"]
+               "epilogue", "savedMB", "tile%", "keep%"]
     cells = [[
         r.layer, r.dataflow.replace("_", "-"),
         f"{r.analytic_cycles:,}", f"{r.analytic_ms:7.3f}",
@@ -149,7 +142,6 @@ def format_table(rows: list[ReconRow]) -> str:
         f"{r.achieved_gflops:7.2f}", f"{r.measured_util * 100:5.1f}",
         f"{r.speed_ratio:6.2f}", r.epilogue, f"{r.fused_saved_mb:6.2f}",
         f"{r.tile_util * 100:5.1f}",
-        r.tile_config if r.tuned else "default",
         f"{r.keep_fraction * 100:5.1f}" if r.pruned else "dense",
     ] for r in rows]
     widths = [max(len(h), *(len(c[i]) for c in cells)) if cells else len(h)

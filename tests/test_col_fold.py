@@ -1,7 +1,7 @@
 """The column fold of ``kernels.ops``: a unit-stride conv whose C is below 128
 and divides it runs on the conv2d kernel with ``f = 128 // C`` adjacent
-output columns folded into the lanes (``core.autotune.col_fold``, the one
-place that decides f).
+output columns folded into the lanes (``core.route.col_fold``, which
+``core.route.route`` calls).
 
 The Pallas route runs in interpret mode and is compared with ``lax.conv``
 at HIGHEST.  Tolerance: 1e-4 absolute on outputs of order 1-10, as in
@@ -17,7 +17,7 @@ import numpy as np
 import pytest
 from jax import lax
 
-from repro.core import autotune
+from repro.core import route
 from repro.core.fuse import Epilogue
 from repro.kernels import ops
 from repro.observability import trace
@@ -48,7 +48,7 @@ def test_folded_conv_matches_lax_conv(c, k, width, epilogue):
     ks = jax.random.split(key, 5)
     x = jax.random.normal(ks[0], (2, 5, width, c))
     w = jax.random.normal(ks[1], (3, 3, c, k)) / (3 * c ** 0.5)
-    f = autotune.col_fold(x.shape, w.shape, 1)
+    f = route.col_fold(x.shape, w.shape, 1)
     assert f == 128 // c
     ep = {}
     if epilogue != "none":
@@ -78,7 +78,7 @@ def test_folded_conv_matches_lax_conv(c, k, width, epilogue):
     ((1, 14, 14, 24), (3, 3, 24, 16), 1, 1),       # 24 does not divide 128
 ])
 def test_col_fold_decides_from_shapes(x_shape, w_shape, stride, f):
-    assert autotune.col_fold(x_shape, w_shape, stride) == f
+    assert route.col_fold(x_shape, w_shape, stride) == f
 
 
 @pytest.mark.parametrize("c,k,fw", [(64, 64, 3), (32, 32, 3), (64, 128, 3),
@@ -114,7 +114,7 @@ def test_folded_rows_and_ragged_last_block(monkeypatch):
     x = jax.random.normal(key, (1, 23, 20, 64))
     w = jax.random.normal(jax.random.fold_in(key, 1), (3, 3, 64, 64)) / 24
     res = jax.random.normal(jax.random.fold_in(key, 2), (1, 23, 20, 64))
-    xk, wk, pk = autotune.conv2d_kernel_shapes(x.shape, w.shape, 1, 1)
+    xk, wk, pk = route.conv2d_kernel_shapes(x.shape, w.shape, 1, 1)
     th = ops.row_block(xk, wk, padding=pk, has_res=True)
     assert 23 % th and th < 23
     ep = Epilogue(bias=jnp.ones((64,)), residual=res, relu=True)
@@ -126,45 +126,36 @@ def test_folded_rows_and_ragged_last_block(monkeypatch):
     assert float(jnp.max(jnp.abs(got - want))) < 1e-4
 
 
-def test_tiles_tuned_unfolded_never_reach_the_folded_call(monkeypatch):
-    """A folded conv looks its tiles up under the folded conv's key: an
-    entry for the unfolded shape is not used, one for the folded shape is."""
-    monkeypatch.setenv("REPRO_AUTOTUNE_CACHE", "/nonexistent-tuning-cache")
-    monkeypatch.setenv("REPRO_TUNED_TABLES_DIR", "/nonexistent-tuned-tables")
-    autotune.reset()
-    autotune.enable()
-    try:
-        x = jax.random.normal(jax.random.PRNGKey(3), (1, 6, 8, 64))
-        w = jax.random.normal(jax.random.PRNGKey(4), (3, 3, 64, 64)) / 24
-        autotune.put(autotune.conv2d_key(x.shape, w.shape, 1, 1, x.dtype),
-                     autotune.TileConfig(bk=64, bc=64))
-        with trace.capture() as tr:
-            ops.conv2d(x, w, padding=1, impl="pallas")
-        assert tr.spans[0].attrs["tuned"] is False
-        key = autotune.conv2d_tuning_key(x.shape, w.shape, 1, 1, x.dtype)
-        assert key == "conv2d|x1x8x9x128|f3x2x128|s1p0|float32|ep:none"
-        autotune.put(key, autotune.TileConfig(bk=128, bc=128))
-        with trace.capture() as tr:
-            got = ops.conv2d(x, w, padding=1, impl="pallas")
-        assert tr.spans[0].attrs["tile_config"] == "bk128/bc128"
-        assert float(jnp.max(jnp.abs(got - _lax_conv(x, w, 1)))) < 1e-4
-    finally:
-        autotune.disable()
-        autotune.reset()
+def test_tiles_tuned_unfolded_never_reach_the_folded_call():
+    """A folded conv runs the conv2d kernel's tiles on the folded conv, and
+    its span says so: ``col_fold`` 2, and a ``tile_util`` of the folded
+    conv's 128-lane blocks (3x2 column taps for 9 taps of 64 channels:
+    0.375), not the unfolded conv's quarter-filled ones (0.25)."""
+    x = jax.random.normal(jax.random.PRNGKey(3), (1, 6, 8, 64))
+    w = jax.random.normal(jax.random.PRNGKey(4), (3, 3, 64, 64)) / 24
+    xk, wk, pk = route.conv2d_kernel_shapes(x.shape, w.shape, 1, 1)
+    assert (xk, wk, pk) == ((1, 8, 9, 128), (3, 2, 128, 128), 0)
+    with trace.capture() as tr:
+        got = ops.conv2d(x, w, padding=1, impl="pallas")
+    (sp,) = tr.spans
+    assert sp.attrs["kernel"] == "conv2d" and sp.attrs["col_fold"] == 2
+    assert sp.attrs["tile_util"] == pytest.approx(
+        (9 * 64 * 64 * 6 * 8) / (3 * 2 * 128 * 128 * 6 * 8))
+    assert float(jnp.max(jnp.abs(got - _lax_conv(x, w, 1)))) < 1e-4
 
 
 def test_tile_util_counts_lanes_and_the_folds_zero_taps():
     """VGG-16's conv1_2 filled a quarter of the MXU unfolded; folded, a
     quarter of its weights are zero taps and the last row block is one row
     short: about 0.75."""
-    got = autotune.tile_util_conv2d((1, 224, 224, 64), (3, 3, 64, 64),
+    got = route.tile_util_conv2d((1, 224, 224, 64), (3, 3, 64, 64),
                                     padding=1)
-    xk, wk, _ = autotune.conv2d_kernel_shapes((1, 224, 224, 64),
+    xk, wk, _ = route.conv2d_kernel_shapes((1, 224, 224, 64),
                                               (3, 3, 64, 64), 1, 1)
     th = ops.row_block(xk, wk, padding=0)
     assert got == pytest.approx(0.75 * 224 / (-(-224 // th) * th))
     assert 0.74 < got <= 0.75
     # C = 32 folds 4 columns: 5/8 of the folded weights are zero taps
-    assert autotune.tile_util_conv2d((32, 56, 56, 32), (3, 3, 32, 32),
+    assert route.tile_util_conv2d((32, 56, 56, 32), (3, 3, 32, 32),
                                      padding=1) == pytest.approx(
         3 / 8 * 56 / 64)

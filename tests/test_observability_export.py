@@ -1,6 +1,5 @@
 """Export-layer tests: Prometheus exposition, the HTTP exporter, the JSONL
-event log (and its instrumentation sites), and the perf-regression gate."""
-import json
+event log (and its instrumentation sites)."""
 import urllib.request
 
 import jax
@@ -176,102 +175,3 @@ def test_elastic_remesh_emits_event(tmp_path):
     assert rec["old_shape"] == [16, 16]
     assert rec["new_shape"] == [8, 16]
     assert rec["grad_accum_factor"] == 2
-
-
-# ------------------------- perf-regression gate -------------------------------
-def _bench_record():
-    return {
-        "version": 1, "backend": "cpu", "impl": "auto", "batch": 1,
-        "reps": 2, "smoke": True,
-        "networks": {
-            "smoke": {
-                "total_measured_ms": 2.0,
-                "total_analytic_ms": 0.2,
-                "speed_ratio": 10.0,
-                "layers": [
-                    {"layer": "smoke_3x3",
-                     "dataflow": "3x3_serial_accumulation",
-                     "measured_ms": 0.5, "gflops": 1.0,
-                     "util_vs_peak": 0.6, "analytic_ms": 0.02,
-                     "analytic_puf": 0.23},
-                    {"layer": "smoke_1x1_fs",
-                     "dataflow": "1x1_feature_stationary",
-                     "measured_ms": 1.5, "gflops": 0.4,
-                     "util_vs_peak": 0.25, "analytic_ms": 0.02,
-                     "analytic_puf": 0.12},
-                ],
-            },
-        },
-    }
-
-
-def test_check_regression_passes_on_identical_record():
-    from benchmarks.check_regression import compare
-
-    base = _bench_record()
-    assert compare(base, base) == []
-
-
-def test_check_regression_flags_injected_slowdown():
-    from benchmarks.check_regression import compare, inject_slowdown
-
-    base = _bench_record()
-    slow = inject_slowdown(base, 3.0)
-    problems = compare(base, slow)
-    assert problems, "3x slowdown must trip the gate"
-    assert any("smoke_3x3" in p for p in problems)
-    # speedups never fail
-    fast = inject_slowdown(base, 0.5)
-    assert compare(base, fast) == []
-
-
-def test_check_regression_flags_structural_changes():
-    from benchmarks.check_regression import compare
-
-    base = _bench_record()
-    cand = json.loads(json.dumps(base))
-    cand["networks"]["smoke"]["layers"][0]["dataflow"] = "7x7_row_decomposition"
-    del cand["networks"]["smoke"]["layers"][1]
-    problems = compare(base, cand)
-    assert any("dataflow changed" in p for p in problems)
-    assert any("missing layer" in p for p in problems)
-
-
-def test_committed_baseline_is_self_consistent():
-    """The committed BENCH_10.json must pass the gate against itself."""
-    from benchmarks.check_regression import (DEFAULT_BASELINE, check_sparse,
-                                             compare, load)
-
-    base = load(DEFAULT_BASELINE)
-    assert compare(base, base) == []
-    assert check_sparse(base) == []
-    # each net measured unfused and fused, plus the structured-sparse twins
-    # and the smoke sets for tier-1 CI
-    assert set(base["networks"]) == {"smoke", "smoke_fused", "smoke_sparse",
-                                     "resnet50", "resnet50_fused",
-                                     "resnet50_sparse",
-                                     "vgg16", "vgg16_fused"}
-    assert len(base["networks"]["resnet50"]["layers"]) == 49
-    assert len(base["networks"]["resnet50_sparse"]["layers"]) == 49
-    assert len(base["networks"]["vgg16"]["layers"]) == 13
-    for name, net in base["networks"].items():
-        fused = name.endswith("_fused")
-        for layer in net["layers"]:
-            assert layer["measured_ms"] > 0
-            assert layer["gflops"] > 0
-            assert 0 < layer["util_vs_peak"] <= 1
-            assert (layer["epilogue"] != "none") == fused
-        assert (net["total_fused_saved_mb"] > 0) == fused
-    # the fused-path invariant holds in the committed record itself
-    assert set(base["fused_delta"]) == {"smoke", "resnet50", "vgg16"}
-    for fd in base["fused_delta"].values():
-        for blk in fd["blocks"]:
-            assert blk["fused_bytes_mb"] < blk["unfused_bytes_mb"]
-    # ...and so does the sparse invariant: every pruned layer of the sparse
-    # twins touches strictly fewer bytes than its dense counterpart
-    assert set(base["sparse_delta"]) == {"smoke", "resnet50"}
-    assert base["sparse_delta"]["resnet50"]["pruned_layers"] == 48
-    for sd in base["sparse_delta"].values():
-        for entry in sd["layers"]:
-            if entry["pruned"]:
-                assert entry["sparse_bytes_mb"] < entry["dense_bytes_mb"]

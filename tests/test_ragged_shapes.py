@@ -1,25 +1,21 @@
 """Ragged-shape parity: dims that are NOT tile multiples, across everything.
 
-The tuner's candidate pool includes tiles that leave remainders on every axis
-(M, C, K), so the padding/clamping paths in ``conv2d.py``/``matmul.py`` must
-be exact for arbitrary (dim, tile) combinations — not just the MXU-aligned
-shapes the defaults were written for.  This sweeps prime-ish dims through all
-four dataflows x {pallas, ref} x {unfused, fused epilogue}, both by calling
-the kernels with explicitly odd tiles and by dispatching through
-``carla_conv`` with odd tiles injected via the tuning cache.
+The kernels take explicit tiles that leave remainders on every axis (M, C,
+K), so the padding/clamping paths in ``conv2d.py``/``matmul.py`` must be
+exact for arbitrary (dim, tile) combinations — not just the MXU-aligned
+shapes of the kernels' own blocks.  This sweeps prime-ish dims through the
+kernels with explicitly odd tiles, and through all four dataflows x
+{pallas, ref} x {unfused, fused epilogue} by dispatching through
+``carla_conv`` on the route each shape takes.
 """
 import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.core import Epilogue, autotune, carla_conv
-from repro.core.autotune import (
-    TileConfig,
-    conv2d_gemm_shape,
-    conv2d_key,
-    gemm_key,
-)
+from repro.core import Epilogue, carla_conv
+from repro.core.route import route
 from repro.kernels import (
+    conv2d,
     matmul_act_stationary,
     matmul_weight_stationary,
     ops,
@@ -47,19 +43,6 @@ def _epilogue(k, out_shape, key):
         residual=jax.random.normal(jax.random.fold_in(key, 2), out_shape))
 
 
-@pytest.fixture
-def iso_cache(tmp_path, monkeypatch):
-    """Tuning cache isolated from the repo's committed tables and enabled."""
-    monkeypatch.setenv("REPRO_TUNED_TABLES_DIR", str(tmp_path / "t"))
-    monkeypatch.setenv("REPRO_AUTOTUNE_CACHE", str(tmp_path / "c"))
-    was = autotune.enabled()
-    autotune.reset()
-    autotune.enable()
-    yield
-    autotune.reset()
-    (autotune.enable if was else autotune.disable)()
-
-
 # --------------------- direct kernel calls, odd tiles -------------------------
 # C=37, K=53 are prime (never tile multiples); tiles 5/7/11 leave remainders
 # on every axis.
@@ -84,12 +67,14 @@ def test_conv2d_kernel_ragged_tiles(h, c, k, fl, s, p, bk, bc, fused):
         ep = _epilogue(k, (1, oh, oh, k), jax.random.fold_in(key, 2))
         kw = dict(scale=ep.scale, bias=ep.bias, relu=True,
                   residual=ep.residual)
-    # through the pallas dispatch with explicit tiles: unit stride runs the
-    # conv2d kernel, stride 2 the im2col GEMM (bc tiles its reduction)
-    got = ops._conv2d_jit(x, w, kw.get("scale"), kw.get("bias"),
-                          kw.get("residual"), relu=bool(kw.get("relu")),
-                          stride=s, padding=p, impl="pallas",
-                          tiles=TileConfig(bk=bk, bc=bc))
+    if s == 1:
+        # the conv2d kernel itself, with the odd tiles
+        got = conv2d(x, w, padding=p, bk=bk, bc=bc, interpret=True, **kw)
+    else:
+        # a strided conv runs as the im2col GEMM, at the kernel's blocks
+        got = ops._conv2d_jit(x, w, kw.get("scale"), kw.get("bias"),
+                              kw.get("residual"), relu=bool(kw.get("relu")),
+                              stride=s, padding=p, impl="pallas")
     want = ref.conv2d_ref(x, w, stride=s, padding=p, **kw)
     assert got.shape == want.shape
     assert _err(got, want) < 1e-3, (h, c, k, fl, s, bk, bc, fused)
@@ -122,29 +107,27 @@ def test_matmul_ragged_tiles_both_stationarities(m, c, k, bm, bk, bc, fused):
     assert _err(got_ws, want) < 1e-3, ("ws", m, c, k, bk, fused)
 
 
-# ----------------- full dispatch with injected odd tiles ----------------------
-# One case per paper dataflow; the cache entry forces ragged tiles (and, for
-# the 1x1s, swaps the stationarity away from the analytic rule).
+# ------------------------ full dispatch, every route -------------------------
+# One case per paper dataflow, on the route its shape takes: the conv2d
+# kernel, the im2col GEMM, and the 1x1 GEMM in both stationarities (81 rows
+# are below the 128 of one MXU tile, 169 above).
 DATAFLOW_RAGGED = [
-    ("3x3", dict(h=9, c=37, k=53, fl=3, s=1, p=1),
-     TileConfig(bk=7, bc=5)),
-    ("7x7", dict(h=15, c=3, k=21, fl=7, s=2, p=3),
-     TileConfig(bk=4, bc=2)),
-    # 1x1 feature-stationary shape (M=81 < 128 rule says WS; force AS)
-    ("1x1_as", dict(h=9, c=37, k=53, fl=1, s=1, p=0),
-     TileConfig(bm=13, bk=7, bc=11, stationarity="activation_stationary")),
-    # 1x1 weight-stationary override at large M (the empirical flip)
-    ("1x1_ws", dict(h=13, c=37, k=53, fl=1, s=1, p=0),
-     TileConfig(bk=7, stationarity="weight_stationary")),
+    ("3x3", dict(h=9, c=37, k=53, fl=3, s=1, p=1), "conv2d", None),
+    ("7x7", dict(h=15, c=3, k=21, fl=7, s=2, p=3), "im2col_gemm",
+     "weight_stationary"),     # 8x8 outputs: 64 rows
+    ("1x1_as", dict(h=13, c=37, k=53, fl=1, s=1, p=0), "gemm",
+     "activation_stationary"),
+    ("1x1_ws", dict(h=9, c=37, k=53, fl=1, s=1, p=0), "gemm",
+     "weight_stationary"),
 ]
 
 
-@pytest.mark.parametrize("tag,case,tiles",
+@pytest.mark.parametrize("tag,case,kernel,stationarity",
                          DATAFLOW_RAGGED, ids=[t[0] for t in DATAFLOW_RAGGED])
 @pytest.mark.parametrize("impl", ["pallas", "ref"])
 @pytest.mark.parametrize("fused", [False, True])
-def test_carla_conv_ragged_tuned_parity(tag, case, tiles, impl, fused,
-                                        iso_cache):
+def test_carla_conv_ragged_parity(tag, case, kernel, stationarity, impl,
+                                  fused):
     h, c, k = case["h"], case["c"], case["k"]
     fl, s, p = case["fl"], case["s"], case["p"]
     key = jax.random.PRNGKey(sum(map(ord, tag)))
@@ -157,17 +140,9 @@ def test_carla_conv_ragged_tuned_parity(tag, case, tiles, impl, fused,
         ep = _epilogue(k, (1, oh, oh, k), jax.random.fold_in(key, 2))
         kw = dict(scale=ep.scale, bias=ep.bias, relu=True,
                   residual=ep.residual)
-    # inject the ragged entry for BOTH the fused and unfused key (the fused
-    # lookup would fall back to ep:none anyway; make the exact hit explicit)
-    tag_ep = ep.tag if ep is not None else "none"
-    if fl == 1:
-        cache_key = gemm_key(h * h, c, k, x.dtype, tag_ep)
-    elif s > 1:   # runs as an im2col GEMM, keyed by that GEMM's shape
-        cache_key = gemm_key(*conv2d_gemm_shape(x.shape, w.shape, s, p),
-                             x.dtype, tag_ep)
-    else:
-        cache_key = conv2d_key(x.shape, w.shape, s, p, x.dtype, tag_ep)
-    autotune.put(cache_key, tiles)
+    r = route(x.shape, w.shape, s, p)
+    assert r.kernel == kernel
+    assert (r.stationarity and r.stationarity.value) == stationarity
 
     got = carla_conv(x, w, stride=s, padding=p, impl=impl, epilogue=ep)
     want = ref.conv2d_ref(x, w, stride=s, padding=p, **kw)

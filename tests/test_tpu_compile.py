@@ -16,10 +16,9 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.core.autotune import TileConfig
 from repro.core.carla import carla_conv
 from repro.core.fuse import Epilogue
-from repro.kernels import ops
+from repro.kernels import matmul_act_stationary, ops
 
 KERNEL_CALL = 'custom_call_target="tpu_custom_call"'
 
@@ -60,8 +59,7 @@ CASES = {
     # conv5 1x1 at M=49 rows: weight-stationary
     "1x1_conv5_ws": ((1, 7, 7, 2048), (1, 1, 2048, 512), 1, 0, False),
     "1x1_c256_b8": ((8, 56, 56, 256), (1, 1, 256, 64), 1, 0, False),
-    # the stem GEMM under a tuned entry that splits its 192 patch columns
-    # into two 128-lane blocks
+    # the stem GEMM with its 192 patch columns in two 128-lane blocks
     "stem_7x7s2_bc128": ((1, 224, 224, 3), (7, 7, 3, 64), 2, 3, False),
     # VGG-16's conv1_2 and conv2_1: the conv2d kernel in row blocks
     "vgg_conv1_2": ((1, 224, 224, 64), (3, 3, 64, 64), 1, 1, False),
@@ -78,32 +76,36 @@ CASES = {
     "3x3_56x56x32_b32_pruned": ((32, 56, 56, 32), (3, 3, 32, 32), 1, 1, False),
     "3x3_28x28x64_b32_pruned": ((32, 28, 28, 64), (3, 3, 64, 64), 1, 1, False),
 }
-TILES = {
-    "stem_7x7s2_bc128": TileConfig(bm=256, bk=64, bc=128,
-                                   stationarity="activation_stationary"),
-}
+# cases that call a kernel with blocks of their own, not the route's
+BLOCKS = {"stem_7x7s2_bc128": dict(bm=256, bk=64, bc=128)}
 
 
 @pytest.mark.parametrize("name", list(CASES))
 def test_kernel_compiles_for_v5e(name, one_chip):
     xs, ws, stride, padding, has_res = CASES[name]
-    tiles = TILES.get(name)
     k = ws[-1]
     oh = (xs[1] - ws[0] + 2 * padding) // stride + 1
 
     def spec(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
 
-    if ws[0] == 1:
+    if name in BLOCKS:
+        def fwd(x, w, sc, bi, res):
+            p, wf = ops._im2col(x, w, stride, padding)
+            b, oh, ow, kk = p.shape
+            out = matmul_act_stationary(p.reshape(b * oh * ow, kk), wf,
+                                        scale=sc, bias=bi, relu=True,
+                                        interpret=False, **BLOCKS[name])
+            return out.reshape(b, oh, ow, k)
+    elif ws[0] == 1:
         def fwd(x, w, sc, bi, res):
             return ops._conv1x1_jit(x, w[0, 0], sc, bi, res, relu=True,
-                                    stride=stride, impl="pallas",
-                                    tiles=tiles)
+                                    stride=stride, impl="pallas")
     else:
         def fwd(x, w, sc, bi, res):
             return ops._conv2d_jit(x, w, sc, bi, res, relu=True,
                                    stride=stride, padding=padding,
-                                   impl="pallas", tiles=tiles)
+                                   impl="pallas")
 
     res = spec(xs[0], oh, oh, k) if has_res else None
     compiled = jax.jit(fwd).lower(spec(*xs), spec(*ws), spec(k), spec(k),
@@ -199,7 +201,7 @@ def test_resnet50_keeps_one_row_block_and_one_c_block():
     C = 32: conv2 pruned) or not, and every weight-stationary 1x1 (conv5's,
     49 rows at batch 1) one C block: the program the ResNet-50 cells ran
     before row and C blocks existed."""
-    from repro.core import autotune
+    from repro.core import route
     from repro.core.modes import Stationarity, select_stationarity
     from repro.core.networks import (
         resnet50_conv_layers,
@@ -215,7 +217,7 @@ def test_resnet50_keeps_one_row_block_and_one_c_block():
             for batch in (1, 32):
                 if l.FL == 3:
                     xs, w_s = (batch, l.IL, l.IL, l.IC), (3, 3, l.IC, l.K)
-                    xk, wk, pk = autotune.conv2d_kernel_shapes(xs, w_s, 1, 1)
+                    xk, wk, pk = route.conv2d_kernel_shapes(xs, w_s, 1, 1)
                     assert row_block(xk, wk, padding=pk) == l.IL, l
                     folded[sparse, l.IC] += xk != xs
                 rows = batch * (l.IL // l.S) ** 2

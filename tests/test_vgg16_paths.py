@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.core import autotune
+from repro.core import route
 from repro.core.fuse import Epilogue
 from repro.kernels import matmul_weight_stationary, ops, ref
 from repro.kernels.conv2d import conv2d, row_block
@@ -77,8 +77,7 @@ def test_row_blocks_take_the_whole_channel_axis(monkeypatch):
                    jax.random.fold_in(key, 2))
     got = conv2d(x, w, padding=1, bc=8, interpret=True, **ep)
     assert _err(got, ref.conv2d_ref(x, w, padding=1, **ep)) < 1e-4
-    tiles = autotune.TileConfig(bk=6, bc=8)
-    assert autotune.tile_util_conv2d(x.shape, w.shape, tiles, padding=1) \
+    assert route.tile_util_conv2d(x.shape, w.shape, padding=1) \
         == pytest.approx(20 * 6 / (128 * 128) * 20 / 24)  # 4 padded rows
 
 
@@ -101,7 +100,7 @@ def test_row_block_span_counts_the_halo_bytes(monkeypatch):
                         2**20)
     x = jax.random.normal(jax.random.PRNGKey(4), (1, 40, 40, 32))
     w = jax.random.normal(jax.random.PRNGKey(5), (3, 3, 32, 16))
-    xk, wk, pk = autotune.conv2d_kernel_shapes(x.shape, w.shape, 1, 1)
+    xk, wk, pk = route.conv2d_kernel_shapes(x.shape, w.shape, 1, 1)
     assert xk[2:] == (17, 128) and pk == 0
     th = row_block(xk, wk, padding=pk)
     n_r = -(-40 // th)
@@ -136,7 +135,7 @@ def test_c_blocks_stream_whole_rows_only_where_c_does_not_fit():
     assert ws_blocks(2048, 512) == (128, 2048)     # ResNet-50 conv5: one block
     assert ws_blocks(25088, 4096) == (4096, 256)   # fc6: 98 blocks of 4 MiB
     assert ws_blocks(4096, 1000) == (1000, 1024)   # fc8: no padded copy of K
-    assert ws_blocks(300, 40, bk=8) == (8, 300)    # a tuned bk is kept
+    assert ws_blocks(300, 40, bk=8) == (8, 300)    # a given bk is kept
 
 
 def test_conv1x1_span_records_c_blocks(monkeypatch):
@@ -158,9 +157,9 @@ def test_three_channel_3x3_runs_as_an_im2col_gemm():
     """VGG-16's conv1_1: 3x3 taps of 3 channels are 27 patch columns, one
     lane tile, so the unit-stride conv runs as a GEMM; 16 channels (144
     columns) stay on the conv2d kernel."""
-    assert autotune.runs_as_gemm((3, 3, 3, 64), 1)
-    assert not autotune.runs_as_gemm((3, 3, 16, 64), 1)
-    assert not autotune.runs_as_gemm((1, 1, 3, 64), 1)
+    assert route.runs_as_gemm((3, 3, 3, 64), 1)
+    assert not route.runs_as_gemm((3, 3, 16, 64), 1)
+    assert not route.runs_as_gemm((1, 1, 3, 64), 1)
     key = jax.random.PRNGKey(10)
     x = jax.random.normal(key, (2, 20, 24, 3))
     w = jax.random.normal(jax.random.fold_in(key, 1), (3, 3, 3, 8))
@@ -169,7 +168,7 @@ def test_three_channel_3x3_runs_as_an_im2col_gemm():
         got = ops.conv2d(x, w, padding=1, impl="pallas", epilogue=ep)
     (sp,) = tr.spans
     assert sp.attrs["kernel"] == "im2col_gemm" and "row_blocks" not in sp.attrs
-    assert autotune.conv2d_gemm_shape(x.shape, w.shape, 1, 1) == (960, 27, 8)
+    assert route.conv2d_gemm_shape(x.shape, w.shape, 1, 1) == (960, 27, 8)
     want = ref.conv2d_ref(x, w, padding=1, bias=ep.bias, relu=True)
     assert _err(got, want) < 1e-4
 
@@ -193,34 +192,3 @@ def test_vgg16_pallas_forward_matches_the_benchmark_reference(seed):
         want = cell.model.reference(cfg, params, x)
     assert got.shape == want.shape == (2, 1000)
     assert _err(got, want) / float(jnp.max(jnp.abs(want))) < 2e-6
-
-
-def test_the_tuner_keys_a_conv_by_the_kernel_that_runs_it():
-    """The tuner keys VGG-16's conv1_1 and the smoke set's small 3x3s by
-    their im2col GEMM, and conv1_2 and conv2_1 (64 channels) by the folded
-    conv, as the dispatch looks them up, and no committed table holds an
-    entry that no dispatch can hit."""
-    import json
-
-    sys.path.insert(0, ROOT)
-    from benchmarks import autotune as tuner
-    from repro.core.networks import smoke_conv_layers, vgg16_conv_layers
-    keys = [tuner._layer_key(layer, 1) for layer in vgg16_conv_layers()]
-    assert keys[0] == "gemm|m50176|c27|k64|float32|ep:none"
-    assert all(k.startswith("conv2d|") for k in keys[1:])
-    assert keys[1] == "conv2d|x1x226x113x128|f3x2x128|s1p0|float32|ep:none"
-    assert keys[2] == "conv2d|x1x114x57x128|f3x2x256|s1p0|float32|ep:none"
-    assert tuner._layer_key(smoke_conv_layers()[0], 1).startswith("gemm|m196|c72|")
-    tables = os.path.join(ROOT, "src", "repro", "kernels", "tuned")
-    for name in sorted(os.listdir(tables)):
-        with open(os.path.join(tables, name)) as f:
-            for key in json.load(f)["entries"]:
-                if key.startswith("conv2d|"):
-                    fh, fw, k = map(int, key.split("|")[2][1:].split("x"))
-                    x_shape = tuple(map(int, key.split("|")[1][1:].split("x")))
-                    stride = int(key.split("|")[3][1:].split("p")[0])
-                    w_shape = (fh, fw, x_shape[3], k)
-                    assert not autotune.runs_as_gemm(w_shape, stride), \
-                        (name, key)
-                    assert autotune.col_fold(x_shape, w_shape, stride) == 1, \
-                        (name, key)
