@@ -37,8 +37,8 @@ _NO_EPILOGUE = Epilogue()
 
 # What the kernel span measures of the dispatch it ran; the carla_conv span
 # copies them from its child rather than computing them a second time.
-_MEASURED = ("kernel", "stationarity", "col_fold", "bytes_touched",
-             "tile_util", "epilogue_hbm_saved")
+_MEASURED = ("kernel", "stationarity", "col_fold", "images_per_step",
+             "bytes_touched", "tile_util", "epilogue_hbm_saved")
 
 
 @dataclass(frozen=True)
@@ -98,8 +98,8 @@ def carla_conv(x: jnp.ndarray, w: jnp.ndarray, *, stride: int = 1,
     (cycles / DRAM bytes / PUF), the epilogue combination that was fused
     (``epilogue=`` attr), and what its one ``kernels.*`` child span measured
     of the kernel that ran: ``kernel``, ``stationarity``, ``col_fold``,
-    ``bytes_touched``, ``tile_util`` and ``epilogue_hbm_saved``.  A dispatch
-    traced inside a jit opens no span.
+    ``images_per_step``, ``bytes_touched``, ``tile_util`` and
+    ``epilogue_hbm_saved``.  A dispatch traced inside a jit opens no span.
     """
     if w.ndim == 2:
         w = w[None, None]

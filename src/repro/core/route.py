@@ -140,7 +140,7 @@ def tile_util_conv2d(x_shape, w_shape, *, stride: int = 1, padding: int = 0,
     oh = x_shape[1] - fh + 2 * padding + 1
     ow = x_shape[2] - fw + 2 * padding + 1
     xk, wk, pk = conv2d_kernel_shapes(x_shape, w_shape, stride, padding)
-    th, bk, bc = step_tiles(xk, wk, padding=pk, has_res=has_res)
+    _, th, bk, bc = step_tiles(xk, wk, padding=pk, has_res=has_res)
     owk = xk[2] - wk[1] + 2 * pk + 1
     mxu = (wk[0] * wk[1] * -(-wk[2] // bc) * _ceil_to(bc, LANES)
            * -(-wk[3] // bk) * _ceil_to(bk, LANES) * _ceil_to(oh, th) * owk)

@@ -37,6 +37,15 @@ The paper's §III.A dataflow, transplanted to the TPU memory hierarchy:
   in one block, VGG-16's 224x224 and 112x112 layers take several (the
   image decomposition of arXiv 1709.05116), and each block past the first
   re-reads ``FH - 1`` rows of the block above it.
+* **Several images a step, where one gives the MXU too few rows**: a grid
+  step over the whole plane takes ``nb`` images (:func:`step_tiles`), so
+  each tap is one dot of ``nb * th * OW`` rows against the weight tile, and
+  each weight tile is fetched once per block of ``nb`` images rather than
+  once per image.  One 14x14 plane gives 196 rows and one 7x7 plane 49,
+  against the :data:`ROWS_PER_DOT` that the rule aims for; ``nb`` is the
+  fewest images, a divisor of the batch, whose step reaches that, within
+  :data:`VMEM_BUDGET`.  At batch 1 and wherever the plane is in row blocks
+  ``nb`` is 1 and the kernel is the one-image kernel.
 * **Paired-SRAM overlap**: Pallas grid pipelining double-buffers the streamed
   weight tiles while compute proceeds.
 * **Fused flush epilogue**: on the last reduction step the kernel can apply a
@@ -84,6 +93,10 @@ BC = 128   # input-channel tile
 # room below that.
 VMEM_BUDGET = 14 * 2**20
 
+# Rows of each tap's dot that a grid step aims for (:func:`step_tiles`):
+# a plane that gives fewer takes several images a step.
+ROWS_PER_DOT = 512
+
 
 def _sublanes(n: int) -> int:
     return -(-n // 8) * 8
@@ -91,6 +104,20 @@ def _sublanes(n: int) -> int:
 
 def _lanes(n: int) -> int:
     return -(-n // 128) * 128
+
+
+def _step_vmem(x_shape, w_shape, *, padding: int, bk: int, bc: int,
+               has_res: bool) -> tuple[int, int, int]:
+    """VMEM bytes of a grid step, as :data:`VMEM_BUDGET` counts them: the
+    weight tile's, and one image's per padded input row and per output
+    row."""
+    wd = x_shape[2]
+    fh, fw = w_shape[:2]
+    ow = wd - fw + 2 * padding + 1
+    weights = 2 * fh * fw * _sublanes(bc) * _lanes(bk) * 4
+    in_row = 2 * _sublanes(wd + 2 * padding) * _lanes(bc) * 4
+    out_row = (1 + 2 + 2 * has_res) * _sublanes(ow) * bk * 4
+    return weights, in_row, out_row
 
 
 def row_block(x_shape, w_shape, *, padding: int, bk: int = BK, bc: int = BC,
@@ -104,15 +131,15 @@ def row_block(x_shape, w_shape, *, padding: int, bk: int = BK, bc: int = BC,
     VMEM for element-offset rows read in several channel blocks than for
     one: at 112x112, C = 256 and 64 rows, 26.2 MB in two blocks of 128
     against under 16 MiB in one of 256."""
-    _, h, wd, cin = x_shape
-    fh, fw, _, k = w_shape
-    oh, ow = h - fh + 2 * padding + 1, wd - fw + 2 * padding + 1
+    cin = x_shape[3]
+    fh, _, _, k = w_shape
+    oh = x_shape[1] - fh + 2 * padding + 1
     bk = min(bk, k)
 
     def rows(bc: int) -> int:
-        weights = 2 * fh * fw * _sublanes(bc) * _lanes(bk) * 4
-        in_row = 2 * _sublanes(wd + 2 * padding) * _lanes(bc) * 4
-        out_row = (1 + 2 + 2 * has_res) * _sublanes(ow) * bk * 4
+        weights, in_row, out_row = _step_vmem(x_shape, w_shape,
+                                              padding=padding, bk=bk, bc=bc,
+                                              has_res=has_res)
         return (VMEM_BUDGET - weights - (fh - 1) * in_row) // (in_row + out_row)
 
     if rows(min(bc, cin)) >= oh:
@@ -122,29 +149,52 @@ def row_block(x_shape, w_shape, *, padding: int, bk: int = BK, bc: int = BC,
 
 
 def step_tiles(x_shape, w_shape, *, padding: int, bk: int = BK, bc: int = BC,
-               has_res: bool = False) -> tuple[int, int, int]:
-    """``(th, bk, bc)`` of a grid step: :func:`row_block`'s rows and the
-    channel tiles clamped to the layer, the whole C when the plane is in
-    row blocks.  The one place that decides them."""
-    cin, k = x_shape[3], w_shape[3]
-    oh = x_shape[1] - w_shape[0] + 2 * padding + 1
+               has_res: bool = False) -> tuple[int, int, int, int]:
+    """``(nb, th, bk, bc)`` of a grid step: :func:`row_block`'s rows, the
+    channel tiles clamped to the layer (the whole C when the plane is in
+    row blocks), and ``nb`` images.  The one place that decides them.
+
+    ``nb`` is 1 unless the whole plane is one row block and one image gives
+    fewer than :data:`ROWS_PER_DOT` rows (``th * OW``); then it is the
+    fewest images, a divisor of the batch, that reach them, or the most
+    whose step fits :data:`VMEM_BUDGET` if none does."""
+    b, _, wd, cin = x_shape
+    fh, fw, _, k = w_shape
+    oh = x_shape[1] - fh + 2 * padding + 1
+    ow = wd - fw + 2 * padding + 1
     th = row_block(x_shape, w_shape, padding=padding, bk=bk, bc=bc,
                    has_res=has_res)
-    return th, min(bk, k), cin if th < oh else min(bc, cin)
+    bk, bc = min(bk, k), cin if th < oh else min(bc, cin)
+    nb = 1
+    if th == oh and th * ow < ROWS_PER_DOT:
+        weights, in_row, out_row = _step_vmem(x_shape, w_shape,
+                                              padding=padding, bk=bk, bc=bc,
+                                              has_res=has_res)
+        per_image = (th + fh - 1) * in_row + th * out_row
+        for n in range(2, b + 1):
+            if b % n:
+                continue
+            if weights + n * per_image > VMEM_BUDGET:
+                break
+            nb = n
+            if n * th * ow >= ROWS_PER_DOT:
+                break
+    return nb, th, bk, bc
 
 
 def _conv2d_kernel(*refs, fh: int, fw: int, n_c: int, c_axis: int,
                    has_sb: bool, has_res: bool, relu: bool):
-    """grid = (B, OH/th, K/bk, C/bc), or (B, K/bk, C/bc) with one row block;
-    c innermost (reduction axis).
+    """grid = (B/nb, K/bk, C/bc) with one row block, or (B, OH/th, K/bk,
+    C/bc) in row blocks (``nb`` = 1 there); c innermost (reduction axis).
 
     refs = (x_ref, w_ref, [sb_ref], [res_ref], o_ref, acc_ref):
-      x_ref:   (1, th + fh - 1, WP, bc) padded input rows of the block and
-               its halo (VMEM-resident across taps)
-      w_ref:   (fh, fw, bc, bk) weight tile (streamed)
+      x_ref:   (nb, th + fh - 1, WP, bc) padded input rows of the block and
+               its halo, for nb images (VMEM-resident across taps)
+      w_ref:   (fh, fw, bc, bk) weight tile (streamed, once per nb images)
       sb_ref:  (2, bk) fp32 — row 0 scale, row 1 bias (when has_sb)
-      res_ref: (1, th, OW, bk) residual block (when has_res)
-      o_ref:   (1, th, OW, bk); acc_ref: fp32 (th, OW, bk) scratch.
+      res_ref: (nb, th, OW, bk) residual block (when has_res)
+      o_ref:   (nb, th, OW, bk); acc_ref: fp32 (nb*th, OW, bk) scratch, the
+               images' rows stacked.
     """
     it = iter(refs)
     x_ref, w_ref = next(it), next(it)
@@ -158,13 +208,20 @@ def _conv2d_kernel(*refs, fh: int, fw: int, n_c: int, c_axis: int,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    th, ow, bk = acc_ref.shape
+    nb = x_ref.shape[0]
+    rows, ow, bk = acc_ref.shape
+    th = rows // nb
     # Serial accumulation: filter rows outer (the CU chain), columns inner.
+    # Each tap is one dot over the rows of all nb images; at nb = 1 the refs
+    # are read as the one-image kernel read them, so its program is the same.
     for r in range(fh):
         for s in range(fw):
-            window = x_ref[0, r:r + th, s:s + ow, :]          # (th, OW, bc)
-            acc_ref[...] += mxu_dot(window.reshape(th * ow, -1),
-                                    w_ref[r, s]).reshape(th, ow, bk)
+            if nb == 1:
+                window = x_ref[0, r:r + th, s:s + ow, :]      # (th, OW, bc)
+            else:
+                window = x_ref[:, r:r + th, s:s + ow, :].reshape(rows, ow, -1)
+            acc_ref[...] += mxu_dot(window.reshape(rows * ow, -1),
+                                    w_ref[r, s]).reshape(rows, ow, bk)
 
     @pl.when(c == n_c - 1)
     def _flush():
@@ -173,10 +230,14 @@ def _conv2d_kernel(*refs, fh: int, fw: int, n_c: int, c_axis: int,
         if has_sb:
             y = y * sb_ref[0][None, None, :] + sb_ref[1][None, None, :]
         if has_res:
-            y = y + res_ref[0].astype(jnp.float32)
+            res = res_ref[0] if nb == 1 else res_ref[...].reshape(rows, ow, bk)
+            y = y + res.astype(jnp.float32)
         if relu:
             y = jnp.maximum(y, 0.0)
-        o_ref[0] = y.astype(o_ref.dtype)
+        if nb == 1:
+            o_ref[0] = y.astype(o_ref.dtype)
+        else:
+            o_ref[...] = y.reshape(nb, th, ow, bk).astype(o_ref.dtype)
 
 
 def _pack_scale_bias(scale, bias, k: int, kpad: int):
@@ -196,8 +257,9 @@ def conv2d(x: jnp.ndarray, w: jnp.ndarray, *, padding: int = 0,
 
     scale/bias ((K,)), residual ((B, OH, OW, K)) and relu are fused into the
     flush step — see the module docstring's fused-flush design note.  Each
-    grid step computes :func:`step_tiles`' ``th`` output rows; a last block
-    that runs past the plane is computed on zero rows and cut.
+    grid step computes :func:`step_tiles`' ``th`` output rows of ``nb``
+    images; a last block that runs past the plane is computed on zero rows
+    and cut.
     """
     b, h, wd, cin = x.shape
     fh, fw, cin2, k = w.shape
@@ -206,8 +268,8 @@ def conv2d(x: jnp.ndarray, w: jnp.ndarray, *, padding: int = 0,
     ow = wd - fw + 2 * padding + 1
     has_sb = scale is not None or bias is not None
     has_res = residual is not None
-    th, bk, bc = step_tiles(x.shape, w.shape, padding=padding, bk=bk, bc=bc,
-                            has_res=has_res)
+    nb, th, bk, bc = step_tiles(x.shape, w.shape, padding=padding, bk=bk,
+                                bc=bc, has_res=has_res)
     n_r = -(-oh // th)
     ohp = n_r * th          # rows computed, the last block's padding included
     # Pad: spatial zero-pads (once, in HBM; the bottom also covers the last
@@ -229,8 +291,8 @@ def conv2d(x: jnp.ndarray, w: jnp.ndarray, *, padding: int = 0,
         return pl.BlockSpec(block, index)
 
     if n_r == 1:
-        # the whole plane: one input block per (b, c) visit
-        x_spec = spec((1, hp, wp_, bc), lambda i, r, j, l: (i, 0, 0, l))
+        # the whole plane of nb images: one input block per (image block, c)
+        x_spec = spec((nb, hp, wp_, bc), lambda i, r, j, l: (i, 0, 0, l))
     else:
         # rows [r*th, r*th + th + fh - 1): the block's own and its halo,
         # addressed by element offset since consecutive windows overlap
@@ -251,18 +313,18 @@ def conv2d(x: jnp.ndarray, w: jnp.ndarray, *, padding: int = 0,
         assert residual.shape == (b, oh, ow, k), (residual.shape, (b, oh, ow, k))
         operands.append(jnp.pad(residual, ((0, 0), (0, ohp - oh), (0, 0),
                                            (0, kpad))))
-        in_specs.append(spec((1, th, ow, bk), lambda i, r, j, l: (i, r, 0, j)))
+        in_specs.append(spec((nb, th, ow, bk), lambda i, r, j, l: (i, r, 0, j)))
 
-    grid = (b, n_r, n_k, n_c) if n_r > 1 else (b, n_k, n_c)
+    grid = (b, n_r, n_k, n_c) if n_r > 1 else (b // nb, n_k, n_c)
     out = pl.pallas_call(
         functools.partial(_conv2d_kernel, fh=fh, fw=fw, n_c=n_c,
                           c_axis=len(grid) - 1,
                           has_sb=has_sb, has_res=has_res, relu=relu),
         grid=grid,
         in_specs=in_specs,
-        out_specs=spec((1, th, ow, bk), lambda i, r, j, l: (i, r, 0, j)),
+        out_specs=spec((nb, th, ow, bk), lambda i, r, j, l: (i, r, 0, j)),
         out_shape=jax.ShapeDtypeStruct((b, ohp, ow, k + kpad), x.dtype),
-        scratch_shapes=[pltpu.VMEM((th, ow, bk), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((nb * th, ow, bk), jnp.float32)],
         # the name is a contract: a profiler trace and the compiled text
         # name the kernel by it, and the benchmark's reduction matches it
         name="_conv2d_kernel",

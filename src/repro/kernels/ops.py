@@ -29,8 +29,9 @@ vs. the unfused op sequence (``epilogue_hbm_saved``).
 Every public entry point is telemetry-instrumented: when the global tracer is
 enabled (``observability.trace``) and the call is eager, the dispatch records
 operand shapes, FLOPs, wall time under ``block_until_ready``, the bytes it
-touched, what ran (``kernel``, ``stationarity``, ``col_fold``) and
-``tile_util`` (logical FLOPs / padded FLOPs under the kernel's blocks).
+touched, what ran (``kernel``, ``stationarity``, ``col_fold``,
+``images_per_step``) and ``tile_util`` (logical FLOPs / padded FLOPs under
+the kernel's blocks).
 These spans are the one record of them: ``carla_conv``'s span copies them
 from its child.  When tracing is disabled (the default), or the call is
 traced inside an outer ``jax.jit``, the jitted function is invoked directly:
@@ -53,9 +54,11 @@ conv2_1, ResNet-50's conv2 3x3s, the pruned conv3 3x3s) runs it with
 weights refolded with zero taps, so each dot fills the MXU's 128 lanes of
 C and of K where 64 channels filled a quarter of it.  The kernel and its
 row blocks then see the folded conv (``core.route.conv2d_kernel_shapes``).
-An eager span records ``col_fold`` (f, or 1) and ``row_blocks`` and counts
-the halo rows that blocks re-read in ``bytes_touched``, and a 1x1's span
-records the GEMM's ``c_blocks``.
+An eager span records ``col_fold`` (f, or 1), ``row_blocks`` and
+``images_per_step`` (``nb``, the images a grid step takes where one image's
+plane gives the MXU few rows), and counts in ``bytes_touched`` the halo rows
+that blocks re-read and the weights streamed once per block of ``nb``
+images; a 1x1's span records the GEMM's ``c_blocks``.
 """
 from __future__ import annotations
 
@@ -76,7 +79,7 @@ from repro.core.route import (
 from repro.observability import trace
 from . import ref as _ref
 from .conv1d import conv1d_causal as _conv1d_pallas
-from .conv2d import conv2d as _conv2d_pallas, row_block
+from .conv2d import conv2d as _conv2d_pallas, row_block, step_tiles
 from .matmul import (
     BC as _GEMM_BC,
     matmul_act_stationary,
@@ -259,7 +262,7 @@ def conv2d(x, w, *, stride: int = 1, padding: int = 0, impl: str = "auto",
                           stride=stride, padding=padding, impl=impl)
         jax.block_until_ready(out)
         b, oh, ow, _ = out.shape
-        halo = 0
+        halo = refetch = 0
         if impl == "pallas":
             sp.attrs["kernel"] = r.kernel
             if r.kernel == "im2col_gemm":
@@ -269,16 +272,21 @@ def conv2d(x, w, *, stride: int = 1, padding: int = 0, impl: str = "auto",
                 xk, wk, pk = conv2d_kernel_shapes(x.shape, w.shape, stride,
                                                   padding)
                 sp.attrs["col_fold"] = r.col_fold
-                th = row_block(xk, wk, padding=pk,
-                               has_res=ep.residual is not None)
+                nb, th, bk, bc = step_tiles(xk, wk, padding=pk,
+                                            has_res=ep.residual is not None)
                 n_r = -(-oh // th)
                 sp.attrs["row_blocks"] = n_r
+                sp.attrs["images_per_step"] = nb
                 # each block past the first re-reads fh - 1 padded rows
                 halo = ((n_r - 1) * (fh - 1) * (xk[2] + 2 * pk)
                         * xk[3] * x.dtype.itemsize)
+                # the weight tiles stream once per (image block, row block),
+                # unless one tile holds them all and stays in VMEM
+                if -(-wk[2] // bc) * -(-wk[3] // bk) > 1:
+                    refetch = (b // nb) * n_r - 1
         sp.attrs["flops"] = 2 * b * oh * ow * k * fh * fw * x.shape[-1]
-        sp.attrs["bytes_touched"] = halo + _nbytes(x, w, out, ep.scale,
-                                                   ep.bias, ep.residual)
+        sp.attrs["bytes_touched"] = halo + refetch * _nbytes(w) + _nbytes(
+            x, w, out, ep.scale, ep.bias, ep.residual)
         sp.attrs["tile_util"] = tile_util_conv2d(
             x.shape, w.shape, stride=stride, padding=padding,
             has_res=ep.residual is not None)

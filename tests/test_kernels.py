@@ -66,6 +66,50 @@ def test_conv2d_sweep(b, h, w, c, k, fl, s, p, dtype):
     assert _err(got, want) < _tol(dtype, scale=fl * fl * c ** 0.5)
 
 
+# Several images per grid step (``nb``, ``kernels.conv2d.step_tiles``): the
+# 14x14 and 7x7 planes give one image's dot 196 and 49 rows, so a batch of 8
+# takes several images a step; B = 6 takes a block that divides it.
+# (b, h, c, k, nb at the default rows per dot, epilogue)
+IMAGE_BLOCK_CASES = [
+    (8, 14, 16, 24, 4, "none"),
+    (8, 14, 16, 24, 4, "scale+bias+residual+relu"),
+    (8, 7, 130, 16, 8, "scale+bias+relu"),
+    (8, 7, 8, 8, 8, "residual"),
+    (6, 14, 8, 16, 3, "scale+bias+residual+relu"),
+    (6, 7, 8, 16, 6, "none"),
+]
+
+
+@pytest.mark.parametrize("b,h,c,k,nb,epilogue", IMAGE_BLOCK_CASES)
+def test_conv2d_image_block_matches_ref_and_one_image_per_step(
+        b, h, c, k, nb, epilogue, monkeypatch):
+    import sys
+    conv2d_mod = sys.modules["repro.kernels.conv2d"]
+    key = jax.random.PRNGKey(b * 1000 + h * 10 + c)
+    x = _rand(key, (b, h, h, c), jnp.float32)
+    w = _rand(jax.random.fold_in(key, 1), (3, 3, c, k), jnp.float32)
+    ep = {}
+    if "scale" in epilogue:
+        ep["scale"] = _rand(jax.random.fold_in(key, 2), (k,), jnp.float32)
+        ep["bias"] = _rand(jax.random.fold_in(key, 3), (k,), jnp.float32)
+    if "residual" in epilogue:
+        ep["residual"] = _rand(jax.random.fold_in(key, 4), (b, h, h, k),
+                               jnp.float32)
+    relu = "relu" in epilogue
+    assert conv2d_mod.step_tiles(x.shape, w.shape, padding=1,
+                                 has_res="residual" in ep)[0] == nb
+    got = conv2d_mod.conv2d(x, w, padding=1, relu=relu, interpret=True, **ep)
+    want = ref.conv2d_ref(x, w, padding=1, relu=relu, **ep)
+    assert got.shape == want.shape
+    assert _err(got, want) < _tol(jnp.float32, scale=9 * c ** 0.5)
+    # the same call at one image per step: the same sums, row by row
+    monkeypatch.setattr(conv2d_mod, "ROWS_PER_DOT", 0)
+    assert conv2d_mod.step_tiles(x.shape, w.shape, padding=1,
+                                 has_res="residual" in ep)[0] == 1
+    one = conv2d_mod.conv2d(x, w, padding=1, relu=relu, interpret=True, **ep)
+    assert _err(got, one) < 1e-5
+
+
 # ------------------------------- matmul --------------------------------------
 MM_CASES = [(128, 256, 128), (100, 300, 80), (256, 512, 384), (1, 512, 300),
             (4, 4096, 128), (513, 129, 257)]

@@ -30,7 +30,7 @@ from repro.core.route import (
     tile_util_gemm,
 )
 from repro.kernels import ops, ref
-from repro.kernels.conv2d import row_block
+from repro.kernels.conv2d import row_block, step_tiles
 from repro.models.cnn import VGG_FC
 from repro.observability import trace
 
@@ -291,5 +291,75 @@ def test_row_blocked_3x3_parent_and_child_count_the_halo(monkeypatch):
     assert sp.attrs["bytes_touched"] == ksp.attrs["bytes_touched"] \
         == plain + halo
     assert ksp.attrs["row_blocks"] == n_r
+    want = ref.conv2d_ref(x, w, padding=1)
+    assert float(jnp.max(jnp.abs(out - want))) < 1e-4
+
+
+NETS = {
+    "resnet50_dense": lambda: resnet50_conv_layers(False),
+    "resnet50_pruned50": lambda: resnet50_conv_layers(True),
+    "vgg16": vgg16_conv_layers,
+}
+
+
+@pytest.mark.parametrize("batch", [1, 32])
+@pytest.mark.parametrize("net", list(NETS))
+def test_images_per_step_only_where_one_image_gives_few_rows(net, batch):
+    """At batch 1 every 3x3 on the conv2d kernel takes one image a step, so
+    the single-stream program is the one-image kernel; at batch 32 the 14x14
+    and 7x7 3x3s take several, a divisor of the batch, and the step still
+    fits VMEM; a plane in row blocks never does."""
+    conv2d_mod = sys.modules["repro.kernels.conv2d"]
+    engaged = set()
+    for l in NETS[net]():
+        xs, ws = (batch, l.IL, l.IL, l.IC), (3, 3, l.IC, l.K)
+        if l.FL != 3 or route(xs, ws, 1, 1).kernel != "conv2d":
+            continue
+        xk, wk, pk = conv2d_kernel_shapes(xs, ws, 1, 1)
+        oh, ow = xk[1] - 3 + 2 * pk + 1, xk[2] - wk[1] + 2 * pk + 1
+        for has_res in (False, True):
+            nb, th, bk, bc = step_tiles(xk, wk, padding=pk, has_res=has_res)
+            assert batch % nb == 0
+            weights, in_row, out_row = conv2d_mod._step_vmem(
+                xk, wk, padding=pk, bk=bk, bc=bc, has_res=has_res)
+            assert weights + nb * ((th + 2) * in_row + th * out_row) \
+                <= conv2d_mod.VMEM_BUDGET
+            if batch == 1 or th < oh:
+                assert nb == 1, l
+            if th * ow >= conv2d_mod.ROWS_PER_DOT:
+                assert nb == 1, l
+            if l.IL in (14, 7):
+                assert nb > 1 or batch == 1, l
+            if nb > 1:
+                engaged.add(l.IL)
+    # at batch 32: ResNet-50's conv4 and conv5, VGG-16's conv5, and the
+    # pruned conv3 if its 28 rows of 16 folded column groups fall short
+    want = {14, 7} & {l.IL for l in NETS[net]()} if batch == 32 else set()
+    if (batch == 32 and net == "resnet50_pruned50"
+            and 28 * 16 < conv2d_mod.ROWS_PER_DOT):
+        want.add(28)
+    assert engaged == want
+
+
+@pytest.mark.parametrize("c,refetch", [(130, 1), (16, 0)])
+def test_image_block_span_counts_the_weights_once_per_block(
+        c, refetch, monkeypatch):
+    """An eager 3x3 at batch 4 whose rows per dot ask for two images a step:
+    both spans record ``images_per_step``, and ``bytes_touched`` counts the
+    weights once per block of images, where they span several tiles (130
+    channels: two C blocks), and once where one tile holds them all."""
+    monkeypatch.setattr(sys.modules["repro.kernels.conv2d"], "ROWS_PER_DOT",
+                        98)
+    key = jax.random.PRNGKey(8)
+    x = jax.random.normal(key, (4, 7, 7, c))
+    w = jax.random.normal(jax.random.fold_in(key, 1), (3, 3, c, 16)) / 30
+    with trace.capture() as tr:
+        out = carla.carla_conv(x, w, padding=1, impl="pallas")
+    (sp,) = tr.spans
+    (ksp,) = sp.children
+    assert sp.attrs["images_per_step"] == ksp.attrs["images_per_step"] == 2
+    plain = 4 * (x.size + w.size + out.size)
+    assert sp.attrs["bytes_touched"] == ksp.attrs["bytes_touched"] \
+        == plain + refetch * 4 * w.size
     want = ref.conv2d_ref(x, w, padding=1)
     assert float(jnp.max(jnp.abs(out - want))) < 1e-4

@@ -40,11 +40,16 @@ def one_chip():
         was = jax.config.jax_enable_compilation_cache
         jax.config.update("jax_enable_compilation_cache", False)
         compilation_cache.reset_cache()
+        # a jit traced earlier in this process on the CPU's shapes cached a
+        # jaxpr with its kernels interpreted, which this file's jits of the
+        # same shapes would reuse; drop it, and what this file traces
+        jax.clear_caches()
         try:
             yield SingleDeviceSharding(topo.devices[0])
         finally:
             jax.config.update("jax_enable_compilation_cache", was)
             compilation_cache.reset_cache()
+            jax.clear_caches()
 
 
 # (x shape, w shape, stride, padding, residual): one dispatch each
@@ -75,6 +80,12 @@ CASES = {
     "3x3_56x56x64_b32": ((32, 56, 56, 64), (3, 3, 64, 64), 1, 1, False),
     "3x3_56x56x32_b32_pruned": ((32, 56, 56, 32), (3, 3, 32, 32), 1, 1, False),
     "3x3_28x28x64_b32_pruned": ((32, 28, 28, 64), (3, 3, 64, 64), 1, 1, False),
+    # the 14x14 and 7x7 3x3s of the offline cells: several images a step
+    "3x3_14x14x256_b32": ((32, 14, 14, 256), (3, 3, 256, 256), 1, 1, False),
+    "3x3_7x7x512_b32": ((32, 7, 7, 512), (3, 3, 512, 512), 1, 1, False),
+    "3x3_14x14x128_b32_pruned": ((32, 14, 14, 128), (3, 3, 128, 128), 1, 1,
+                                 False),
+    "3x3_7x7x256_b32_pruned": ((32, 7, 7, 256), (3, 3, 256, 256), 1, 1, False),
 }
 # cases that call a kernel with blocks of their own, not the route's
 BLOCKS = {"stem_7x7s2_bc128": dict(bm=256, bk=64, bc=128)}
